@@ -61,13 +61,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import (Any, Callable, Iterator, Optional, Protocol, Union,
                     runtime_checkable)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed import sharding as shardlib
@@ -85,6 +85,20 @@ __all__ = [
     "PiCholeskyWarmstart", "SVDStrategy", "PinrmseStrategy",
     "LowRankStrategy",
 ]
+
+
+def _jit(fn: Callable, **jit_kwargs) -> Callable:
+    """``jax.jit`` of an engine stage whose matmuls are traced at full
+    precision.  On a TPU an f32 matmul otherwise runs as one bf16 pass,
+    which is not the f32 arithmetic the precision policies promise (the Θ
+    fit, the refinement residual and the scoring all go through XLA).  The
+    Pallas kernels set their own MXU precision."""
+    @functools.wraps(fn)
+    def stage(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return jax.jit(stage, **jit_kwargs)
 
 
 def _sample_grid(lams: jax.Array, g: int) -> jax.Array:
@@ -987,17 +1001,17 @@ class CVEngine:
                                   lams, aux)
             fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
             repl = jax.tree.map(lambda _: P(), aux)
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 self._core, mesh=mesh,
                 in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), P(fold_ax),
                           P(fold_ax), P(lam_ax), repl),
                 out_specs=P(fold_ax, lam_ax),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux)
 
         donate = (0, 1) if self.donate else ()
-        return jax.jit(sweep, donate_argnums=donate)
+        return _jit(sweep, donate_argnums=donate)
 
     @staticmethod
     def _mesh_key(mesh: Optional[Mesh]):
@@ -1045,17 +1059,17 @@ class CVEngine:
                 return self._replay_core(state, f_idx, h_tr, g_tr,
                                          x_folds, y_folds, lams)
             fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 self._replay_core, mesh=mesh,
                 in_specs=(shardlib.cv_state_specs(state), P(fold_ax),
                           P(fold_ax), P(fold_ax), P(fold_ax), P(fold_ax),
                           P(lam_ax)),
                 out_specs=P(fold_ax, lam_ax),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(state, f_idx, h_tr, g_tr, x_folds, y_folds, lams)
 
-        return jax.jit(replay)
+        return _jit(replay)
 
     def _replay_fn(self, mesh: Optional[Mesh]):
         key = self._mesh_key(mesh)
@@ -1082,15 +1096,15 @@ class CVEngine:
                 return core(f_idx, h_tr, g_tr, aux)
             fold_ax = shardlib.CV_FOLD_AXIS
             repl = jax.tree.map(lambda _: P(), aux)
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 core, mesh=mesh,
                 in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), repl),
                 out_specs=(P(fold_ax), P(fold_ax)),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(f_idx, h_tr, g_tr, aux)
 
-        return jax.jit(statef)
+        return _jit(statef)
 
     def _state_fn(self, mesh: Optional[Mesh], with_anchors: bool):
         key = (self._mesh_key(mesh), with_anchors)
@@ -1110,7 +1124,7 @@ class CVEngine:
                                   block=strat.block, basis=strat.basis,
                                   factors=pf_f, backend=bk)
 
-        return jax.jit(jax.vmap(one))(jnp.asarray(pf.vec))
+        return _jit(jax.vmap(one))(jnp.asarray(pf.vec))
 
     # -- pipelined staged sweep -------------------------------------------
     #
@@ -1132,7 +1146,7 @@ class CVEngine:
     def _prepare_fn(self):
         if self._prepare is None:
             strat, bk = self.strategy, self._bk
-            self._prepare = jax.jit(
+            self._prepare = _jit(
                 lambda h_tr, g_tr, x, y, lams: strat.prepare(
                     x, y, h_tr, g_tr, lams, bk))
         return self._prepare
@@ -1152,8 +1166,8 @@ class CVEngine:
 
             donate = ((1,) if self.donate
                       and getattr(strat, "state_uses_hessian", False) else ())
-            self._fold_states[with_anchors] = jax.jit(one,
-                                                      donate_argnums=donate)
+            self._fold_states[with_anchors] = _jit(one,
+                                                   donate_argnums=donate)
         return self._fold_states[with_anchors]
 
     def _build_chunk_errors(self, mesh: Optional[Mesh]):
@@ -1170,16 +1184,16 @@ class CVEngine:
             if mesh is None:
                 return core(state, f_idx, h_tr, g_tr, x_folds, y_folds,
                             lams_c, aux)
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 core, mesh=mesh,
                 in_specs=shardlib.cv_chunk_in_specs(state, aux),
                 out_specs=P(shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS),
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(state, f_idx, h_tr, g_tr, x_folds, y_folds,
                            lams_c, aux)
 
-        return jax.jit(chunk_errors)
+        return _jit(chunk_errors)
 
     def _chunk_errors_fn(self, mesh: Optional[Mesh]):
         key = self._mesh_key(mesh)
@@ -1253,15 +1267,15 @@ class CVEngine:
             def statef(f_idx, h_tr, g_tr, aux):
                 fold_ax = shardlib.CV_FOLD_AXIS
                 repl = jax.tree.map(lambda _: P(), aux)
-                sharded = shard_map(
+                sharded = jax.shard_map(
                     core, mesh=mesh,
                     in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), repl),
                     out_specs=(P(fold_ax), P(fold_ax)),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 return sharded(f_idx, h_tr, g_tr, aux)
 
-            self._states[key] = jax.jit(statef)
+            self._states[key] = _jit(statef)
         return self._states[key]
 
     def _staged_state_for(self, mesh, h_tr, g_tr, folds: FoldData, lams,
@@ -1746,7 +1760,7 @@ class CVEngine:
                     return bk.pack_tril(factors, strat.block)
                 return jax.vmap(per_fold)(jnp.arange(h_tr.shape[0]), h_tr)
 
-            self._anchor_targets = jax.jit(targets)
+            self._anchor_targets = _jit(targets)
         return self._anchor_targets
 
     def select_interpolant(self, folds: FoldData, lams: jax.Array, *,

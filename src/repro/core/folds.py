@@ -34,8 +34,10 @@ def make_folds(x: jax.Array, y: jax.Array, k: int) -> FoldData:
     n_f = n // k
     x = x[: n_f * k].reshape(k, n_f, -1)
     y = y[: n_f * k].reshape(k, n_f)
-    fold_hess = jnp.einsum("kni,knj->kij", x, x)
-    fold_grad = jnp.einsum("kni,kn->ki", x, y)
+    # full precision: on a TPU an f32 einsum is otherwise one bf16 pass
+    hi = jax.lax.Precision.HIGHEST
+    fold_hess = jnp.einsum("kni,knj->kij", x, x, precision=hi)
+    fold_grad = jnp.einsum("kni,kn->ki", x, y, precision=hi)
     return FoldData(fold_hess.sum(0), fold_grad.sum(0), fold_hess, fold_grad, x, y)
 
 

@@ -171,10 +171,15 @@ def fit(
     v = vandermonde(sample_lams, degree, center).astype(fit_dtype)
 
     # Steps 5–6: Θ = (VᵀV)⁻¹ VᵀT — normal equations exactly as in the
-    # paper, at the fit dtype; Θ is then stored at the storage dtype.
+    # paper, at the fit dtype; Θ is then stored at the storage dtype.  One
+    # packed tile of columns at a time: a fold-batched solve against the
+    # whole (r+1, P) right-hand side compiled for v5e to 13.2 GiB of
+    # temporaries for the 5-fold state at h=4096, against 4.8 GiB this way.
     h_lam = v.T @ v
-    g_lam = v.T @ targets.astype(fit_dtype)
-    theta = jnp.linalg.solve(h_lam, g_lam)
+    tiles = targets.astype(fit_dtype).reshape(g, -1, block * block)
+    theta = jax.lax.map(lambda t: jnp.linalg.solve(h_lam, v.T @ t),
+                        jnp.moveaxis(tiles, 1, 0))         # (n, r+1, B²)
+    theta = jnp.moveaxis(theta, 0, 1).reshape(degree + 1, -1)
     return PiCholesky(theta=theta.astype(store_dtype),
                       center=center.astype(fit_dtype), h=h, block=block)
 

@@ -16,15 +16,13 @@ per-device), with ring wire formulas per op:
 Hardware constants are an :class:`HW` dataclass, not module globals: the
 autotuner ranks candidate configurations by these terms, so scoring a CPU
 container against TPU v5e numbers would rank against the wrong machine.
-:func:`detect_hw` picks a per-platform preset from
-``jax.devices()[0].platform`` (``cpu`` / ``gpu`` / ``tpu``); the
-``REPRO_HW`` env var forces a preset by name, and
-``REPRO_HW_PEAK_FLOPS`` / ``REPRO_HW_HBM_BW`` / ``REPRO_HW_LINK_BW``
-(plus ``REPRO_HW_CACHE_BW`` / ``REPRO_HW_CACHE_BYTES`` for the
-cache-aware memory term) override individual terms (calibrating against
-a measured machine).  The
-module-level ``PEAK_FLOPS`` / ``HBM_BW`` / ``LINK_BW`` constants remain
-the TPU v5e preset for backward compatibility.
+:func:`detect_hw` looks up ``jax.devices()[0].device_kind`` in
+:data:`HW_PRESETS`; a device kind that is not in the table is an error,
+never a default.  The ``REPRO_HW`` env var forces a preset by key or
+name, and ``REPRO_HW_PEAK_FLOPS`` / ``REPRO_HW_HBM_BW`` /
+``REPRO_HW_LINK_BW`` (plus ``REPRO_HW_CACHE_BW`` / ``REPRO_HW_CACHE_BYTES``
+for the cache-aware memory term) override individual terms (calibrating
+against a measured machine).
 """
 from __future__ import annotations
 
@@ -37,10 +35,6 @@ from .dtype_bytes import DTYPE_BYTES as _DTYPE_BYTES
 
 __all__ = ["HW", "HW_PRESETS", "detect_hw", "collective_bytes", "roofline",
            "Roofline"]
-
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,41 +57,43 @@ class HW:
     cache_bytes: Optional[float] = None  # last-level cache capacity
 
 
-#: Per-platform presets keyed by ``jax.devices()[0].platform``.  tpu is
-#: v5e (197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s ICI link); gpu is an
-#: A100-80GB-class part (312 TFLOP/s bf16, 2.0 TB/s HBM, 300 GB/s NVLink);
-#: cpu is a deliberately rough server-class estimate — on CPU the tuner
-#: only needs the *relative* ordering of candidates, and all candidates
-#: share the platform.  Only the cpu preset models the cache hierarchy
-#: (~30 MB LLC at ~8× DRAM bandwidth): on CPU the candidates' total
-#: flops/bytes are nearly flat and *locality* — whether the λ-chunk ×
-#: packed-factor working set stays cache-resident — is what actually
-#: separates their wall time; the accelerator presets keep the classic
-#: HBM-only term (VMEM-sized tiles are the kernels' own contract).
+#: Per-chip peaks keyed by ``jax.devices()[0].device_kind``.  Only the cpu
+#: preset models the cache hierarchy (~30 MB LLC at ~8× DRAM bandwidth): on
+#: CPU the candidates' total flops/bytes are nearly flat and *locality* —
+#: whether the λ-chunk × packed-factor working set stays cache-resident —
+#: is what separates their wall time; the accelerator presets keep the
+#: classic HBM-only term (VMEM-sized tiles are the kernels' own contract).
 HW_PRESETS = {
-    "tpu": HW(name="tpu-v5e", peak_flops=PEAK_FLOPS, hbm_bw=HBM_BW,
-              link_bw=LINK_BW),
-    "gpu": HW(name="gpu-a100", peak_flops=312e12, hbm_bw=2.0e12,
-              link_bw=300e9),
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM, 1,600 Gbit/s of interchip interconnect over 4 links
+    "TPU v5 lite": HW(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
+                      link_bw=50e9),
+    # a deliberately rough server-class host: on CPU the tuner only needs
+    # the *relative* ordering of candidates, which all share the platform
     "cpu": HW(name="cpu", peak_flops=1e11, hbm_bw=5e10, link_bw=2.5e10,
               cache_bw=4e11, cache_bytes=3e7),
 }
 
 
 def detect_hw() -> HW:
-    """The :class:`HW` for this process: ``REPRO_HW`` preset override if
-    set, else the preset for the default jax platform (cpu fallback for
-    unknown platforms), with per-term ``REPRO_HW_*`` numeric overrides
-    applied on top."""
-    name = os.environ.get("REPRO_HW", "").strip().lower()
+    """The :class:`HW` for this process: the ``REPRO_HW`` preset (by key or
+    name) if set, else the preset for this process's device kind, with
+    per-term ``REPRO_HW_*`` numeric overrides applied on top.  Raises for a
+    device kind the table does not hold."""
+    name = os.environ.get("REPRO_HW", "").strip()
     if name:
-        if name not in HW_PRESETS:
+        by_name = {hw.name: hw for hw in HW_PRESETS.values()}
+        hw = HW_PRESETS.get(name) or by_name.get(name.lower())
+        if hw is None:
             raise ValueError(f"REPRO_HW={name!r}: no such preset; "
-                             f"have {sorted(HW_PRESETS)}")
-        hw = HW_PRESETS[name]
+                             f"have {sorted(by_name)}")
     else:
         import jax
-        hw = HW_PRESETS.get(jax.devices()[0].platform, HW_PRESETS["cpu"])
+        kind = jax.devices()[0].device_kind
+        if kind not in HW_PRESETS:
+            raise ValueError(f"no peak rates for device kind {kind!r}; "
+                             f"have {sorted(HW_PRESETS)} (or set REPRO_HW)")
+        hw = HW_PRESETS[kind]
     overrides = {}
     for field, env in (("peak_flops", "REPRO_HW_PEAK_FLOPS"),
                        ("hbm_bw", "REPRO_HW_HBM_BW"),

@@ -23,38 +23,68 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .compat import vmem_scratch
+from .compat import mxu_dot, vmem_scratch
 
 __all__ = ["cholesky_blocked"]
 
 
+# The TPU lowering has no dynamic slice, so the two in-register recurrences
+# below never index at the loop variable: a row or column is picked by an
+# iota mask and a reduction, and written back by a masked select.
+
+
+def _iota(shape, axis: int) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _pick_col(a: jax.Array, k) -> jax.Array:
+    """Column ``k`` of ``a`` as a (rows, 1) vector."""
+    return jnp.sum(jnp.where(_iota(a.shape, 1) == k, a, 0.0),
+                   axis=1, keepdims=True)
+
+
+def _pick_row(a: jax.Array, k) -> jax.Array:
+    """Row ``k`` of ``a`` as a (1, cols) vector."""
+    return jnp.sum(jnp.where(_iota(a.shape, 0) == k, a, 0.0),
+                   axis=0, keepdims=True)
+
+
 def _potf2(a: jax.Array) -> jax.Array:
-    """Unblocked Cholesky of a B×B tile (functional, in-register)."""
+    """Unblocked Cholesky of a B×B tile (functional, in-register).
+
+    Reads only the lower triangle: it is mirrored once, and every rank-1
+    update subtracts the symmetric ``c cᵀ``, so row ``k`` of the trailing
+    block equals column ``k`` bit for bit and supplies ``cᵀ`` without a
+    per-step transpose.
+    """
     b = a.shape[0]
-    iota = jax.lax.iota(jnp.int32, b)
+    rows, cols = _iota((b, b), 0), _iota((b, b), 1)
+    a = jnp.where(rows >= cols, a, a.T)
 
     def body(k, a):
-        pivot = jnp.sqrt(a[k, k])
-        col = jnp.where(iota > k, a[:, k] / pivot, 0.0)
-        col = jnp.where(iota == k, pivot, col)
-        mask = (iota[:, None] > k) & (iota[None, :] > k)
-        a = jnp.where(mask, a - col[:, None] * col[None, :], a)
-        return a.at[:, k].set(col)
+        col = _pick_col(a, k)                                # (B, 1)
+        pivot = jnp.sqrt(_pick_row(col, k))                  # (1, 1)
+        c = jnp.where(_iota((b, 1), 0) > k, col / pivot, 0.0)
+        c_t = jnp.where(_iota((1, b), 1) > k, _pick_row(a, k) / pivot, 0.0)
+        a = jnp.where((rows > k) & (cols > k), a - c * c_t, a)
+        return jnp.where(cols == k, jnp.where(rows == k, pivot, c), a)
 
     a = jax.lax.fori_loop(0, b, body, a)
-    return jnp.where(iota[:, None] >= iota[None, :], a, 0.0)
+    return jnp.where(rows >= cols, a, 0.0)
 
 
 def _inv_lower(l: jax.Array) -> jax.Array:
     """X with L X = I via row-wise forward substitution (in-register)."""
     b = l.shape[0]
-    iota = jax.lax.iota(jnp.int32, b)
-    eye = jnp.eye(b, dtype=l.dtype)
+    l_t = l.T                 # column k of Lᵀ is row k of L, along sublanes
 
     def body(k, x):
-        row = l[k]
-        s = jnp.sum(jnp.where((iota < k)[:, None], x, 0.0) * row[:, None], axis=0)
-        return x.at[k].set((eye[k] - s) / l[k, k])
+        row = _pick_col(l_t, k)                              # (B, 1): L[k, :]
+        s = jnp.sum(jnp.where(_iota((b, b), 0) < k, x, 0.0) * row,
+                    axis=0, keepdims=True)
+        e_k = (_iota((1, b), 1) == k).astype(l.dtype)
+        new = (e_k - s) / _pick_row(row, k)                  # ÷ L[k, k]
+        return jnp.where(_iota((b, b), 0) == k, new, x)
 
     return jax.lax.fori_loop(0, b, body, jnp.zeros_like(l))
 
@@ -80,8 +110,7 @@ def _make_panel_kernel(compute_dtype=None):
             if compute_dtype is not None:
                 panel = panel.astype(compute_dtype)
                 inv_t = inv_t.astype(compute_dtype)
-            out_ref[...] = jnp.dot(panel, inv_t,
-                                   preferred_element_type=out_ref.dtype)
+            out_ref[...] = mxu_dot(panel, inv_t, out_ref.dtype)
 
     return kernel
 
@@ -98,8 +127,7 @@ def _make_syrk_kernel(compute_dtype=None):
             if compute_dtype is not None:
                 pi = pi.astype(compute_dtype)
                 pj_t = pj_t.astype(compute_dtype)
-            out_ref[...] = c_ref[...] - jnp.dot(
-                pi, pj_t, preferred_element_type=out_ref.dtype)
+            out_ref[...] = c_ref[...] - mxu_dot(pi, pj_t, out_ref.dtype)
 
         @pl.when(i < j)
         def _copy():

@@ -1,22 +1,30 @@
-"""Version compatibility for the Pallas TPU API surface.
+"""What every Pallas TPU kernel here shares: the memory-space handles under
+one spelling, and the MXU product.
 
-jax renamed the TPU memory-space handles across 0.4.x → 0.5.x:
-
-* old: ``pltpu.VMEM(shape, dtype)`` scratch, ``pltpu.SMEM`` block memory space
-* new: ``pltpu.MemorySpace.VMEM(shape, dtype)`` / ``pltpu.MemorySpace.SMEM``
-
-Kernels import these two names instead of touching ``pltpu`` directly so the
-same kernel body lowers under either jax release.
+``vmem_scratch(shape, dtype)`` allocates a VMEM scratch buffer; ``SMEM`` is
+the block memory space for scalars read by the kernel body.
 """
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["vmem_scratch", "SMEM"]
+__all__ = ["vmem_scratch", "SMEM", "mxu_dot"]
 
-if hasattr(pltpu, "VMEM"):
-    vmem_scratch = pltpu.VMEM
-    SMEM = pltpu.SMEM
-else:  # pragma: no cover - newer jax
-    vmem_scratch = pltpu.MemorySpace.VMEM
-    SMEM = pltpu.MemorySpace.SMEM
+vmem_scratch = pltpu.VMEM
+SMEM = pltpu.SMEM
+
+
+def mxu_dot(a: jax.Array, b: jax.Array, out_dtype) -> jax.Array:
+    """``a @ b`` accumulated at ``out_dtype``.
+
+    The MXU multiplies f32 operands as one bf16 pass unless asked for full
+    precision, which the factorizations cannot afford: a rank-B update at
+    bf16 accuracy makes ``H + λI`` indefinite at the paper's smallest λ.
+    16-bit operands multiply exactly at the default, and Mosaic refuses
+    the full-precision request for them.
+    """
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jnp.dot(a, b, precision=precision, preferred_element_type=out_dtype)

@@ -36,6 +36,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
 
+from .compat import mxu_dot, vmem_scratch
+
 __all__ = ["solve_lower_packed", "solve_packed"]
 
 
@@ -65,16 +67,16 @@ def _make_kernel(block: int, nt: int, reverse: bool):
             # accumulation at the scratch/accum dtype
             w_t = out_ref[pl.ds(t * block, block), :]
             tile = tiles_ref[0].T if reverse else tiles_ref[0]
-            acc_ref[...] += jnp.dot(tile, w_t.astype(tile.dtype),
-                                    preferred_element_type=acc_ref.dtype)
+            acc_ref[...] += mxu_dot(tile, w_t.astype(tile.dtype),
+                                    acc_ref.dtype)
 
         @pl.when(t == i)
         def _solve():
             g_i = g_ref[pl.ds(i * block, block), :]
             inv = inv_ref[0].T if reverse else inv_ref[0]
             rhs = (g_i - acc_ref[...]).astype(inv.dtype)
-            out_ref[pl.ds(i * block, block), :] = jnp.dot(
-                inv, rhs, preferred_element_type=out_ref.dtype)
+            out_ref[pl.ds(i * block, block), :] = mxu_dot(
+                inv, rhs, out_ref.dtype)
 
     return kernel
 
@@ -161,7 +163,7 @@ def solve_lower_packed(vec: jax.Array, g: jax.Array, h: int, block: int = 128,
                          lambda s, u, idx: (idx[s * nt + u], 0, 0)),
         ],
         out_specs=pl.BlockSpec((hp, q), lambda s, u, idx: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((block, q), g2.dtype)],
+        scratch_shapes=[vmem_scratch((block, q), g2.dtype)],
     )
     w = pl.pallas_call(
         _make_kernel(block, nt, transpose),

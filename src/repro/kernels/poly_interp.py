@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import SMEM
+from .compat import SMEM, mxu_dot, vmem_scratch
 
 from repro.core import packing
 
@@ -53,7 +53,7 @@ def _make_kernel(degree: int):
 
         @pl.when(i >= j)
         def _lower():
-            x = lam_ref[t]
+            x = lam_ref[0, t]
             acc = theta_ref[degree, 0]
             for k in range(degree - 1, -1, -1):  # Horner, in registers
                 acc = acc * x + theta_ref[k, 0]
@@ -88,7 +88,7 @@ def interp_factors(theta: jax.Array, lams: jax.Array, h: int, block: int = 128,
         num_scalar_prefetch=1,
         grid=(q, nt, nt),
         in_specs=[
-            pl.BlockSpec(memory_space=SMEM),  # λ values
+            pl.BlockSpec(memory_space=SMEM),  # λ values, (1, q)
             pl.BlockSpec((degree + 1, 1, block, block),
                          lambda t, i, j, pidx: (0, pidx[i * nt + j], 0, 0)),
         ],
@@ -99,7 +99,7 @@ def interp_factors(theta: jax.Array, lams: jax.Array, h: int, block: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q, nt * block, nt * block), theta.dtype),
         interpret=interpret,
-    )(pidx, x, theta_t)
+    )(pidx, x[None], theta_t)
     return out[:, :h, :h]
 
 
@@ -129,14 +129,14 @@ def _make_solve_kernel(degree: int, block: int, nt: int, reverse: bool,
         def _accumulate():
             # Horner at the coefficient (compute) dtype: λ is quantized to
             # it per step, the GEMM accumulates at the scratch dtype
-            x = lam_ref[c].astype(theta_ref.dtype)
+            x = lam_ref[0, c].astype(theta_ref.dtype)
             tile = theta_ref[degree, 0]
             for k in range(degree - 1, -1, -1):  # Horner, in registers
                 tile = tile * x + theta_ref[k, 0]
             tile = tile.T if reverse else tile
             w_t = out_ref[0, pl.ds(t * block, block), :]
-            acc_ref[...] += jnp.dot(tile, w_t.astype(tile.dtype),
-                                    preferred_element_type=acc_ref.dtype)
+            acc_ref[...] += mxu_dot(tile, w_t.astype(tile.dtype),
+                                    acc_ref.dtype)
 
         @pl.when(t == i)
         def _solve():
@@ -146,8 +146,8 @@ def _make_solve_kernel(degree: int, block: int, nt: int, reverse: bool,
                 g_i = g_ref[pl.ds(i * block, block), :]
             inv = inv_ref[0, 0].T if reverse else inv_ref[0, 0]
             rhs = (g_i - acc_ref[...]).astype(inv.dtype)
-            out_ref[0, pl.ds(i * block, block), :] = jnp.dot(
-                inv, rhs, preferred_element_type=out_ref.dtype)
+            out_ref[0, pl.ds(i * block, block), :] = mxu_dot(
+                inv, rhs, out_ref.dtype)
 
     return kernel
 
@@ -183,21 +183,23 @@ def _interp_sweep(theta_t: jax.Array, x: jax.Array, inv_diag: jax.Array,
         num_scalar_prefetch=1,
         grid=(q, nt, nt),
         in_specs=[
-            pl.BlockSpec(memory_space=SMEM),                        # λ values
+            pl.BlockSpec(memory_space=SMEM),                  # λ values, (1, q)
             pl.BlockSpec((1, 1, block, block), inv_index),
             g_spec,
             pl.BlockSpec((degree + 1, 1, block, block),
                          lambda c, s, u, idx: (0, idx[s * nt + u], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, hp, nrhs), lambda c, s, u, idx: (c, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((block, nrhs), g.dtype)],
+        scratch_shapes=[vmem_scratch((block, nrhs), g.dtype)],
     )
+    # λ goes in as (1, q): under a fold vmap its block then still spans the
+    # array's last two dims, as the TPU lowering requires of an SMEM block
     return pl.pallas_call(
         _make_solve_kernel(degree, block, nt, reverse, rhs_batched),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q, hp, nrhs), g.dtype),
         interpret=interpret,
-    )(idx, x, inv_diag, g, theta_t)
+    )(idx, x[None], inv_diag, g, theta_t)
 
 
 @functools.partial(jax.jit, static_argnames=("h", "block", "interpret",

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .compat import mxu_dot
+
 __all__ = ["solve_lower_blocked", "solve_factor_sweep"]
 
 
@@ -42,14 +44,14 @@ def _make_solve_kernel(block: int, nt: int, reverse: bool,
         if compute_dtype is not None:       # MXU at reduced precision,
             panel = panel.astype(compute_dtype)   # full-precision accum
             w = w.astype(compute_dtype)
-        s = jnp.dot(panel, w, preferred_element_type=w_ref.dtype)
+        s = mxu_dot(panel, w, w_ref.dtype)
         g_i = g_ref[pl.ds(i * block, block), :]
         rhs = g_i - s
         inv = inv_ref[0]
         if compute_dtype is not None:
             rhs = rhs.astype(compute_dtype)
             inv = inv.astype(compute_dtype)
-        w_i = jnp.dot(inv, rhs, preferred_element_type=w_ref.dtype)
+        w_i = mxu_dot(inv, rhs, w_ref.dtype)
         w_ref[pl.ds(i * block, block), :] = w_i
 
     return kernel

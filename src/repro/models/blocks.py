@@ -281,7 +281,6 @@ def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig,
         out, aux = _moe_local(xf, p, cfg, e, jnp.int32(0), cap)
     else:
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         dp = ctx.dp_axes
         dp_ok = (b * s) % ctx.dp_size == 0
@@ -324,11 +323,11 @@ def moe_apply(p: Dict, x: jax.Array, cfg: ModelConfig,
 
         out_spec = (P((*dp, "model") if dp_ok else None, None) if use_rs
                     else tok_spec)
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             shard_fn, mesh=ctx.mesh,
             in_specs=(tok_spec, P(None, None), w_spec, w_spec, wo_spec),
             out_specs=(out_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )(xf, p["router"], p["wi"], p["wg"], p["wo"])
 
     out = out.reshape(b, s, d).astype(x.dtype)

@@ -169,25 +169,47 @@ def test_auto_lam_chunk_h_smaller_than_block():
 
 def test_hw_presets_cover_platforms():
     from repro.distributed import roofline as rl
-    assert set(rl.HW_PRESETS) == {"cpu", "gpu", "tpu"}
+    # keyed by device_kind; the v5e entry carries the published peaks
+    assert set(rl.HW_PRESETS) == {"cpu", "TPU v5 lite"}
     for hw in rl.HW_PRESETS.values():
         assert hw.peak_flops > 0 and hw.hbm_bw > 0 and hw.link_bw > 0
-    # backcompat: module constants ARE the tpu-v5e preset
-    tpu = rl.HW_PRESETS["tpu"]
-    assert (tpu.peak_flops, tpu.hbm_bw, tpu.link_bw) == \
-        (rl.PEAK_FLOPS, rl.HBM_BW, rl.LINK_BW)
+    tpu = rl.HW_PRESETS["TPU v5 lite"]
+    assert (tpu.name, tpu.peak_flops, tpu.hbm_bw) == \
+        ("tpu-v5e", 197e12, 819e9)
+
+
+def _fake_device_kind(monkeypatch, kind):
+    class _Dev:
+        device_kind = kind
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "NVIDIA H100"])
+def test_detect_hw_unknown_device_kind_raises(monkeypatch, kind):
+    from repro.distributed import roofline as rl
+    monkeypatch.delenv("REPRO_HW", raising=False)
+    _fake_device_kind(monkeypatch, kind)
+    with pytest.raises(ValueError, match="no peak rates for device kind"):
+        rl.detect_hw()
+    # an explicit preset still serves a device the table does not know
+    monkeypatch.setenv("REPRO_HW", "tpu-v5e")
+    assert rl.detect_hw() == rl.HW_PRESETS["TPU v5 lite"]
 
 
 def test_detect_hw_platform_and_env_override(monkeypatch):
     from repro.distributed import roofline as rl
     monkeypatch.delenv("REPRO_HW", raising=False)
-    assert rl.detect_hw() == rl.HW_PRESETS[jax.devices()[0].platform]
-    monkeypatch.setenv("REPRO_HW", "gpu")
-    assert rl.detect_hw().name == "gpu-a100"
+    assert rl.detect_hw() == rl.HW_PRESETS[jax.devices()[0].device_kind]
+    _fake_device_kind(monkeypatch, "TPU v5 lite")
+    assert rl.detect_hw().name == "tpu-v5e"
+    monkeypatch.setenv("REPRO_HW", "cpu")
+    assert rl.detect_hw().name == "cpu"
+    monkeypatch.setenv("REPRO_HW", "TPU v5 lite")     # by key, too
+    assert rl.detect_hw().name == "tpu-v5e"
     monkeypatch.setenv("REPRO_HW_PEAK_FLOPS", "1e12")
     hw = rl.detect_hw()
     assert hw.peak_flops == 1e12 and hw.name.endswith("+env")
-    assert hw.hbm_bw == rl.HW_PRESETS["gpu"].hbm_bw   # others untouched
+    assert hw.hbm_bw == rl.HW_PRESETS["TPU v5 lite"].hbm_bw  # others untouched
     monkeypatch.setenv("REPRO_HW", "hal9000")
     with pytest.raises(ValueError, match="no such preset"):
         rl.detect_hw()

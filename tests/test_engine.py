@@ -158,6 +158,23 @@ def test_auto_backend_is_reference_off_tpu():
         resolve_backend("no-such-backend")
 
 
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_sweep_traces_every_matmul_at_full_precision(backend):
+    """On a TPU an f32 matmul at the default precision is one bf16 pass: the
+    engine's own matmuls and the kernels' f32 MXU products must all ask for
+    full precision (interpret mode shows the kernels' products too)."""
+    folds = props.regression_folds(h=32, n=128, k=4, dtype=jnp.float32)
+    eng = engine.CVEngine(engine.PiCholeskyStrategy(g=4, block=16),
+                          backend=backend, block=16)
+    h_tr, g_tr = eng._split(folds.hess, folds.grad, folds.fold_hess,
+                            folds.fold_grad)
+    text = eng._sweep_fn(None).lower(
+        h_tr, g_tr, folds.x_folds, folds.y_folds,
+        jnp.logspace(-2, 1, 7, dtype=jnp.float32)).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert dots and all("HIGHEST" in line for line in dots), dots
+
+
 # ------------------------------------------------------ compatibility layer
 
 
