@@ -1,9 +1,7 @@
-"""Shared benchmark utilities: timing, CSV/JSON emission, problem construction."""
+"""Shared benchmark utilities: timing, CSV emission, problem construction."""
 from __future__ import annotations
 
-import json
 import os
-import pathlib
 import time
 from typing import Callable
 
@@ -16,8 +14,8 @@ SCALE = os.environ.get("REPRO_BENCH_SCALE", "ci")
 SIZES = {"ci": [256, 512], "mid": [512, 1024, 2048],
          "paper": [1024, 2048, 4096]}[SCALE]
 
-# REPRO_BENCH_SMOKE=1: tiny problems, one repeat — CI runs this to catch
-# schema drift in the emitted JSON records, not to measure anything.
+# REPRO_BENCH_SMOKE=1: tiny problems — CI runs the roofline bench this way
+# to check that it runs, not to measure anything.
 SMOKE = os.environ.get("REPRO_BENCH_SMOKE", "") == "1"
 
 
@@ -38,34 +36,9 @@ def emit(name: str, seconds: float, derived: str = "") -> None:
     print(f"{name},{seconds * 1e6:.1f},{derived}", flush=True)
 
 
-def emit_json(filename: str, record: dict) -> pathlib.Path:
-    """Write a machine-readable benchmark record to the repo root.
-
-    The perf trajectory lives in these committed files; smoke-mode CI
-    re-emits them on tiny problems so schema drift fails fast.
-    """
-    path = pathlib.Path(__file__).resolve().parents[1] / filename
-    path.write_text(json.dumps(record, indent=2, sort_keys=True) + "\n")
-    emit(f"json_{filename}", 0.0, f"path={path}")
-    return path
-
-
 def ridge_problem(h: int, n: int | None = None, seed: int = 0):
     from repro.data import make_regression_dataset
     n = n or max(2 * h, 512)
     x, y = make_regression_dataset(jax.random.PRNGKey(seed), n, h,
                                    dtype=jnp.float64)
     return x, y
-
-
-def bench_pair(tag: str, host_fn: Callable, engine_fn: Callable,
-               repeats: int = 3, warmup: int = 1) -> dict:
-    """Time a host-loop driver against its CVEngine counterpart and emit
-    both rows plus the speedup line.  Returns {host, engine, speedup}."""
-    t_host = timeit(host_fn, repeats=repeats, warmup=warmup)
-    t_eng = timeit(engine_fn, repeats=repeats, warmup=warmup)
-    emit(f"{tag}_host", t_host, f"seconds={t_host:.3f}")
-    emit(f"{tag}_engine", t_eng, f"seconds={t_eng:.3f}")
-    emit(f"{tag}_engine_speedup", 0.0,
-         f"engine_vs_host={t_host / t_eng:.2f}x")
-    return {"host": t_host, "engine": t_eng, "speedup": t_host / t_eng}

@@ -2,14 +2,11 @@
 
     PYTHONPATH=src python -m benchmarks.run [names...]
 
-Prints ``name,us_per_call,derived`` CSV rows; benches with a machine-readable
-record (``table3`` → ``BENCH_table3.json``, ``serving`` →
-``BENCH_serving.json``) also write it to the repo root so the perf
-trajectory is committed alongside the code.
+Prints ``name,us_per_call,derived`` CSV rows.  These are CPU readings of
+the paper's tables; the chip benchmark is ``bench/`` (``BENCHMARK.json``).
 
 Environment: REPRO_BENCH_SCALE=ci|mid|paper controls problem sizes (ci
-default on this CPU container); REPRO_BENCH_SMOKE=1 shrinks everything to
-seconds-scale so CI can validate the emitted JSON schema on every push.
+default); REPRO_BENCH_SMOKE=1 shrinks the roofline bench to seconds-scale.
 """
 import sys
 
@@ -18,18 +15,15 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 from . import (bench_fig4_smoothness, bench_fig10_pinrmse, bench_fig11_nrmse,
-               bench_roofline, bench_serving, bench_table1_vec,
-               bench_table3_timing, bench_table4_holdout)
+               bench_roofline, bench_table1_vec, bench_table4_holdout)
 
 BENCHES = {
     "fig4": bench_fig4_smoothness.run,
     "table1": bench_table1_vec.run,
-    "table3": bench_table3_timing.run,
     "table4": bench_table4_holdout.run,
     "fig10": bench_fig10_pinrmse.run,
     "fig11": bench_fig11_nrmse.run,
     "roofline": bench_roofline.run,
-    "serving": bench_serving.run,
 }
 
 def main() -> None:

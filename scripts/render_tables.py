@@ -1,11 +1,6 @@
-"""Render §Dry-run / §Roofline markdown tables from results/dryrun/*.json,
-and the committed bench records (``BENCH_table3.json`` including the
-mixed-precision ``precision_sweep`` section, and the multi-tenant
-``BENCH_serving.json``).
+"""Render §Dry-run / §Roofline markdown tables from results/dryrun/*.json.
 
     PYTHONPATH=src python scripts/render_tables.py [--out results/tables.md]
-    PYTHONPATH=src python scripts/render_tables.py --bench BENCH_table3.json
-    PYTHONPATH=src python scripts/render_tables.py --bench BENCH_serving.json
 """
 import argparse
 import glob
@@ -28,112 +23,11 @@ def fmt_bytes(n):
         n /= 1024.0
 
 
-def render_serving(rec, lines):
-    """Markdown sections for a ``bench_serving/v1`` record."""
-    cfg = rec.get("config", {})
-    lat = rec.get("latency", {})
-    cache = rec.get("cache", {})
-    lines += [f"## Serving traffic (n={cfg.get('n_requests')} requests, "
-              f"{cfg.get('n_tenants')} tenants, "
-              f"{cfg.get('n_problems')} problems, h={cfg.get('h')}, "
-              f"zipf_a={cfg.get('zipf_a')})", "",
-              "| p50 latency | p99 latency | throughput | wall |",
-              "|---|---|---|---|",
-              f"| {fmt(lat.get('p50_s'))}s | {fmt(lat.get('p99_s'))}s "
-              f"| {fmt(rec.get('throughput_rps'), 2)} req/s "
-              f"| {fmt(rec.get('wall_s'))}s |", "",
-              "## Shared-cache hit-rate", "",
-              "| hits | misses | hit rate | anchor hits | tenants sharing "
-              "| evictions |",
-              "|---|---|---|---|---|---|",
-              f"| {cache.get('hits')} | {cache.get('misses')} "
-              f"| **{fmt(cache.get('hit_rate'), 3)}** "
-              f"| {cache.get('anchor_hits')} "
-              f"| {cache.get('tenants_sharing')}/{cfg.get('n_tenants')} "
-              f"| {cache.get('evictions')} |", ""]
-    tenants = rec.get("tenants", {})
-    if tenants:
-        lines += ["### Per-tenant partition", "",
-                  "| tenant | hits | misses | anchor hits | puts |",
-                  "|---|---|---|---|---|"]
-        for t, r in sorted(tenants.items()):
-            lines.append(f"| {t} | {r.get('hits')} | {r.get('misses')} "
-                         f"| {r.get('anchor_hits')} | {r.get('puts')} |")
-        lines.append("")
-    fid = rec.get("fidelity", {})
-    bat = rec.get("batching", {})
-    lines += [f"batching: {bat.get('dispatches')} dispatches, mean batch "
-              f"{fmt(bat.get('batch_mean'), 2)}; fidelity: "
-              f"{fid.get('problems_audited')} problems audited, "
-              f"argmin_match=**{fid.get('argmin_match')}**, "
-              f"bitwise_match=**{fid.get('bitwise_match')}**", ""]
-    return lines
-
-
-def render_bench(path):
-    """Markdown lines for a committed BENCH_*.json record."""
-    rec = json.load(open(path))
-    lines = [f"# Bench record: {os.path.basename(path)} "
-             f"({rec.get('schema', '?')}, smoke={rec.get('smoke')})", ""]
-
-    if rec.get("schema") == "bench_serving/v1":
-        return render_serving(rec, lines)
-
-    wc = rec.get("warm_vs_cold", {})
-    if wc.get("grids"):
-        lines += ["## Warm-replay vs cold sweep", "",
-                  "| q | cold s | warm s | speedup | warm chol calls |",
-                  "|---|---|---|---|---|"]
-        for q, r in sorted(wc["grids"].items(), key=lambda kv: int(kv[0])):
-            lines.append(f"| {q} | {fmt(r['cold_s'])} | {fmt(r['warm_s'])} "
-                         f"| {fmt(r['warm_vs_cold_speedup'], 2)}x "
-                         f"| {r['warm_trace_cholesky_calls']} |")
-        lines.append("")
-
-    ov = rec.get("overlap_vs_serial", {})
-    if ov:
-        lines += ["## Pipelined early-stop vs serial full sweep", "",
-                  f"serial {fmt(ov.get('serial_s'))}s → early-stop "
-                  f"{fmt(ov.get('early_stop_s'))}s "
-                  f"(**{fmt(ov.get('overlap_vs_serial'), 2)}x**, "
-                  f"{ov.get('chunks_evaluated')}/{ov.get('chunks_total')} "
-                  f"chunks, argmin_match={ov.get('argmin_match')})", ""]
-
-    ps = rec.get("precision_sweep", {})
-    if ps.get("policies"):
-        lines += [f"## Mixed-precision sweep (h={ps.get('h')}, "
-                  f"q={ps.get('q')}, chunk={ps.get('chunk')})", "",
-                  "| policy | cold s | state bytes | packed B/λ | λ* |",
-                  "|---|---|---|---|---|"]
-        for pol in ("fp32", "bf16_store", "bf16_refined"):
-            r = ps["policies"].get(pol)
-            if r is None:
-                continue
-            lines.append(f"| {pol} | {fmt(r['cold_s'])} "
-                         f"| {fmt_bytes(r['state_bytes'])} "
-                         f"| {fmt_bytes(r['packed_bytes_per_lam'])} "
-                         f"| {fmt(r['best_lam'], 4)} |")
-        lines += ["",
-                  f"bf16_store vs fp32: "
-                  f"**{fmt(ps.get('speedup_bf16_store'), 2)}x** speed, "
-                  f"**{fmt(ps.get('mem_ratio_bf16_store'), 2)}x** state "
-                  f"memory; bf16_refined argmin_match="
-                  f"**{ps.get('argmin_match')}**", ""]
-    return lines
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--src", default="results/dryrun")
     ap.add_argument("--out", default="results/tables.md")
-    ap.add_argument("--bench", default=None,
-                    help="render a committed BENCH_*.json record instead "
-                         "of the dry-run tables")
     args = ap.parse_args()
-
-    if args.bench:
-        print("\n".join(render_bench(args.bench)))
-        return
 
     rows = []
     for f in sorted(glob.glob(os.path.join(args.src, "*.json"))):

@@ -9,9 +9,8 @@ verbatim for two jobs the engine cannot do for itself:
   implementations (same math, different execution structure), so a bug in
   the batching/sharding machinery cannot hide behind "both paths share the
   code";
-* **benchmark baseline** — ``benchmarks/bench_table3_timing.py`` reports
-  engine vs host-loop wall time; the gap is the paper's §5 "exploit the
-  architecture" claim made measurable.
+* **baseline** — the host-loop structure the engine's batched sweep
+  replaces (the paper's §5 "exploit the architecture" claim).
 
 Do not add features here; new work goes through the engine strategies.
 """

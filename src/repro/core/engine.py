@@ -73,7 +73,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.distributed import sharding as shardlib
 
 from . import factor_cache as cachelib
-from . import packing, picholesky, solvers
+from . import packing, picholesky, solvers, tracing
 from . import sketch as sketchlib
 from .backends import BackendLike, LinalgBackend, resolve_backend
 from .folds import CVResult, FoldData, holdout_nrmse
@@ -113,7 +113,20 @@ def _sample_grid(lams: jax.Array, g: int) -> jax.Array:
 
 def _errors_from_thetas(thetas: jax.Array, x_f: jax.Array,
                         y_f: jax.Array) -> jax.Array:
-    return jax.vmap(lambda t: holdout_nrmse(t, x_f, y_f))(thetas)
+    with tracing.scope(tracing.SCORE):
+        return jax.vmap(lambda t: holdout_nrmse(t, x_f, y_f))(thetas)
+
+
+def _split_stats(hess, grad, fold_hess, fold_grad):
+    """Per-fold train Hessians and gradients: the totals less each fold's."""
+    with tracing.scope(tracing.SPLIT):
+        return hess[None] - fold_hess, grad[None] - fold_grad
+
+
+def _packed_anchors(factors: jax.Array, block: int, bk) -> jax.Array:
+    """The anchor factors tile-packed, (g, P): the Θ fit's targets."""
+    with tracing.scope(tracing.THETA_FIT):
+        return bk.pack_tril(factors, block)
 
 
 # ------------------------------------------------------------------ protocol
@@ -192,8 +205,9 @@ class ExactCholesky(StrategyBase):
         return k * q
 
     def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
-        thetas = solvers.solve_cholesky_sweep(h_tr_f, g_tr_f, lams,
-                                              self.chol_fn, bk)
+        with tracing.scope("cv.exact"):
+            thetas = solvers.solve_cholesky_sweep(h_tr_f, g_tr_f, lams,
+                                                  self.chol_fn, bk)
         return _errors_from_thetas(thetas, x_f, y_f)
 
 
@@ -210,10 +224,12 @@ class _InterpolantErrors:
     sweep per λ chunk, riding inside the same O(chunk · P) budget."""
 
     def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
-        thetas = state.solve(lams, g_tr_f, backend=bk)       # (q_loc, h)
+        with tracing.scope(tracing.LAM_STAGE):
+            thetas = state.solve(lams, g_tr_f, backend=bk)   # (q_loc, h)
         if bk.precision.refine_iters:
-            thetas = picholesky.refine_solutions(state, h_tr_f, g_tr_f,
-                                                 lams, thetas, backend=bk)
+            with tracing.scope(tracing.REFINE):
+                thetas = picholesky.refine_solutions(
+                    state, h_tr_f, g_tr_f, lams, thetas, backend=bk)
         return _errors_from_thetas(thetas, x_f, y_f)
 
 
@@ -235,7 +251,8 @@ class PiCholeskyStrategy(_InterpolantErrors, StrategyBase):
         return k * self.g
 
     def prepare(self, x_folds, y_folds, h_tr, g_tr, lams, bk):
-        return _sample_grid(lams, self.g)
+        with tracing.scope(tracing.ANCHOR_CHOL):
+            return _sample_grid(lams, self.g)
 
     def fold_state(self, f_idx, h_tr_f, g_tr_f, aux, bk):
         return picholesky.fit(h_tr_f, aux, self.degree, block=self.block,
@@ -256,15 +273,15 @@ class PiCholeskyStrategy(_InterpolantErrors, StrategyBase):
         (g, P) so the engine can cache them — a later fit with a different
         degree/basis over the same anchors then refits from these targets
         with zero factorizations (``picholesky.fit(factors=...)``)."""
-        h = h_tr_f.shape[-1]
-        eye = jnp.eye(h, dtype=h_tr_f.dtype)
-        factors = jax.vmap(lambda lam: bk.cholesky(h_tr_f + lam * eye))(aux)
-        vec = bk.pack_tril(factors, self.block)
-        pf = packing.PackedFactor(vec=vec, h=h, block=self.block)
+        factors = picholesky.anchor_factors(h_tr_f, aux, bk.cholesky)
+        vec = _packed_anchors(factors, self.block, bk)
+        pf = packing.PackedFactor(vec=vec, h=h_tr_f.shape[-1],
+                                  block=self.block)
         model = picholesky.fit(h_tr_f, aux, self.degree, block=self.block,
                                basis=self.basis, factors=pf, backend=bk)
         # fit from the full-precision targets, cache at the storage dtype
-        return model, vec.astype(bk.precision.store_dtype(vec.dtype))
+        with tracing.scope(tracing.THETA_FIT):
+            return model, vec.astype(bk.precision.store_dtype(vec.dtype))
 
     def anchor_hessian(self, f_idx, h_tr_f, x_folds, bk):
         """Hessian the anchor factorizations run on — the exact per-fold
@@ -327,18 +344,20 @@ class PiCholeskySketched(PiCholeskyStrategy):
         return x_folds[others].reshape((k - 1) * n_f, h)
 
     def _sketched_hessian(self, f_idx, x_folds, bk):
-        x_tr = self._train_rows(f_idx, x_folds)
-        ad = bk.precision.accum_dtype(x_tr.dtype)
-        h_sk = sketchlib.sketched_gram(self._plan(), x_tr, f_idx,
-                                       accum_dtype=ad)
-        return h_sk.astype(x_tr.dtype)
+        with tracing.scope("cv.sketch"):
+            x_tr = self._train_rows(f_idx, x_folds)
+            ad = bk.precision.accum_dtype(x_tr.dtype)
+            h_sk = sketchlib.sketched_gram(self._plan(), x_tr, f_idx,
+                                           accum_dtype=ad)
+            return h_sk.astype(x_tr.dtype)
 
     def anchor_hessian(self, f_idx, h_tr_f, x_folds, bk):
         return self._sketched_hessian(f_idx, x_folds, bk)
 
     def prepare(self, x_folds, y_folds, h_tr, g_tr, lams, bk):
         self._plan()    # fail at trace time, not mid-vmap
-        return dict(anchors=_sample_grid(lams, self.g), x=x_folds)
+        with tracing.scope(tracing.ANCHOR_CHOL):
+            return dict(anchors=_sample_grid(lams, self.g), x=x_folds)
 
     def fold_state(self, f_idx, h_tr_f, g_tr_f, aux, bk):
         h_sk = self._sketched_hessian(f_idx, aux["x"], bk)
@@ -348,27 +367,29 @@ class PiCholeskySketched(PiCholeskyStrategy):
 
     def fold_state_and_anchors(self, f_idx, h_tr_f, g_tr_f, aux, bk):
         h_sk = self._sketched_hessian(f_idx, aux["x"], bk)
-        h = h_sk.shape[-1]
-        eye = jnp.eye(h, dtype=h_sk.dtype)
-        factors = jax.vmap(
-            lambda lam: bk.cholesky(h_sk + lam * eye))(aux["anchors"])
-        vec = bk.pack_tril(factors, self.block)
-        pf = packing.PackedFactor(vec=vec, h=h, block=self.block)
+        factors = picholesky.anchor_factors(h_sk, aux["anchors"],
+                                            bk.cholesky)
+        vec = _packed_anchors(factors, self.block, bk)
+        pf = packing.PackedFactor(vec=vec, h=h_sk.shape[-1],
+                                  block=self.block)
         model = picholesky.fit(h_sk, aux["anchors"], self.degree,
                                block=self.block, basis=self.basis,
                                factors=pf, backend=bk)
-        return model, vec.astype(bk.precision.store_dtype(vec.dtype))
+        with tracing.scope(tracing.THETA_FIT):
+            return model, vec.astype(bk.precision.store_dtype(vec.dtype))
 
     def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
         # The IHS loop IS refine_solutions with the exact Hessian: the
         # sketched interpolant preconditions, the residual is dense-exact.
         # Never reads aux — warm replay runs with aux=().
-        thetas = state.solve(lams, g_tr_f, backend=bk)
+        with tracing.scope(tracing.LAM_STAGE):
+            thetas = state.solve(lams, g_tr_f, backend=bk)
         iters = self._plan().ihs_iters + bk.precision.refine_iters
         if iters:
-            thetas = picholesky.refine_solutions(state, h_tr_f, g_tr_f,
-                                                 lams, thetas, backend=bk,
-                                                 iters=iters)
+            with tracing.scope(tracing.REFINE):
+                thetas = picholesky.refine_solutions(
+                    state, h_tr_f, g_tr_f, lams, thetas, backend=bk,
+                    iters=iters)
         return _errors_from_thetas(thetas, x_f, y_f)
 
     def cache_meta(self, lams):
@@ -417,34 +438,35 @@ class PiCholeskyWarmstart(_InterpolantErrors, StrategyBase):
 
     def prepare(self, x_folds, y_folds, h_tr, g_tr, lams, bk):
         chol = self.chol_fn or bk.cholesky
-        sample_full = _sample_grid(lams, self.g_first)
+        with tracing.scope(tracing.ANCHOR_CHOL):
+            sample_full = _sample_grid(lams, self.g_first)
+            sample_rest = _sample_grid(lams, max(self.g_rest, 1))
         base = picholesky.fit(h_tr[0], sample_full, self.degree,
                               block=self.block, chol_fn=chol, backend=bk)
-        sample_rest = _sample_grid(lams, max(self.g_rest, 1))
-        # residual regression runs at the policy's fit dtype (bf16-stored
-        # anchors must not degrade the damped least squares)
-        fit_dtype = bk.precision.fit_dtype(h_tr.dtype)
-        v_rest = picholesky.vandermonde(sample_rest, self.degree
-                                        ).astype(fit_dtype)
-        gram = v_rest.T @ v_rest
-        lhs = gram + self.mu * jnp.diag(jnp.diag(gram))
+        with tracing.scope("cv.warmstart"):
+            # residual regression runs at the policy's fit dtype
+            # (bf16-stored anchors must not degrade the damped least
+            # squares)
+            fit_dtype = bk.precision.fit_dtype(h_tr.dtype)
+            v_rest = picholesky.vandermonde(sample_rest, self.degree
+                                            ).astype(fit_dtype)
+            gram = v_rest.T @ v_rest
+            lhs = gram + self.mu * jnp.diag(jnp.diag(gram))
         return dict(sample_rest=sample_rest, v_rest=v_rest, lhs=lhs,
                     base_theta=base.theta, center=base.center)
 
     def fold_state(self, f_idx, h_tr_f, g_tr_f, aux, bk):
-        chol = self.chol_fn or bk.cholesky
-        h = h_tr_f.shape[-1]
-        eye = jnp.eye(h, dtype=h_tr_f.dtype)
-        factors = jax.vmap(lambda lam: chol(h_tr_f + lam * eye)
-                           )(aux["sample_rest"])
-        fit_dtype = aux["v_rest"].dtype
-        t = bk.pack_tril(factors, self.block).astype(fit_dtype)
-        resid = t - aux["v_rest"] @ aux["base_theta"].astype(fit_dtype)
-        dtheta = jnp.linalg.solve(aux["lhs"], aux["v_rest"].T @ resid)
-        theta = (aux["base_theta"].astype(fit_dtype) + dtheta
-                 ).astype(aux["base_theta"].dtype)
+        factors = picholesky.anchor_factors(
+            h_tr_f, aux["sample_rest"], self.chol_fn or bk.cholesky)
+        with tracing.scope("cv.warmstart"):
+            fit_dtype = aux["v_rest"].dtype
+            t = bk.pack_tril(factors, self.block).astype(fit_dtype)
+            resid = t - aux["v_rest"] @ aux["base_theta"].astype(fit_dtype)
+            dtheta = jnp.linalg.solve(aux["lhs"], aux["v_rest"].T @ resid)
+            theta = (aux["base_theta"].astype(fit_dtype) + dtheta
+                     ).astype(aux["base_theta"].dtype)
         return picholesky.PiCholesky(theta=theta, center=aux["center"],
-                                     h=h, block=self.block)
+                                     h=h_tr_f.shape[-1], block=self.block)
 
     def cache_meta(self, lams):
         if self.chol_fn is not None:
@@ -482,17 +504,19 @@ class SVDStrategy(StrategyBase):
         return dict(x=x_folds, y=y_folds)
 
     def fold_state(self, f_idx, h_tr_f, g_tr_f, aux, bk):
-        k, n_f, h = aux["x"].shape
-        others = (f_idx + 1 + jnp.arange(k - 1)) % k
-        x_tr = aux["x"][others].reshape((k - 1) * n_f, h)
-        y_tr = aux["y"][others].reshape(-1)
-        s, vt, uty = solvers.svd_ridge_factors(x_tr, y_tr, self.mode,
-                                               self.k_trunc, self.key)
+        with tracing.scope("cv.svd"):
+            k, n_f, h = aux["x"].shape
+            others = (f_idx + 1 + jnp.arange(k - 1)) % k
+            x_tr = aux["x"][others].reshape((k - 1) * n_f, h)
+            y_tr = aux["y"][others].reshape(-1)
+            s, vt, uty = solvers.svd_ridge_factors(x_tr, y_tr, self.mode,
+                                                   self.k_trunc, self.key)
         return dict(s=s, vt=vt, uty=uty)
 
     def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
-        thetas = solvers.svd_ridge_sweep(
-            (state["s"], state["vt"], state["uty"]), lams)
+        with tracing.scope("cv.svd"):
+            thetas = solvers.svd_ridge_sweep(
+                (state["s"], state["vt"], state["uty"]), lams)
         return _errors_from_thetas(thetas, x_f, y_f)
 
 
@@ -534,17 +558,19 @@ class LowRankStrategy(StrategyBase):
         return dict(x=x_folds)
 
     def fold_state(self, f_idx, h_tr_f, g_tr_f, aux, bk):
-        k, n_f, h = aux["x"].shape
-        others = (f_idx + 1 + jnp.arange(k - 1)) % k
-        x_tr = aux["x"][others].reshape((k - 1) * n_f, h)
-        return solvers.lowrank_ridge_factors(x_tr, self.rank,
-                                             precision=bk.precision)
+        with tracing.scope("cv.low_rank"):
+            k, n_f, h = aux["x"].shape
+            others = (f_idx + 1 + jnp.arange(k - 1)) % k
+            x_tr = aux["x"][others].reshape((k - 1) * n_f, h)
+            return solvers.lowrank_ridge_factors(x_tr, self.rank,
+                                                 precision=bk.precision)
 
     def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
         # never reads aux — warm replay runs with aux=()
-        thetas = solvers.lowrank_ridge_sweep(
-            state, g_tr_f, lams,
-            compute_dtype=bk.precision.accum_dtype(g_tr_f.dtype))
+        with tracing.scope("cv.low_rank"):
+            thetas = solvers.lowrank_ridge_sweep(
+                state, g_tr_f, lams,
+                compute_dtype=bk.precision.accum_dtype(g_tr_f.dtype))
         return _errors_from_thetas(thetas, x_f, y_f)
 
     def cache_meta(self, lams):
@@ -587,18 +613,22 @@ class PinrmseStrategy(StrategyBase):
                                                   self.chol_fn, bk)
             return _errors_from_thetas(thetas, x_f, y_f)
 
-        mean_err = jax.vmap(fold_curve)(h_tr, g_tr, x_folds, y_folds).mean(0)
-        # the curve fit runs at the policy's fit dtype (fp32 floor — the
-        # interpolated *errors* must not quantize), one definition shared
-        # with the factor fits instead of a local jax_enable_x64 probe
-        fit_dtype = bk.precision.fit_dtype(mean_err.dtype)
-        v = picholesky.vandermonde(sample, self.degree).astype(fit_dtype)
-        theta = jnp.linalg.solve(v.T @ v, v.T @ mean_err.astype(fit_dtype))
-        return theta
+        with tracing.scope("cv.pinrmse"):
+            mean_err = jax.vmap(fold_curve)(h_tr, g_tr, x_folds,
+                                            y_folds).mean(0)
+            # the curve fit runs at the policy's fit dtype (fp32 floor —
+            # the interpolated *errors* must not quantize), one definition
+            # shared with the factor fits instead of a local
+            # jax_enable_x64 probe
+            fit_dtype = bk.precision.fit_dtype(mean_err.dtype)
+            v = picholesky.vandermonde(sample, self.degree).astype(fit_dtype)
+            return jnp.linalg.solve(v.T @ v,
+                                    v.T @ mean_err.astype(fit_dtype))
 
     def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
-        v = picholesky.vandermonde(lams, self.degree).astype(aux.dtype)
-        return v @ aux  # identical on every fold ⇒ mean is the curve itself
+        with tracing.scope("cv.pinrmse"):
+            v = picholesky.vandermonde(lams, self.degree).astype(aux.dtype)
+            return v @ aux  # identical on every fold ⇒ mean is the curve
 
 
 STRATEGIES = {
@@ -809,8 +839,7 @@ class CVEngine:
         self._prepare = None      # jitted replicated prepare stage
         self._interp_engines: dict = {}  # (degree, basis) -> derived engine
         self._anchor_targets = None      # jitted anchor-factorize stage
-        self._split = jax.jit(
-            lambda hess, grad, fh, fg: (hess[None] - fh, grad[None] - fg))
+        self._split = jax.jit(_split_stats)
 
     # -- mesh -------------------------------------------------------------
 
@@ -967,11 +996,12 @@ class CVEngine:
         """
         q_loc = lams.shape[0]
         chunk = self._resolve_chunk(q_loc, h, dtype)
-        if chunk is None or chunk >= q_loc:
-            return errors_at(lams)
-        chunks, _ = shardlib.chunk_lams(lams, chunk)    # (n_c, chunk)
-        errs = jax.lax.map(errors_at, chunks)           # (n_c, k_loc, chunk)
-        return jnp.moveaxis(errs, 1, 0).reshape(k_loc, -1)[:, :q_loc]
+        with tracing.scope(tracing.LAM_STAGE):
+            if chunk is None or chunk >= q_loc:
+                return errors_at(lams)
+            chunks, _ = shardlib.chunk_lams(lams, chunk)  # (n_c, chunk)
+            errs = jax.lax.map(errors_at, chunks)         # (n_c, k_loc, chunk)
+            return jnp.moveaxis(errs, 1, 0).reshape(k_loc, -1)[:, :q_loc]
 
     def _core(self, h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux):
         """(k_loc folds) × (q_loc λs) error grid — runs per device shard."""
@@ -1137,11 +1167,15 @@ class CVEngine:
     # with a block after every dispatch — the serial reference the parity
     # tests compare bit-for-bit against.
 
+    @contextlib.contextmanager
     def _stage_scope(self, label: str):
-        """Counting scope for stage-granular backends (CountingBackend);
-        a no-op context for plain backends."""
+        """The host span ``cv.<label>`` around a staged dispatch, and the
+        counting scope of stage-granular backends (CountingBackend)."""
         stage = getattr(self._bk, "stage", None)
-        return stage(label) if callable(stage) else contextlib.nullcontext()
+        counting = (stage(label) if callable(stage)
+                    else contextlib.nullcontext())
+        with tracing.span(f"cv.{label}"), counting:
+            yield
 
     def _prepare_fn(self):
         if self._prepare is None:
@@ -1294,11 +1328,7 @@ class CVEngine:
         aux: Any = ()
         warm = False
         if meta is not None:
-            key = cachelib.make_key(
-                h_tr, meta["anchors"], block=meta["params"]["block"],
-                backend=bk.name, params=meta["params"],
-                precision=self._prec.descriptor(),
-                sketch=meta.get("sketch", "exact"))
+            key = self._cache_key(h_tr, meta)
 
             def cold_state(with_anchors):
                 state, pf, _ = self._pipelined_state(
@@ -1419,7 +1449,8 @@ class CVEngine:
             if not pipelined:
                 jax.block_until_ready(e)
             width = min(chunk, q - c * chunk)
-            fold_errs = np.asarray(e)[:, :width]    # syncs this chunk only
+            with tracing.span("cv.fetch"):
+                fold_errs = np.asarray(e)[:, :width]  # syncs this chunk only
             mean = fold_errs.mean(0)
             finite = np.isfinite(mean)
             if not finite.all() and stop_tol is not None:
@@ -1641,7 +1672,8 @@ class CVEngine:
             with self._stage_scope("fold_errors"):
                 e = chunk_fn(state, f_idx, h_tr, g_tr, folds.x_folds,
                              folds.y_folds, jnp.asarray(lam_w), aux)
-            return lam_w, np.asarray(e).mean(0)
+            with tracing.span("cv.fetch"):
+                return lam_w, np.asarray(e).mean(0)
 
         lo = float(np.log10(lams_np.min()))
         hi = float(np.log10(lams_np.max()))
@@ -1754,10 +1786,9 @@ class CVEngine:
             def targets(h_tr, anchors, x_folds):
                 def per_fold(f, h_f):
                     h_eff = strat.anchor_hessian(f, h_f, x_folds, bk)
-                    eye = jnp.eye(h_eff.shape[-1], dtype=h_eff.dtype)
-                    factors = jax.vmap(
-                        lambda lam: bk.cholesky(h_eff + lam * eye))(anchors)
-                    return bk.pack_tril(factors, strat.block)
+                    factors = picholesky.anchor_factors(h_eff, anchors,
+                                                        bk.cholesky)
+                    return _packed_anchors(factors, strat.block, bk)
                 return jax.vmap(per_fold)(jnp.arange(h_tr.shape[0]), h_tr)
 
             self._anchor_targets = _jit(targets)
@@ -1794,10 +1825,7 @@ class CVEngine:
         meta = strat.cache_meta(lams)
         key = None
         if self.cache is not None and meta is not None:
-            key = cachelib.make_key(
-                h_tr, meta["anchors"], block=strat.block, backend=bk.name,
-                params=meta["params"], precision=self._prec.descriptor(),
-                sketch=meta.get("sketch", "exact"))
+            key = self._cache_key(h_tr, meta)
         pf = (self.cache.get_anchors(key)
               if key is not None and self.reuse else None)
         status = "anchors"
@@ -1858,9 +1886,8 @@ class CVEngine:
         allocation in bytes, excluding inputs/outputs.
 
         This is the measurable form of the O(chunk · P) memory contract —
-        the packed-pipeline acceptance test and the committed
-        ``BENCH_table3.json`` record both read it, so there is exactly one
-        definition of "the sweep's peak memory".
+        the packed-pipeline acceptance test reads it, so there is exactly
+        one definition of "the sweep's peak memory".
         """
         lams = jnp.asarray(lams)
         h_tr, g_tr = self._split(folds.hess, folds.grad, folds.fold_hess,
@@ -1873,9 +1900,8 @@ class CVEngine:
         """XLA temp bytes of the λ-stream (replay) stage alone, from a
         fitted state — the policy-governed O(chunk · P) working set without
         the ``fold_state`` factorization buffers.  This is the quantity the
-        precision policy's storage dtype halves (the committed
-        ``precision_sweep`` bench record reads it), measured the same way
-        as :meth:`sweep_temp_bytes`."""
+        precision policy's storage dtype halves, measured the same way as
+        :meth:`sweep_temp_bytes`."""
         lams = jnp.asarray(lams)
         h_tr, g_tr = self._split(folds.hess, folds.grad, folds.fold_hess,
                                  folds.fold_grad)
@@ -1884,6 +1910,15 @@ class CVEngine:
         lowered = self._replay_fn(None).lower(
             state, h_tr, g_tr, folds.x_folds, folds.y_folds, lams)
         return int(lowered.compile().memory_analysis().temp_size_in_bytes)
+
+    def _cache_key(self, h_tr, meta: dict) -> cachelib.CacheKey:
+        """The cache key of a sweep's λ-independent inputs, fingerprinted
+        (and its bytes counted) by the attached cache."""
+        return self.cache.fingerprint(
+            h_tr, meta["anchors"], block=meta["params"]["block"],
+            backend=self._bk.name, params=meta["params"],
+            precision=self._prec.descriptor(),
+            sketch=meta.get("sketch", "exact"))
 
     def _acquire_cached_state(self, meta: dict, key, cold_state_fn):
         """Cache dispatch shared by :meth:`run` and :meth:`sweep_async`:
@@ -1921,11 +1956,7 @@ class CVEngine:
                     lams_run: jax.Array, q: int):
         """Warm-replay dispatch: fingerprint → (hit | anchor refit | cold
         populate) → replay.  Returns (error grid, cache_info, n_chol)."""
-        key = cachelib.make_key(
-            h_tr, meta["anchors"], block=meta["params"]["block"],
-            backend=self._bk.name, params=meta["params"],
-            precision=self._prec.descriptor(),
-            sketch=meta.get("sketch", "exact"))
+        key = self._cache_key(h_tr, meta)
         k = h_tr.shape[0]
 
         def cold_state(with_anchors):
@@ -1963,22 +1994,26 @@ class CVEngine:
         else:
             lams_run = lams
 
-        # engine-owned train-stat buffers: safe to donate into the sweep
-        h_tr, g_tr = self._split(folds.hess, folds.grad,
-                                 folds.fold_hess, folds.fold_grad)
-        meta = (self.strategy.cache_meta(lams)
-                if self.cache is not None
-                and hasattr(self.strategy, "cache_meta") else None)
-        if meta is not None:
-            errs, cache_info, n_chol = self._run_cached(
-                meta, mesh, h_tr, g_tr, folds, lams_run, q)
-        else:
-            errs = self._sweep_fn(mesh)(h_tr, g_tr, folds.x_folds,
-                                        folds.y_folds, lams_run)
-            cache_info = (None if self.cache is None
-                          else dict(status="bypass"))
-            n_chol = self.strategy.n_exact_chol(k, q)
-        errs = np.asarray(errs)[:, :q]
+        with tracing.span("cv.run", h=int(folds.fold_hess.shape[-1]), k=k,
+                          q=int(q)) as span:
+            # engine-owned train-stat buffers: safe to donate into the sweep
+            h_tr, g_tr = self._split(folds.hess, folds.grad,
+                                     folds.fold_hess, folds.fold_grad)
+            meta = (self.strategy.cache_meta(lams)
+                    if self.cache is not None
+                    and hasattr(self.strategy, "cache_meta") else None)
+            if meta is not None:
+                errs, cache_info, n_chol = self._run_cached(
+                    meta, mesh, h_tr, g_tr, folds, lams_run, q)
+            else:
+                errs = self._sweep_fn(mesh)(h_tr, g_tr, folds.x_folds,
+                                            folds.y_folds, lams_run)
+                cache_info = (None if self.cache is None
+                              else dict(status="bypass"))
+                n_chol = self.strategy.n_exact_chol(k, q)
+            span.set_metadata(status=(cache_info or {}).get("status", "none"))
+            with tracing.span("cv.fetch"):
+                errs = np.asarray(errs)[:, :q]
         return CVResult.from_errors(
             lams, errs.mean(0), n_chol,
             engine=dict(
@@ -2069,12 +2104,8 @@ class CVEngine:
         cache = self.cache
         splits = [self._split(f.hess, f.grad, f.fold_hess, f.fold_grad)
                   for f, _ in problems]
-        keys = [cachelib.make_key(
-            h_tr, m["anchors"], block=m["params"]["block"],
-            backend=self._bk.name, params=m["params"],
-            precision=self._prec.descriptor(),
-            sketch=m.get("sketch", "exact"))
-            for (h_tr, _), m in zip(splits, metas)]
+        keys = [self._cache_key(h_tr, m)
+                for (h_tr, _), m in zip(splits, metas)]
         with_anchors = (self.cache_anchors
                         and hasattr(strat, "fold_state_and_anchors"))
 
@@ -2150,17 +2181,21 @@ class CVEngine:
         results = []
         for i, ((folds_i, lams_i), (h_tr, g_tr)) in enumerate(
                 zip(problems, splits)):
-            with self._stage_scope("fold_errors"):
-                errs = replay(entries[i].state, h_tr, g_tr, folds_i.x_folds,
-                              folds_i.y_folds, lams_i)
             k_i, q_i = h_tr.shape[0], int(lams_i.shape[0])
+            with tracing.span("cv.run", h=int(h_tr.shape[-1]), k=k_i, q=q_i,
+                              status=statuses[i]):
+                with self._stage_scope("fold_errors"):
+                    errs = replay(entries[i].state, h_tr, g_tr,
+                                  folds_i.x_folds, folds_i.y_folds, lams_i)
+                with tracing.span("cv.fetch"):
+                    errs = np.asarray(errs)
             n_chol = (strat.n_exact_chol(k_i, q_i)
                       if statuses[i] == "miss" else 0)
             info = dict(status=statuses[i],
                         digest=entries[i].key.digest()[:12],
                         policy=self.reuse, tenant=tenants[i], **cache.stats)
             results.append(CVResult.from_errors(
-                lams_i, np.asarray(errs).mean(0), n_chol,
+                lams_i, errs.mean(0), n_chol,
                 engine=dict(strategy=strat.name, backend=self._bk.name,
                             precision=self._prec.name, mesh=None,
                             donated=bool(self.donate),

@@ -70,7 +70,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 
-from . import packing, picholesky, solvers
+from . import packing, picholesky, solvers, tracing
 
 __all__ = ["CacheKey", "CacheEntry", "FactorCache", "array_hash",
            "hessian_fingerprint", "make_key", "INDEX_FILENAME"]
@@ -177,15 +177,20 @@ def make_key(h_tr, anchors, *, block: int, backend: str,
     (:meth:`~repro.core.precision.PrecisionPolicy.descriptor`).
     ``sketch``: the anchor-production descriptor (``'exact'`` | a
     :meth:`~repro.core.sketch.SketchPlan.descriptor` | ``'lowrank/r…'``).
+
+    Host spans: ``cache.fingerprint`` around it all, ``cache.d2h`` around
+    the Hessians' copy to the host; the rest is hashing.
     """
-    h_tr = np.asarray(h_tr)
-    return CacheKey(
-        fold_hashes=hessian_fingerprint(h_tr),
-        anchors=tuple(float(a) for a in np.asarray(anchors).ravel()),
-        h=int(h_tr.shape[-1]), block=int(block),
-        dtype=str(h_tr.dtype), backend=str(backend),
-        params=tuple(sorted(params.items())),
-        precision=str(precision), sketch=str(sketch))
+    with tracing.span("cache.fingerprint", bytes=int(h_tr.nbytes)):
+        with tracing.span("cache.d2h"):
+            h_tr = np.asarray(h_tr)
+        return CacheKey(
+            fold_hashes=hessian_fingerprint(h_tr),
+            anchors=tuple(float(a) for a in np.asarray(anchors).ravel()),
+            h=int(h_tr.shape[-1]), block=int(block),
+            dtype=str(h_tr.dtype), backend=str(backend),
+            params=tuple(sorted(params.items())),
+            precision=str(precision), sketch=str(sketch))
 
 
 def _tree_nbytes(tree) -> int:
@@ -265,9 +270,10 @@ class FactorCache:
     purged with the entry), so a stale hit is impossible.
 
     Counters (``hits`` / ``misses`` / ``anchor_hits`` / ``evictions`` /
-    ``bytes_saved``) are cumulative over the cache's lifetime — eviction
-    never rewrites history (the *resident* saving is the separate
-    :attr:`live_bytes_saved`); tests and the warm-vs-cold bench read them
+    ``bytes_saved`` / ``fingerprint_bytes``, the host bytes hashed into
+    keys by :meth:`fingerprint`) are cumulative over the cache's lifetime
+    — eviction never rewrites history (the *resident* saving is the
+    separate :attr:`live_bytes_saved`); tests and the benchmark read them
     via :attr:`stats`.
 
     Multi-tenant deployments partition the read/write counters per tenant
@@ -296,6 +302,7 @@ class FactorCache:
         #: old live-entries-only accounting made an eviction retroactively
         #: rewrite the reported saving)
         self.bytes_saved = 0
+        self.fingerprint_bytes = 0
         self.tenant_stats: Dict[str, Dict[str, int]] = {}
         self._tenant: Optional[str] = None
         self._tick = 0
@@ -322,7 +329,15 @@ class FactorCache:
                     evictions=self.evictions, bytes=self.total_bytes,
                     bytes_saved=self.bytes_saved,
                     live_bytes_saved=self.live_bytes_saved,
+                    fingerprint_bytes=self.fingerprint_bytes,
                     max_bytes=self.max_bytes)
+
+    def fingerprint(self, h_tr, anchors, **params) -> CacheKey:
+        """:func:`make_key`, counting the Hessian bytes it hashes on the
+        host into :attr:`fingerprint_bytes`."""
+        key = make_key(h_tr, anchors, **params)
+        self.fingerprint_bytes += int(h_tr.nbytes)
+        return key
 
     # ------------------------------------------------- per-tenant counters
 
@@ -367,6 +382,21 @@ class FactorCache:
         if policy not in ("exact", "covering"):
             raise ValueError(f"unknown reuse policy {policy!r}; "
                              "expected 'exact' or 'covering'")
+        with tracing.span("cache.lookup") as span:
+            entry = self._find(key, policy)
+            span.set_metadata(result="miss" if entry is None else "hit")
+        if entry is None:
+            self.misses += 1
+            self._tenant_count("misses")
+            return None
+        self.hits += 1
+        self._tenant_count("hits")
+        entry.hits += 1
+        self._touch(entry)
+        return entry
+
+    def _find(self, key: CacheKey, policy: str) -> Optional[CacheEntry]:
+        """The entry serving ``key`` under ``policy``, or None."""
         entry = self.entries.get(key.digest())
         if entry is not None and entry.state is None:
             entry = None        # anchors-only entry: no Θ to serve
@@ -385,28 +415,22 @@ class FactorCache:
                     width = c_hi - c_lo
                     if best_width is None or width < best_width:
                         best_width, entry = width, cand
-        if entry is None:
-            self.misses += 1
-            self._tenant_count("misses")
-            return None
-        self.hits += 1
-        self._tenant_count("hits")
-        entry.hits += 1
-        self._touch(entry)
         return entry
 
     def get_anchors(self, key: CacheKey) -> Optional[packing.PackedFactor]:
         """Cached packed anchor factors for ``key``'s anchor fingerprint
         (degree/basis-independent), or None.  Counts as an anchor hit."""
-        digest = self._by_anchor.get(key.anchor_digest())
-        if digest is None:
-            return None
-        entry = self.entries[digest]
-        if entry.anchors is not None:  # entry may have been repopulated bare
+        with tracing.span("cache.lookup") as span:
+            digest = self._by_anchor.get(key.anchor_digest())
+            entry = None if digest is None else self.entries[digest]
+            anchors = None if entry is None else entry.anchors
+            span.set_metadata(result="anchor miss" if anchors is None
+                              else "anchor hit")
+        if anchors is not None:  # entry may have been repopulated bare
             self.anchor_hits += 1
             self._tenant_count("anchor_hits")
             self._touch(entry)
-        return entry.anchors
+        return anchors
 
     # --------------------------------------------------------------- write
 
@@ -418,22 +442,23 @@ class FactorCache:
         if state is None and anchors is None:
             raise ValueError("refusing to cache an empty entry: "
                              "need a fitted state, packed anchors, or both")
-        digest = key.digest()
-        nbytes = _tree_nbytes((state, anchors))
-        baseline = _tree_nbytes_at((state, anchors), key.dtype)
-        entry = CacheEntry(key=key, state=state, anchors=anchors,
-                           nbytes=nbytes,
-                           bytes_saved=max(0, baseline - nbytes))
-        self.bytes_saved += entry.bytes_saved
-        self._tenant_count("puts")
-        if digest not in self.entries:
-            self._by_base.setdefault(key.base_digest(), []).append(digest)
-        self.entries[digest] = entry
-        if anchors is not None:
-            self._by_anchor[key.anchor_digest()] = digest
-        self._touch(entry)
-        self._evict_to_budget(keep=digest)
-        return entry
+        with tracing.span("cache.lookup", result="put"):
+            digest = key.digest()
+            nbytes = _tree_nbytes((state, anchors))
+            baseline = _tree_nbytes_at((state, anchors), key.dtype)
+            entry = CacheEntry(key=key, state=state, anchors=anchors,
+                               nbytes=nbytes,
+                               bytes_saved=max(0, baseline - nbytes))
+            self.bytes_saved += entry.bytes_saved
+            self._tenant_count("puts")
+            if digest not in self.entries:
+                self._by_base.setdefault(key.base_digest(), []).append(digest)
+            self.entries[digest] = entry
+            if anchors is not None:
+                self._by_anchor[key.anchor_digest()] = digest
+            self._touch(entry)
+            self._evict_to_budget(keep=digest)
+            return entry
 
     # ------------------------------------------------------ byte-budget LRU
 

@@ -24,12 +24,12 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from . import packing
+from . import packing, tracing
 from .backends import BackendLike, resolve_backend
 
-__all__ = ["PiCholesky", "fit", "evaluate", "evaluate_packed", "vandermonde",
-           "choose_sample_lambdas", "refine_solutions", "loo_interp_scores",
-           "select_interpolant"]
+__all__ = ["PiCholesky", "fit", "anchor_factors", "evaluate",
+           "evaluate_packed", "vandermonde", "choose_sample_lambdas",
+           "refine_solutions", "loo_interp_scores", "select_interpolant"]
 
 
 def vandermonde(lams: jax.Array, degree: int, center: float | jax.Array = 0.0) -> jax.Array:
@@ -156,32 +156,40 @@ def fit(
             raise ValueError(
                 f"packed factors have (h={factors.h}, block={factors.block}); "
                 f"fit called with (h={h}, block={block})")
-        targets = factors.vec
-    else:
-        if factors is None:
-            eye = jnp.eye(h, dtype=hessian.dtype)
-            factors = jax.vmap(lambda lam: chol_fn(hessian + lam * eye)
-                               )(sample_lams)
+        factors = factors.vec
+    elif factors is None:
+        factors = anchor_factors(hessian, sample_lams, chol_fn)
+    with tracing.scope(tracing.THETA_FIT):
         # Step 2: tile-packed target matrix T (g × P) — aligned BLAS-3 layout.
-        targets = bk.pack_tril(factors, block)
+        targets = (factors if factors.ndim == 2
+                   else bk.pack_tril(factors, block))
+        center = (jnp.mean(sample_lams) if basis == "centered"
+                  else jnp.zeros((), sample_lams.dtype))
+        fit_dtype = bk.precision.fit_dtype(targets.dtype)
+        store_dtype = bk.precision.store_dtype(targets.dtype)
+        v = vandermonde(sample_lams, degree, center).astype(fit_dtype)
 
-    center = jnp.mean(sample_lams) if basis == "centered" else jnp.zeros((), sample_lams.dtype)
-    fit_dtype = bk.precision.fit_dtype(targets.dtype)
-    store_dtype = bk.precision.store_dtype(targets.dtype)
-    v = vandermonde(sample_lams, degree, center).astype(fit_dtype)
+        # Steps 5–6: Θ = (VᵀV)⁻¹ VᵀT — normal equations exactly as in the
+        # paper, at the fit dtype; Θ is then stored at the storage dtype.
+        # One packed tile of columns at a time: a fold-batched solve
+        # against the whole (r+1, P) right-hand side compiled for v5e to
+        # 13.2 GiB of temporaries for the 5-fold state at h=4096, against
+        # 4.8 GiB this way.
+        h_lam = v.T @ v
+        tiles = targets.astype(fit_dtype).reshape(g, -1, block * block)
+        theta = jax.lax.map(lambda t: jnp.linalg.solve(h_lam, v.T @ t),
+                            jnp.moveaxis(tiles, 1, 0))     # (n, r+1, B²)
+        theta = jnp.moveaxis(theta, 0, 1).reshape(degree + 1, -1)
+        return PiCholesky(theta=theta.astype(store_dtype),
+                          center=center.astype(fit_dtype), h=h, block=block)
 
-    # Steps 5–6: Θ = (VᵀV)⁻¹ VᵀT — normal equations exactly as in the
-    # paper, at the fit dtype; Θ is then stored at the storage dtype.  One
-    # packed tile of columns at a time: a fold-batched solve against the
-    # whole (r+1, P) right-hand side compiled for v5e to 13.2 GiB of
-    # temporaries for the 5-fold state at h=4096, against 4.8 GiB this way.
-    h_lam = v.T @ v
-    tiles = targets.astype(fit_dtype).reshape(g, -1, block * block)
-    theta = jax.lax.map(lambda t: jnp.linalg.solve(h_lam, v.T @ t),
-                        jnp.moveaxis(tiles, 1, 0))         # (n, r+1, B²)
-    theta = jnp.moveaxis(theta, 0, 1).reshape(degree + 1, -1)
-    return PiCholesky(theta=theta.astype(store_dtype),
-                      center=center.astype(fit_dtype), h=h, block=block)
+
+def anchor_factors(hessian: jax.Array, sample_lams: jax.Array,
+                   chol_fn: Callable[[jax.Array], jax.Array]) -> jax.Array:
+    """Step 1: the g exact factorizations ``chol(H + λ_s I)``, (g, h, h)."""
+    with tracing.scope(tracing.ANCHOR_CHOL):
+        eye = jnp.eye(hessian.shape[-1], dtype=hessian.dtype)
+        return jax.vmap(lambda lam: chol_fn(hessian + lam * eye))(sample_lams)
 
 
 def loo_interp_scores(

@@ -18,6 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import tracing
 from .backends import BackendLike, resolve_backend
 
 __all__ = [
@@ -69,7 +70,8 @@ def solve_cholesky(hessian: jax.Array, g: jax.Array, lam: jax.Array,
     bk = resolve_backend(backend)
     chol_fn = chol_fn or bk.cholesky
     h = hessian.shape[-1]
-    l = chol_fn(hessian + lam * jnp.eye(h, dtype=hessian.dtype))
+    with tracing.scope(tracing.ANCHOR_CHOL):
+        l = chol_fn(hessian + lam * jnp.eye(h, dtype=hessian.dtype))
     return bk.solve_from_factor(l, g)
 
 

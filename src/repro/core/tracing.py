@@ -1,0 +1,81 @@
+"""Stage names for the profiler, on the device and on the host.
+
+Two helpers, one name table.  :func:`scope` is ``jax.named_scope``: opened
+inside traced code, it costs nothing at run time and lands in the
+``op_name`` metadata of every HLO op traced under it, so a device trace
+can attribute each op to the stage that issued it.  :func:`span` is
+``jax.profiler.TraceAnnotation``: a host event in the profiler's own
+trace, on the same clock as the device ops (about a microsecond when no
+trace is running).  Nested scopes and spans resolve to the innermost.
+
+Device scopes, opened inside the jitted stage functions (a scope around a
+call to an already compiled function would add nothing):
+
+``cv.split``
+    site: the function ``CVEngine._split`` jits.  Covers: the per-fold
+    train Hessians and gradients from the fold stats.
+``cv.anchor_chol``
+    site: ``picholesky.anchor_factors`` (every anchor-factorizing path) and
+    the anchor grid of ``prepare``.  Covers: the g anchor factorizations
+    and the shifted Hessians they read; other strategies' factorizations.
+``cv.theta_fit``
+    site: ``picholesky.fit`` from ``pack_tril`` to the returned Θ (so also
+    ``CVEngine._refit_from_anchors``).  Covers: packing of the anchor
+    factors and the normal equations.
+``cv.lam_stage``
+    site: ``_InterpolantErrors.fold_errors`` around ``state.solve``, and
+    ``CVEngine._stream_errors``.  Covers: ``interp_solve`` (both
+    substitution sweeps, the diagonal-block inversion, Horner, pads) and
+    the ``lax.map`` chunking of the λ grid.
+``cv.refine``
+    site: around ``picholesky.refine_solutions``.  Covers: the refinement
+    sweeps (``bf16_refined``).
+``cv.score``
+    site: ``_errors_from_thetas``.  Covers: hold-out scoring.
+``cv.<strategy>``
+    the rest of a non-piCholesky strategy: ``cv.exact``, ``cv.svd``,
+    ``cv.low_rank``, ``cv.sketch``, ``cv.warmstart``, ``cv.pinrmse``.
+
+Host spans:
+
+``cv.run``
+    ``CVEngine.run``, and one per problem of ``run_batch``; args ``h``,
+    ``k``, ``q`` and the cache ``status`` (``miss`` / ``hit`` / ``refit``
+    / ``bypass``, ``none`` without a cache).
+``cache.fingerprint``
+    ``factor_cache.make_key``; arg ``bytes``.  Inside it ``cache.d2h``,
+    the Hessians' copy to the host; the rest is hashing.
+``cache.lookup``
+    ``FactorCache.lookup`` / ``get_anchors`` / ``put``; arg ``result``.
+``cv.fetch``
+    the curve's copy to the host in ``run``, ``sweep_async``, ``search``
+    and ``run_batch``.
+``cv.prepare``, ``cv.fold_state``, ``cv.fold_errors``
+    the staged dispatches, through ``CVEngine._stage_scope``.
+
+The counter beside them is ``FactorCache.fingerprint_bytes``: the host
+bytes hashed into cache keys, in ``FactorCache.stats``.
+"""
+from __future__ import annotations
+
+import jax
+
+SPLIT = "cv.split"
+ANCHOR_CHOL = "cv.anchor_chol"
+THETA_FIT = "cv.theta_fit"
+LAM_STAGE = "cv.lam_stage"
+REFINE = "cv.refine"
+SCORE = "cv.score"
+#: the device scopes of the piCholesky pipeline, in pipeline order
+SCOPES = (SPLIT, ANCHOR_CHOL, THETA_FIT, LAM_STAGE, REFINE, SCORE)
+
+
+def scope(name: str):
+    """A device scope: ``jax.named_scope``, for use inside traced code."""
+    return jax.named_scope(name)
+
+
+def span(name: str, **args):
+    """A host span: ``jax.profiler.TraceAnnotation`` with its args as the
+    event's stats.  More args can follow with ``set_metadata``."""
+    return jax.profiler.TraceAnnotation(name, **args)

@@ -148,6 +148,7 @@ def _factor_panel(panel: jax.Array, block: int, interpret: bool,
         out_shape=jax.ShapeDtypeStruct(panel.shape, panel.dtype),
         scratch_shapes=[vmem_scratch((block, block), panel.dtype)],
         interpret=interpret,
+        name="cholesky_panel",
     )(panel)
 
 
@@ -166,6 +167,7 @@ def _syrk_update(trailing: jax.Array, panel: jax.Array, block: int,
         out_specs=pl.BlockSpec((block, block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(trailing.shape, trailing.dtype),
         interpret=interpret,
+        name="cholesky_syrk",
     )(panel, panel, trailing)
 
 

@@ -170,6 +170,7 @@ def solve_lower_packed(vec: jax.Array, g: jax.Array, h: int, block: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hp, q), g2.dtype),
         interpret=interpret,
+        name="packed_trsm_upper" if transpose else "packed_trsm_lower",
     )(idx, inv_diag, g2, tiles)
     w = w[:h]
     return w[:, 0] if squeeze else w

@@ -99,6 +99,7 @@ def interp_factors(theta: jax.Array, lams: jax.Array, h: int, block: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q, nt * block, nt * block), theta.dtype),
         interpret=interpret,
+        name="interp_factors",
     )(pidx, x[None], theta_t)
     return out[:, :h, :h]
 
@@ -199,6 +200,7 @@ def _interp_sweep(theta_t: jax.Array, x: jax.Array, inv_diag: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q, hp, nrhs), g.dtype),
         interpret=interpret,
+        name="interp_solve_bwd" if reverse else "interp_solve_fwd",
     )(idx, x[None], inv_diag, g, theta_t)
 
 

@@ -75,6 +75,7 @@ def pack_tril(mat: jax.Array, block: int = 128, *, interpret: bool | None = None
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, block, block), mat.dtype),
         interpret=interpret,
+        name="pack_tril",
     )(idx, mat)
     return out.reshape(-1)
 
@@ -106,5 +107,6 @@ def unpack_tril(vec: jax.Array, h: int, block: int = 128, *, interpret: bool | N
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nt * block, nt * block), vec.dtype),
         interpret=interpret,
+        name="unpack_tril",
     )(pidx, packed)
     return out[:h, :h]

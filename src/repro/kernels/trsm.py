@@ -113,6 +113,7 @@ def solve_lower_blocked(l: jax.Array, g: jax.Array, block: int = 256, *,
         out_specs=pl.BlockSpec((hp, q), lambda step: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((hp, q), g2.dtype),
         interpret=interpret,
+        name="trsm_upper" if transpose else "trsm_lower",
     )(mat, inv_diag, g2)
     w = w[:h]
     return w[:, 0] if squeeze else w

@@ -11,7 +11,7 @@ Hessians from one shared content-addressed
 partitioning and result isolation.
 
 :mod:`~repro.serving.traffic` generates the deterministic Zipf-mix
-synthetic workload the committed ``BENCH_serving.json`` record measures.
+synthetic workload the serving tests replay.
 """
 from .server import CVSweepServer, ServerConfig, SweepRequest, SweepResponse
 from .traffic import TrafficConfig, make_traffic

@@ -9,9 +9,8 @@ optional shifted range (different anchors → admission into a separate
 group).  Tenants round-robin over the request stream, so hot problems
 are shared across tenants by construction.
 
-Everything is a pure function of :class:`TrafficConfig` — the committed
-``BENCH_serving.json`` record and the serving tests replay the exact
-same stream.
+Everything is a pure function of :class:`TrafficConfig` — the serving
+tests replay the exact same stream.
 """
 from __future__ import annotations
 
