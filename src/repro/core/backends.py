@@ -109,6 +109,14 @@ class LinalgBackend:
         """Dense interpolated factors (q, h, h) — debug / dense consumers."""
         raise NotImplementedError
 
+    def interp_lam_chunk(self, h: int, block: int, q: int, store_dtype, *,
+                         degree: int, budget: int) -> int:
+        """λs per :meth:`interp_solve` call, at most ``q``, by what the call
+        holds per λ: here its (chunk, P) interpolated rows at
+        ``store_dtype``, within ``budget`` bytes."""
+        from repro.distributed import sharding
+        return min(q, sharding.auto_lam_chunk(h, block, store_dtype, budget))
+
 
 @dataclasses.dataclass(frozen=True)
 class ReferenceBackend(LinalgBackend):
@@ -266,6 +274,15 @@ class PallasBackend(LinalgBackend):
         return interp_factors(theta, jnp.atleast_1d(lams), h, block,
                               center=center)
 
+    def interp_lam_chunk(self, h, block, q, store_dtype, *, degree, budget):
+        """The kernel builds no factor, so ``budget`` does not bind: its
+        chunk is what one sweep's VMEM holds
+        (:func:`~repro.kernels.poly_interp.sweep_lam_chunk`)."""
+        from repro.kernels.packed_trsm import _resolve_dtypes
+        from repro.kernels.poly_interp import sweep_lam_chunk
+        cd, ad = _resolve_dtypes(store_dtype, *self._dtypes(store_dtype))
+        return sweep_lam_chunk(h, block, q, 1, degree, cd, ad)
+
 
 class CountingBackend(LinalgBackend):
     """Delegating wrapper that counts calls to ``cholesky`` — the
@@ -371,6 +388,10 @@ class CountingBackend(LinalgBackend):
     def interp_factors(self, theta, lams, *, h, block, center=0.0):
         return self.inner.interp_factors(theta, lams, h=h, block=block,
                                          center=center)
+
+    def interp_lam_chunk(self, h, block, q, store_dtype, *, degree, budget):
+        return self.inner.interp_lam_chunk(h, block, q, store_dtype,
+                                           degree=degree, budget=budget)
 
 
 BackendLike = Union[None, str, LinalgBackend]

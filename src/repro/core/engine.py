@@ -679,9 +679,11 @@ class SweepChunk:
     cache: Optional[dict]    # warm-replay cache info (None without a cache)
 
 
-#: HBM/VMEM budget (bytes) the ``lam_chunk='auto'`` heuristic sizes the
-#: per-chunk packed-factor working set against — one VMEM's worth, so the
-#: streamed sweep's λ-dependent footprint matches what a TPU core can hold.
+#: Budget (bytes) the ``lam_chunk='auto'`` rule sizes a chunk's per-λ
+#: factors against where the λ stage builds them: (chunk, P) packed rows on
+#: the reference path, dense factors for the non-interpolant strategies.
+#: The Pallas ``interp_solve`` builds none; its chunk is the kernel's own
+#: VMEM rule (:meth:`~repro.core.backends.LinalgBackend.interp_lam_chunk`).
 LAM_CHUNK_BUDGET_BYTES = 16 * 1024 * 1024
 
 
@@ -703,9 +705,13 @@ class CVEngine:
     lam_chunk: λ-axis streaming: the per-device λ shard is processed in
                fixed-size chunks under an outer ``lax.map``, so the sweep's
                peak memory is O(chunk · P) regardless of the grid size q.
-               ``'auto'`` (default) sizes the chunk so one chunk's packed
-               factors fit :data:`LAM_CHUNK_BUDGET_BYTES`; an ``int`` fixes
-               it; ``None`` disables streaming (whole shard in one call).
+               ``'auto'`` (default) sizes the chunk by what the λ stage
+               holds per λ: on the Pallas ``interp_solve`` path, the
+               kernel's VMEM working set (the whole shard at the paper's
+               sizes: every Θ tile read once per chunk serves all its λs);
+               elsewhere, one chunk's packed or dense factors within
+               :data:`LAM_CHUNK_BUDGET_BYTES`.  An ``int`` fixes it;
+               ``None`` disables streaming (whole shard in one call).
                Requires ``fold_errors`` to be λ-elementwise — true of every
                built-in strategy (each λ's solve/score is independent).
     cache:     a :class:`~repro.core.factor_cache.FactorCache` enabling the
@@ -899,22 +905,47 @@ class CVEngine:
     # -- λ chunking --------------------------------------------------------
 
     def _resolve_chunk(self, q_loc: int, h: int, dtype) -> Optional[int]:
-        """Static chunk size for a (q_loc,) λ shard, or None (no streaming).
-
-        The VMEM-auto heuristic budgets the chunk's packed working set at
-        the policy's *storage* dtype — bf16 storage doubles the chunk at
-        the same byte budget.
-        """
+        """Static chunk size for a (q_loc,) λ shard, or None (no streaming)."""
         if self.lam_chunk is None:
             return None
         if self.lam_chunk == "auto":
-            block = getattr(self.strategy, "block", None) or self.block or 128
-            return shardlib.auto_lam_chunk(
-                h, block, self._prec.store_dtype(dtype),
-                LAM_CHUNK_BUDGET_BYTES)
+            return self._auto_chunk(q_loc, h, dtype)
         chunk = int(self.lam_chunk)
         if chunk <= 0:
             raise ValueError(f"lam_chunk must be positive, got {chunk}")
+        return chunk
+
+    def _auto_chunk(self, q_loc: int, h: int, dtype,
+                    block: Optional[int] = None) -> int:
+        """The ``lam_chunk='auto'`` rule, at most ``q_loc``: what the λ
+        stage holds per λ, at the policy's *storage* dtype, decides it.
+        Interpolant strategies ask their backend's ``interp_solve``; the
+        others build a factor per λ, budgeted as packed rows.  The
+        autotuner's chunk ladder centres on this same rule."""
+        block = (block or getattr(self.strategy, "block", None)
+                 or self.block or 128)
+        store = self._prec.store_dtype(dtype)
+        if isinstance(self.strategy, _InterpolantErrors):
+            return self._bk.interp_lam_chunk(
+                h, block, q_loc, store, degree=self.strategy.degree,
+                budget=LAM_CHUNK_BUDGET_BYTES)
+        return min(q_loc, shardlib.auto_lam_chunk(h, block, store,
+                                                  LAM_CHUNK_BUDGET_BYTES))
+
+    def _lam_chunk_used(self, q_loc: int, h: int, dtype) -> int:
+        """λs per λ-stage call on a (q_loc,) shard: the counter
+        ``lam_chunk_resolved`` of ``extras['engine']``."""
+        chunk = self._resolve_chunk(q_loc, h, dtype)
+        return q_loc if chunk is None else min(chunk, q_loc)
+
+    def _stage_chunk(self, q: int, h: int, dtype, mesh) -> int:
+        """λs per dispatch of the staged sweep's chunk stage (whole grid
+        when streaming is off), padded to the λ mesh axis."""
+        chunk = self._resolve_chunk(q, h, dtype)
+        if chunk is None or chunk > q:
+            chunk = q
+        if mesh is not None:
+            chunk += (-chunk) % mesh.shape[shardlib.CV_LAM_AXIS]
         return chunk
 
     # -- roofline-guided autotuning ---------------------------------------
@@ -995,9 +1026,9 @@ class CVEngine:
         warm-replay path, so the memory contract has one implementation.
         """
         q_loc = lams.shape[0]
-        chunk = self._resolve_chunk(q_loc, h, dtype)
+        chunk = self._lam_chunk_used(q_loc, h, dtype)
         with tracing.scope(tracing.LAM_STAGE):
-            if chunk is None or chunk >= q_loc:
+            if chunk == q_loc:
                 return errors_at(lams)
             chunks, _ = shardlib.chunk_lams(lams, chunk)  # (n_c, chunk)
             errs = jax.lax.map(errors_at, chunks)         # (n_c, k_loc, chunk)
@@ -1413,11 +1444,7 @@ class CVEngine:
 
         # fixed-size chunk schedule (last chunk edge-padded) so one jitted
         # chunk stage serves the whole stream
-        chunk = self._resolve_chunk(q, h, h_tr.dtype)
-        if chunk is None or chunk > q:
-            chunk = q
-        if mesh is not None:
-            chunk += (-chunk) % mesh.shape[shardlib.CV_LAM_AXIS]
+        chunk = self._stage_chunk(q, h, h_tr.dtype, mesh)
         chunks, _ = shardlib.chunk_lams(lams, chunk)
         n_c = chunks.shape[0]
 
@@ -1527,12 +1554,16 @@ class CVEngine:
         errors = np.concatenate([p.errors for p in parts])
         lams_eval = np.concatenate([p.lams for p in parts])
         mesh = self._resolve_mesh(folds.fold_hess.shape[0])
+        n_lam = 1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS]
+        chunk = self._stage_chunk(int(jnp.shape(lams)[0]),
+                                  folds.fold_hess.shape[-1],
+                                  folds.fold_hess.dtype, mesh)
         meta = dict(
             strategy=self.strategy.name, backend=self._bk.name,
             precision=self._prec.name,
             mesh=None if mesh is None else dict(mesh.shape),
             donated=bool(self.donate), lam_chunk=self.lam_chunk,
-            cache=last.cache)
+            lam_chunk_resolved=chunk // n_lam, cache=last.cache)
         meta["async"] = dict(
             pipelined=pipelined, stop_tol=stop_tol,
             stop_patience=stop_patience, stopped=last.stopped,
@@ -1645,7 +1676,8 @@ class CVEngine:
                                  folds.fold_hess, folds.fold_grad)
         strat = self.strategy
 
-        chunk = self._resolve_chunk(q, h, h_tr.dtype)
+        # a wave is one chunk-stage dispatch of at most 8 λs
+        chunk = self._resolve_chunk(8, h, h_tr.dtype)
         if wave is None:
             w = max(3, min(8, chunk if chunk else 8))
         else:
@@ -1737,6 +1769,8 @@ class CVEngine:
             precision=self._prec.name,
             mesh=None if mesh is None else dict(mesh.shape),
             donated=bool(self.donate), lam_chunk=self.lam_chunk,
+            lam_chunk_resolved=w // (1 if mesh is None else
+                                     mesh.shape[shardlib.CV_LAM_AXIS]),
             cache=cache_info)
         meta["search"] = dict(
             wave=w, waves=waves, lams_evaluated=n_eval, dense_q=q,
@@ -2014,6 +2048,7 @@ class CVEngine:
             span.set_metadata(status=(cache_info or {}).get("status", "none"))
             with tracing.span("cv.fetch"):
                 errs = np.asarray(errs)[:, :q]
+        n_lam = 1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS]
         return CVResult.from_errors(
             lams, errs.mean(0), n_chol,
             engine=dict(
@@ -2021,6 +2056,9 @@ class CVEngine:
                 precision=self._prec.name,
                 mesh=None if mesh is None else dict(mesh.shape),
                 donated=bool(self.donate), lam_chunk=self.lam_chunk,
+                lam_chunk_resolved=self._lam_chunk_used(
+                    int(lams_run.shape[0]) // n_lam,
+                    folds.fold_hess.shape[-1], folds.fold_hess.dtype),
                 cache=cache_info))
 
     # -- batched admission (multi-tenant serving) ---------------------------
@@ -2199,7 +2237,10 @@ class CVEngine:
                 engine=dict(strategy=strat.name, backend=self._bk.name,
                             precision=self._prec.name, mesh=None,
                             donated=bool(self.donate),
-                            lam_chunk=self.lam_chunk, cache=info,
+                            lam_chunk=self.lam_chunk,
+                            lam_chunk_resolved=self._lam_chunk_used(
+                                q_i, h_tr.shape[-1], h_tr.dtype),
+                            cache=info,
                             batch=dict(size=n, index=i,
                                        cold=len(cold_idx)))))
         return results

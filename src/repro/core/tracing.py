@@ -53,8 +53,16 @@ Host spans:
 ``cv.prepare``, ``cv.fold_state``, ``cv.fold_errors``
     the staged dispatches, through ``CVEngine._stage_scope``.
 
-The counter beside them is ``FactorCache.fingerprint_bytes``: the host
-bytes hashed into cache keys, in ``FactorCache.stats``.
+Counters beside them:
+
+``FactorCache.fingerprint_bytes``
+    in ``FactorCache.stats``: the host bytes hashed into cache keys.
+``lam_chunk_resolved``
+    in ``extras['engine']`` of every result that records ``lam_chunk``
+    (``run``, ``run_async``, ``search``, ``run_batch``): the λs one call
+    of the λ stage takes on a device, the configured ``lam_chunk``
+    resolved; with ``'auto'`` on the Pallas path, the λ columns each read
+    of a Θ tile serves.
 """
 from __future__ import annotations
 

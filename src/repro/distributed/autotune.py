@@ -46,7 +46,7 @@ import json
 import math
 import os
 import shutil
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -261,17 +261,18 @@ def candidate_lattice(*, h: int, k: int, q: int, n_devices: int,
                       blocks: Optional[Sequence[int]] = None,
                       chunks: Optional[Sequence[int]] = None,
                       mesh_shapes: Optional[Sequence] = None,
-                      store_dtype=None,
-                      budget: Optional[int] = None) -> List[TunedConfig]:
+                      auto_chunk: Optional[Callable[[int, int], int]] = None
+                      ) -> List[TunedConfig]:
     """The legal configuration lattice for one problem geometry.
 
     ``default`` (the engine's untuned configuration) is always the first
     element — the search can only ever match or beat its prediction, and
     ties resolve to it.  Blocks whose padded single-tile layout coincides
     (block ≥ 2·2^ceil(log2(h)) beyond the first covering tile) are pruned
-    by the ``block >= 2 * h`` guard; per-block chunk ladders follow the
-    block's own packed bytes (a wider block pads more, so its VMEM-auto
-    chunk is smaller).
+    by the ``block >= 2 * h`` guard.  Per-block chunk ladders centre on
+    ``auto_chunk(block, q_loc)``, the engine's ``lam_chunk='auto'`` rule
+    (:meth:`~repro.core.engine.CVEngine._auto_chunk`), else on the
+    default's chunk.
     """
     blocks = tuple(blocks) if blocks is not None else DEFAULT_BLOCKS
     blocks = tuple(dict.fromkeys(
@@ -296,9 +297,8 @@ def candidate_lattice(*, h: int, k: int, q: int, n_devices: int,
         for block in blocks:
             if chunks is not None:
                 ladder = tuple(max(1, min(int(c), q_loc)) for c in chunks)
-            elif store_dtype is not None and budget is not None:
-                auto = shardlib.auto_lam_chunk(h, block, store_dtype, budget)
-                ladder = chunk_ladder(auto, q_loc)
+            elif auto_chunk is not None:
+                ladder = chunk_ladder(auto_chunk(block, q_loc), q_loc)
             else:
                 ladder = chunk_ladder(default.lam_chunk, q_loc)
             for chunk in dict.fromkeys(ladder):
@@ -436,12 +436,11 @@ def tune(engine, folds, lams, *, cache: Optional[TuningCache] = None,
         if hit is not None:
             return dataclasses.replace(hit, source="cache")
 
-    store_dtype = engine._prec.store_dtype(dtype)
-    from repro.core.engine import LAM_CHUNK_BUDGET_BYTES
     cands = candidate_lattice(
         h=h, k=k, q=q, n_devices=n_devices, default=default,
         blocks=blocks, chunks=chunks, mesh_shapes=mesh_shapes,
-        store_dtype=store_dtype, budget=LAM_CHUNK_BUDGET_BYTES)
+        auto_chunk=lambda block, q_loc: engine._auto_chunk(
+            q_loc, h, dtype, block))
     scored = score_candidates(engine, folds, lams, cands, hw=hw, cache=cache)
     # strict < over a default-first list: ties (and equal-cost degenerate
     # candidates) resolve to the default configuration

@@ -182,8 +182,10 @@ def pad_to_multiple(x: jax.Array, multiple: int, axis: int = 0):
 def auto_lam_chunk(h: int, block: int, dtype, budget: int) -> int:
     """λ-chunk size whose per-chunk packed working set fits ``budget`` bytes.
 
-    One definition shared by the engine's ``lam_chunk='auto'`` heuristic
-    and the benches, so "the chunk that fits one VMEM" cannot drift.
+    The ``lam_chunk='auto'`` rule wherever the λ stage builds a factor per
+    λ: the reference ``interp_solve``'s (chunk, P) rows, the
+    non-interpolant strategies' factors
+    (:meth:`~repro.core.engine.CVEngine._auto_chunk`).
     ``dtype`` is the *storage* dtype of the streamed interpolant rows
     (:meth:`~repro.core.precision.PrecisionPolicy.store_dtype`) — halving
     the itemsize (bf16) doubles the chunk at the same budget, which is the

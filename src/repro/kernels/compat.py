@@ -16,8 +16,10 @@ vmem_scratch = pltpu.VMEM
 SMEM = pltpu.SMEM
 
 
-def mxu_dot(a: jax.Array, b: jax.Array, out_dtype) -> jax.Array:
-    """``a @ b`` accumulated at ``out_dtype``.
+def mxu_dot(a: jax.Array, b: jax.Array, out_dtype, *,
+            transpose_b: bool = False) -> jax.Array:
+    """``a @ b`` (``a @ bᵀ`` with ``transpose_b``) accumulated at
+    ``out_dtype``; the transposed form is the MXU's own, no copy of ``b``.
 
     The MXU multiplies f32 operands as one bf16 pass unless asked for full
     precision, which the factorizations cannot afford: a rank-B update at
@@ -27,4 +29,6 @@ def mxu_dot(a: jax.Array, b: jax.Array, out_dtype) -> jax.Array:
     """
     precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
                  else jax.lax.Precision.DEFAULT)
-    return jnp.dot(a, b, precision=precision, preferred_element_type=out_dtype)
+    dims = (((1,), (1 if transpose_b else 0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=out_dtype)

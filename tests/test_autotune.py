@@ -33,8 +33,9 @@ def test_lattice_default_first_and_legal():
                                    source="default")
     cands = autotune.candidate_lattice(
         h=24, k=4, q=16, n_devices=4, default=default,
-        blocks=(8, 16, 32), store_dtype=jnp.float32,
-        budget=64 * 1024)
+        blocks=(8, 16, 32), auto_chunk=lambda block, q_loc: min(
+            q_loc, shardlib.auto_lam_chunk(24, block, jnp.float32,
+                                           64 * 1024)))
     assert cands[0] is default
     keys = [c.key() for c in cands]
     assert len(keys) == len(set(keys))          # deduped
@@ -99,8 +100,9 @@ def test_tune_zero_candidate_executions():
     scored = autotune.score_candidates(
         eng, folds, lams, autotune.candidate_lattice(
             h=24, k=4, q=16, n_devices=len(jax.devices()), default=default,
-            blocks=(32, 64), mesh_shapes=[None], store_dtype=jnp.float32,
-            budget=64 * 1024))
+            blocks=(32, 64), mesh_shapes=[None],
+            auto_chunk=lambda block, q_loc: eng._auto_chunk(
+                q_loc, 24, jnp.float32, block)))
     assert calls["n"] == 0
     assert min(s.predicted_s for s in scored) == pytest.approx(
         cfg.predicted_s)

@@ -121,6 +121,99 @@ def test_interp_solve_kernel(h, block, degree):
     np.testing.assert_allclose(out, exact, rtol=1e-6, atol=1e-8)
 
 
+# (degree, h, block, m, rhs_per_lam, q, chunk, compute): h a multiple of
+# the block and not (identity tail), m right-hand sides, q % chunk ≠ 0
+LAM_BATCH_CASES = [
+    (1, 32, 8, 1, False, 1, 1, None),
+    (2, 32, 8, 1, False, 5, 2, None),
+    (2, 37, 8, 3, False, 8, 3, None),
+    (3, 37, 8, 1, True, 8, 5, None),
+    (3, 32, 8, 3, True, 5, 3, None),
+    (1, 37, 8, 3, True, 5, 2, None),
+    (2, 48, 16, 1, False, 8, 3, "bfloat16"),
+    (1, 37, 8, 3, True, 5, 2, "bfloat16"),
+    (3, 32, 8, 1, True, 8, 3, "bfloat16"),
+    (2, 37, 8, 1, False, 1, 1, "bfloat16"),
+    (3, 37, 8, 3, False, 8, 5, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize(
+    "degree,h,block,m,rhs_per_lam,q,chunk,compute", LAM_BATCH_CASES)
+def test_interp_solve_lam_batched(degree, h, block, m, rhs_per_lam, q,
+                                  chunk, compute):
+    """Every λ of a call shares each Θ read: the batched solve ≡ the
+    reference (packed rows, then packed substitution), ≡ the same call
+    one λ at a time, ≡ the grid in edge-padded chunks."""
+    from repro.core.backends import ReferenceBackend
+    from repro.core.precision import PRESETS
+    from repro.distributed import sharding as shardlib
+
+    a = _spd(h, jnp.float32)
+    sample = picholesky.choose_sample_lambdas(1e-2, 1.0, degree + 3)
+    model = picholesky.fit(a, sample, degree, block=block)
+    lams = jnp.logspace(-2, 0, q, dtype=jnp.float32)
+    shape = ((q,) if rhs_per_lam else ()) + (h,) + ((m,) if m > 1 else ())
+    g = jax.random.normal(jax.random.PRNGKey(7), shape, jnp.float32)
+    if compute is None:
+        theta, kw, policy, tol = model.theta.astype(jnp.float32), {}, \
+            "fp32", 1e-5
+    else:   # bf16 storage and MXU operands, f32 accumulation
+        theta = model.theta.astype(jnp.bfloat16)
+        kw = dict(compute_dtype=compute, accum_dtype="float32")
+        policy, tol = "bf16_store", 3e-2
+
+    def solve(lam, rhs):
+        return interp_solve(theta, lam, rhs, h, block, center=model.center,
+                            rhs_per_lam=rhs_per_lam, **kw)
+
+    out = solve(lams, g)
+    assert out.shape == (q,) + shape[int(rhs_per_lam):]
+    assert out.dtype == jnp.float32
+    expect = ReferenceBackend(precision=PRESETS[policy]).interp_solve(
+        theta, lams, g, h=h, block=block, center=model.center,
+        rhs_per_lam=rhs_per_lam)
+    scale = float(jnp.max(jnp.abs(expect)))
+    np.testing.assert_allclose(out, expect, rtol=0, atol=tol * scale)
+
+    one = jnp.concatenate([solve(lams[i:i + 1],
+                                 g[i:i + 1] if rhs_per_lam else g)
+                           for i in range(q)])
+    np.testing.assert_allclose(out, one, rtol=0, atol=1e-6 * scale)
+    lam_c, _ = shardlib.chunk_lams(lams, chunk)
+    g_c = (jnp.pad(g, [(0, lam_c.size - q)] + [(0, 0)] * (g.ndim - 1))
+           if rhs_per_lam else None)
+    chunked = jnp.concatenate([
+        solve(lam_c[j], g_c[j * chunk:(j + 1) * chunk] if rhs_per_lam else g)
+        for j in range(lam_c.shape[0])])[:q]
+    np.testing.assert_allclose(out, chunked, rtol=0, atol=1e-6 * scale)
+
+
+def test_interp_solve_splits_lams_beyond_its_vmem(monkeypatch):
+    """More λs than one sweep's VMEM holds go through the kernel in
+    chunks, with the same solutions."""
+    from repro.kernels import poly_interp
+
+    h, block, q = 29, 8, 7
+    a = _spd(h, jnp.float32)
+    model = picholesky.fit(a, picholesky.choose_sample_lambdas(1e-2, 1.0, 5),
+                           2, block=block)
+    theta = model.theta.astype(jnp.float32)
+    lams = jnp.logspace(-2, 0, q, dtype=jnp.float32)
+    g = jax.random.normal(jax.random.PRNGKey(8), (h,), jnp.float32)
+    whole = interp_solve(theta, lams, g, h, block, center=model.center)
+    budget = poly_interp.sweep_vmem_bytes(h, block, 3, 1, 2, jnp.float32,
+                                          jnp.float32)
+    monkeypatch.setattr(poly_interp, "SWEEP_VMEM_BYTES", budget)
+    assert poly_interp.sweep_lam_chunk(h, block, q, 1, 2, jnp.float32,
+                                       jnp.float32) == 3
+    interp_solve.clear_cache()
+    split = interp_solve(theta, lams, g, h, block, center=model.center)
+    interp_solve.clear_cache()
+    np.testing.assert_allclose(split, whole, rtol=0,
+                               atol=1e-6 * float(jnp.max(jnp.abs(whole))))
+
+
 @pytest.mark.parametrize("h,block,degree", [(32, 8, 2), (48, 16, 3)])
 def test_poly_interp_kernel(h, block, degree):
     a = _spd(h, jnp.float32)

@@ -272,6 +272,53 @@ def test_auto_chunk_sized_to_vmem_budget():
             1024, 64, jnp.float64)
 
 
+@pytest.mark.parametrize("precision", ["fp32", "bf16_refined", "bf16_store"])
+def test_auto_chunk_follows_the_lam_stage(precision):
+    """``lam_chunk='auto'`` counts what the λ stage holds per λ.  The
+    Pallas ``interp_solve`` builds no factor, only VMEM columns, so the
+    paper grid (h=4096, block 128, q=31) is one chunk; a shard under the
+    kernel's cap is taken whole.  The reference path builds (chunk, P)
+    rows, and the exact strategy dense factors: both keep the P rule."""
+    h, block, f32 = 4096, 128, jnp.float32
+    strat = engine.PiCholeskyStrategy(g=4, block=block)
+    pallas = engine.CVEngine(strat, backend="pallas", precision=precision)
+    assert pallas._resolve_chunk(31, h, f32) == 31
+    cap = pallas._resolve_chunk(10_000, h, f32)
+    assert 31 <= cap < 10_000
+    if precision != "fp32":   # bf16 storage: more λs per VMEM
+        fp32 = engine.CVEngine(strat, backend="pallas", precision="fp32")
+        assert cap > fp32._resolve_chunk(10_000, h, f32)
+    assert pallas._resolve_chunk(7, h, f32) == 7
+    store = engine.CVEngine(strat, precision=precision)._prec.store_dtype(f32)
+    p_rule = shardlib.auto_lam_chunk(h, block, store,
+                                     engine.LAM_CHUNK_BUDGET_BYTES)
+    ref = engine.CVEngine(strat, backend="reference", precision=precision)
+    assert ref._resolve_chunk(31, h, f32) == p_rule == 1
+    exact = engine.CVEngine("exact", backend="pallas", precision=precision)
+    assert exact._resolve_chunk(31, h, f32) == p_rule
+
+
+def test_lam_chunk_resolved_recorded(folds4):
+    """Each entry point that records ``lam_chunk`` records the λs one
+    λ-stage call took beside it."""
+    from repro.core.factor_cache import FactorCache
+
+    def resolved(res):
+        return res.extras["engine"]["lam_chunk_resolved"]
+
+    strat = engine.PiCholeskyStrategy(g=4, block=16)
+    eng = engine.CVEngine(strat, lam_chunk=7)
+    assert resolved(eng.run(folds4, LAMS)) == 7
+    assert resolved(eng.run_async(folds4, LAMS)) == 7
+    assert resolved(eng.search(folds4, LAMS)) == 7     # waves of min(8, 7)
+    assert resolved(engine.CVEngine(strat, lam_chunk=None
+                                    ).run(folds4, LAMS)) == 31
+    cached = engine.CVEngine(strat, cache=FactorCache())
+    assert cached._resolve_chunk(31, 64, jnp.float64) == 31
+    (res,) = cached.run_batch([(folds4, LAMS)])
+    assert resolved(res) == 31
+
+
 # ------------------------------------------- constant-memory acceptance
 
 

@@ -86,9 +86,10 @@ def test_solve_packed_compiles(one_chip):
              _spec(one_chip, (PACKED,)), _spec(one_chip, (H,)))
 
 
+@pytest.mark.parametrize("q", [Q, 31])   # 31: the paper grid in one call
 @pytest.mark.parametrize("matmul_precision", [None, "highest"])
 @pytest.mark.parametrize("compute", [None, "bfloat16"])
-def test_interp_solve_compiles(one_chip, compute, matmul_precision):
+def test_interp_solve_compiles(one_chip, compute, matmul_precision, q):
     # the engine traces its stages under "highest"; Mosaic refuses that
     # precision for bf16 operands, so the kernel must choose its own
     store = jnp.float32 if compute is None else jnp.bfloat16
@@ -98,7 +99,7 @@ def test_interp_solve_compiles(one_chip, compute, matmul_precision):
                      t, lam, g, H, BLOCK, interpret=False,
                      compute_dtype=compute, accum_dtype=accum),
                  _spec(one_chip, (DEGREE + 1, PACKED), store),
-                 _spec(one_chip, (Q,)), _spec(one_chip, (H,)))
+                 _spec(one_chip, (q,)), _spec(one_chip, (H,)))
 
 
 def test_solve_lower_blocked_compiles(one_chip):
