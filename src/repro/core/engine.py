@@ -167,6 +167,11 @@ class StrategyBase:
     #: must leave this False.
     batchable_state: bool = False
 
+    #: True when the strategy can divide its state stage's factorizations
+    #: over the devices of a mesh axis (``divided_state``) — the engine
+    #: then does so over the λ axis, whose devices hold the same folds.
+    divisible_state: bool = False
+
     def prepare(self, x_folds, y_folds, h_tr, g_tr, lams, bk):
         return ()
 
@@ -246,6 +251,7 @@ class PiCholeskyStrategy(_InterpolantErrors, StrategyBase):
     name: str = "picholesky"
     state_uses_hessian = True
     batchable_state = True
+    divisible_state = True
 
     def n_exact_chol(self, k, q):
         return k * self.g
@@ -282,6 +288,44 @@ class PiCholeskyStrategy(_InterpolantErrors, StrategyBase):
         # fit from the full-precision targets, cache at the storage dtype
         with tracing.scope(tracing.THETA_FIT):
             return model, vec.astype(bk.precision.store_dtype(vec.dtype))
+
+    def divided_state(self, h_tr, aux, bk, n: int, axis: str):
+        """``fold_state`` of every fold of ``h_tr`` (k, h, h), its k·g
+        anchor factorizations divided over the ``n`` devices of mesh axis
+        ``axis`` (:class:`~repro.distributed.sharding.PairLayout`), inside
+        the engine's ``shard_map`` body: this device factorizes and packs
+        its own (fold, anchor) pairs, an all-to-all gives it one tile slab
+        of every pair, it fits Θ on that slab for every fold, and a gather
+        gives every device every fold's Θ.  Returns ``(state batched over
+        the k folds, this device's packed anchors (per_device, P) at the
+        storage dtype)``."""
+        k, h = h_tr.shape[0], h_tr.shape[-1]
+        lay = shardlib.PairLayout(k, self.g, n, h, self.block)
+        fold, anchor = lay.pairs(jax.lax.axis_index(axis))
+        with tracing.scope(tracing.ANCHOR_CHOL):
+            hess = h_tr[fold]
+        factors = picholesky.pair_factors(hess, aux[anchor],
+                                          self.chol_fn or bk.cholesky)
+        vec = _packed_anchors(factors, self.block, bk)   # (per_device, P)
+        size = vec.shape[-1]
+        with tracing.scope(tracing.ANCHOR_EXCHANGE):
+            slabs = jnp.pad(vec, ((0, 0), (0, n * lay.slab - size)))
+            got = jax.lax.all_to_all(slabs, axis, 1, 0, tiled=True)
+            targets = got[:k * self.g].reshape(k, self.g, lay.slab)
+        with tracing.scope(tracing.THETA_FIT):
+            theta, center = jax.vmap(lambda t: picholesky.fit_targets(
+                t, aux, self.degree, block=self.block, basis=self.basis,
+                backend=bk))(targets)
+        with tracing.scope(tracing.ANCHOR_EXCHANGE):
+            # gathered on a new leading axis: a tiled gather along the
+            # packed axis compiled for v5e in 2.4 times the time at h=4096
+            theta = jax.lax.all_gather(theta, axis)       # (n, k, r+1, slab)
+            theta = jnp.moveaxis(theta, 0, 2).reshape(
+                k, self.degree + 1, -1)[..., :size]
+        state = picholesky.PiCholesky(theta=theta, center=center, h=h,
+                                      block=self.block)
+        with tracing.scope(tracing.THETA_FIT):
+            return state, vec.astype(bk.precision.store_dtype(vec.dtype))
 
     def anchor_hessian(self, f_idx, h_tr_f, x_folds, bk):
         """Hessian the anchor factorizations run on — the exact per-fold
@@ -326,6 +370,7 @@ class PiCholeskySketched(PiCholeskyStrategy):
     name: str = "picholesky_sketched"
     state_uses_hessian = False
     batchable_state = False
+    divisible_state = False
 
     def __post_init__(self):
         object.__setattr__(self, "sketch", sketchlib.as_plan(self.sketch))
@@ -696,9 +741,20 @@ class CVEngine:
     strategy:  a :class:`CVStrategy` instance or registry name.
     backend:   ``'auto'`` (Pallas on TPU, reference elsewhere) | ``'pallas'``
                | ``'reference'`` | a :class:`LinalgBackend`.
-    mesh:      ``None`` (single device), ``'auto'`` (2-D folds × lams mesh
-               over all local devices), or an explicit 2-D Mesh whose axes
-               are ``(CV_FOLD_AXIS, CV_LAM_AXIS)``.
+    mesh:      ``None`` (default: chosen from the problem's geometry — one
+               device while the one-device sweep fits what device 0 has
+               free, by :func:`~repro.distributed.sharding.sweep_bytes` of
+               (k, g, h, n, dtype) against
+               :func:`~repro.distributed.sharding.device_bytes_free`, else
+               the ``'auto'`` mesh over every local device; a device that
+               reports no limit, the CPU, always fits), ``'auto'`` (2-D
+               folds × lams mesh over all local devices), or an explicit
+               2-D Mesh whose axes are ``(CV_FOLD_AXIS, CV_LAM_AXIS)``.
+               Where the λ axis holds more than one device, the devices
+               of a λ row hold the same folds, and a strategy that can
+               divide its state stage (``divisible_state``: piCholesky)
+               deals the (fold, anchor) factorizations out over them
+               (:meth:`PiCholeskyStrategy.divided_state`).
     donate:    donate the per-fold training Hessians into the jitted sweep
                (``None`` = on except on CPU, where XLA cannot alias).
     block:     Pallas kernel tile size override for small test problems.
@@ -849,16 +905,65 @@ class CVEngine:
 
     # -- mesh -------------------------------------------------------------
 
-    def _resolve_mesh(self, k: int) -> Optional[Mesh]:
+    def _resolve_mesh(self, folds: FoldData) -> Optional[Mesh]:
+        """The sweep's mesh for ``folds``; with ``mesh=None`` the default
+        rule reads their geometry (:meth:`_fit_mesh`)."""
         if self.mesh is None:
-            return None
+            return self._fit_mesh(folds)
         if isinstance(self.mesh, Mesh):
             return self.mesh
         if self.mesh == "auto":
             if len(jax.devices()) == 1:
                 return None
-            return shardlib.make_cv_mesh(k)
+            return shardlib.make_cv_mesh(folds.fold_hess.shape[0])
         raise ValueError(f"mesh must be None, 'auto' or a Mesh; got {self.mesh!r}")
+
+    def _fit_mesh(self, folds: FoldData) -> Optional[Mesh]:
+        """The default mesh: none while the one-device sweep fits what
+        device 0 has free, else the ``'auto'`` mesh over every local
+        device."""
+        devices = jax.local_devices()
+        free = shardlib.device_bytes_free(devices[0])
+        if len(devices) == 1 or free is None:
+            return None
+        k, n_f, h = folds.x_folds.shape
+        need = shardlib.sweep_bytes(k, getattr(self.strategy, "g", 1), h,
+                                    k * n_f, folds.fold_hess.dtype.itemsize)
+        return None if need <= free else shardlib.make_cv_mesh(k, devices)
+
+    def _state_division(self, mesh: Optional[Mesh]) -> int:
+        """Devices each λ row divides the state stage over: the λ axis
+        when the strategy can divide its state, else 1 (each device runs
+        the whole state stage of its folds)."""
+        if mesh is None or not getattr(self.strategy, "divisible_state",
+                                       False):
+            return 1
+        return mesh.shape[shardlib.CV_LAM_AXIS]
+
+    def _shard_counts(self, mesh: Optional[Mesh], folds: FoldData,
+                      q_loc: int, ran: bool) -> dict:
+        """``extras['engine']['shard']``: the devices, the factorizations
+        one device ran for the problem and the bytes it received in the
+        anchor exchange (``ran`` False: the state came from the cache)."""
+        k, h = folds.fold_hess.shape[0], folds.fold_hess.shape[-1]
+        devices = 1 if mesh is None else mesh.devices.size
+        k_loc = k if mesh is None else k // mesh.shape[shardlib.CV_FOLD_AXIS]
+        n_div = self._state_division(mesh)
+        if not ran:
+            return dict(devices=devices, pairs_per_device=0, exchange_bytes=0)
+        if n_div == 1:
+            return dict(devices=devices,
+                        pairs_per_device=self.strategy.n_exact_chol(k_loc,
+                                                                    q_loc),
+                        exchange_bytes=0)
+        strat = self.strategy
+        lay = shardlib.PairLayout(k_loc, strat.g, n_div, h, strat.block)
+        itemsize = folds.fold_hess.dtype.itemsize
+        theta_itemsize = np.dtype(
+            self._prec.store_dtype(folds.fold_hess.dtype)).itemsize
+        return dict(devices=devices, pairs_per_device=lay.per_device,
+                    exchange_bytes=lay.exchange_bytes(
+                        strat.degree, itemsize, theta_itemsize))
 
     @staticmethod
     def _check_fold_axis(mesh: Optional[Mesh], k: int) -> None:
@@ -1034,12 +1139,18 @@ class CVEngine:
             errs = jax.lax.map(errors_at, chunks)         # (n_c, k_loc, chunk)
             return jnp.moveaxis(errs, 1, 0).reshape(k_loc, -1)[:, :q_loc]
 
-    def _core(self, h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux):
-        """(k_loc folds) × (q_loc λs) error grid — runs per device shard."""
+    def _core(self, h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux,
+              n_div: int = 1):
+        """(k_loc folds) × (q_loc λs) error grid — runs per device shard;
+        ``n_div`` > 1 divides the state stage over the λ axis."""
         strat, bk = self.strategy, self._bk
-        state = jax.vmap(
-            lambda f, h, g: strat.fold_state(f, h, g, aux, bk)
-        )(f_idx, h_tr, g_tr)
+        if n_div > 1:
+            state, _ = strat.divided_state(h_tr, aux, bk, n_div,
+                                           shardlib.CV_LAM_AXIS)
+        else:
+            state = jax.vmap(
+                lambda f, h, g: strat.fold_state(f, h, g, aux, bk)
+            )(f_idx, h_tr, g_tr)
 
         def errors_at(lams_c):
             return jax.vmap(
@@ -1063,7 +1174,9 @@ class CVEngine:
             fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
             repl = jax.tree.map(lambda _: P(), aux)
             sharded = jax.shard_map(
-                self._core, mesh=mesh,
+                functools.partial(self._core,
+                                  n_div=self._state_division(mesh)),
+                mesh=mesh,
                 in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), P(fold_ax),
                           P(fold_ax), P(lam_ax), repl),
                 out_specs=P(fold_ax, lam_ax),
@@ -1138,10 +1251,20 @@ class CVEngine:
             self._replays[key] = self._build_replay(mesh)
         return self._replays[key]
 
-    def _build_state(self, mesh: Optional[Mesh], with_anchors: bool):
+    def _state_core(self, n_div: int, with_anchors: bool):
+        """Per-device state stage ``(f_idx, h_tr, g_tr, aux) -> (batched
+        state, packed anchors)``: each fold's ``fold_state``, or the folds'
+        states divided over ``n_div`` devices of the λ axis.  Without
+        ``with_anchors`` the anchors are an empty row per fold."""
         strat, bk = self.strategy, self._bk
 
         def core(f_idx, h_tr, g_tr, aux):
+            if n_div > 1:
+                state, vec = strat.divided_state(h_tr, aux, bk, n_div,
+                                                 shardlib.CV_LAM_AXIS)
+                return state, (vec if with_anchors else
+                               jnp.zeros((h_tr.shape[0], 0), h_tr.dtype))
+
             def one(f, h_f, g_f):
                 if with_anchors:
                     return strat.fold_state_and_anchors(f, h_f, g_f, aux, bk)
@@ -1149,21 +1272,45 @@ class CVEngine:
                     jnp.zeros((0,), h_f.dtype)
             return jax.vmap(one)(f_idx, h_tr, g_tr)
 
-        def statef(h_tr, g_tr, x_folds, y_folds, lams):
-            k = h_tr.shape[0]
-            f_idx = jnp.arange(k)
-            aux = strat.prepare(x_folds, y_folds, h_tr, g_tr, lams, bk)
-            if mesh is None:
-                return core(f_idx, h_tr, g_tr, aux)
-            fold_ax = shardlib.CV_FOLD_AXIS
+        return core
+
+    def _sharded_state(self, mesh: Optional[Mesh], with_anchors: bool):
+        """The state stage over ``mesh``: ``(f_idx, h_tr, g_tr, aux) ->
+        (state, packed anchors)``, the state fold-sharded.  Divided, each
+        device returns its own pairs' anchors, which are put back in
+        (fold, anchor) order here."""
+        n_div = self._state_division(mesh)
+        core = self._state_core(n_div, with_anchors)
+        if mesh is None:
+            return core
+        fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
+        by_pair = with_anchors and n_div > 1
+
+        def statef(f_idx, h_tr, g_tr, aux):
             repl = jax.tree.map(lambda _: P(), aux)
-            sharded = jax.shard_map(
+            state, avec = jax.shard_map(
                 core, mesh=mesh,
                 in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), repl),
-                out_specs=(P(fold_ax), P(fold_ax)),
+                out_specs=(P(fold_ax),
+                           P((fold_ax, lam_ax)) if by_pair else P(fold_ax)),
                 check_vma=False,
-            )
-            return sharded(f_idx, h_tr, g_tr, aux)
+            )(f_idx, h_tr, g_tr, aux)
+            if by_pair:   # (fold rows, λ row's padded pair list, P)
+                k, g = h_tr.shape[0], self.strategy.g
+                rows = mesh.shape[fold_ax]
+                avec = avec.reshape(rows, -1, avec.shape[-1])[
+                    :, :k // rows * g].reshape(k, g, -1)
+            return state, avec
+
+        return statef
+
+    def _build_state(self, mesh: Optional[Mesh], with_anchors: bool):
+        strat, bk = self.strategy, self._bk
+        state_of = self._sharded_state(mesh, with_anchors)
+
+        def statef(h_tr, g_tr, x_folds, y_folds, lams):
+            aux = strat.prepare(x_folds, y_folds, h_tr, g_tr, lams, bk)
+            return state_of(jnp.arange(h_tr.shape[0]), h_tr, g_tr, aux)
 
         return _jit(statef)
 
@@ -1275,9 +1422,10 @@ class CVEngine:
         factorizations sit in the device queue (with their donated Hessian
         slices) while fold f's output is still being computed, and the ring
         bounds in-flight donated buffers to two.  With a mesh, the stage is
-        one fold-sharded batched call: the folds factorize in parallel
-        across the fold axis instead of in dispatch order (no donation —
-        the chunk stage reads ``h_tr`` again).
+        one batched call over the mesh (:meth:`_sharded_state`): the folds
+        factorize in parallel across the fold axis, divided over the λ
+        axis, instead of in dispatch order (no donation — the chunk stage
+        reads ``h_tr`` again).
 
         Returns ``(batched state, packed anchors | None, aux)``.
         """
@@ -1318,29 +1466,7 @@ class CVEngine:
         stage, so ``prepare``'s factorizations are never traced twice)."""
         key = ("staged", self._mesh_key(mesh), with_anchors)
         if key not in self._states:
-            strat, bk = self.strategy, self._bk
-
-            def core(f_idx, h_tr, g_tr, aux):
-                def one(f, h_f, g_f):
-                    if with_anchors:
-                        return strat.fold_state_and_anchors(f, h_f, g_f,
-                                                            aux, bk)
-                    return strat.fold_state(f, h_f, g_f, aux, bk), \
-                        jnp.zeros((0,), h_f.dtype)
-                return jax.vmap(one)(f_idx, h_tr, g_tr)
-
-            def statef(f_idx, h_tr, g_tr, aux):
-                fold_ax = shardlib.CV_FOLD_AXIS
-                repl = jax.tree.map(lambda _: P(), aux)
-                sharded = jax.shard_map(
-                    core, mesh=mesh,
-                    in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), repl),
-                    out_specs=(P(fold_ax), P(fold_ax)),
-                    check_vma=False,
-                )
-                return sharded(f_idx, h_tr, g_tr, aux)
-
-            self._states[key] = _jit(statef)
+            self._states[key] = _jit(self._sharded_state(mesh, with_anchors))
         return self._states[key]
 
     def _staged_state_for(self, mesh, h_tr, g_tr, folds: FoldData, lams,
@@ -1436,7 +1562,7 @@ class CVEngine:
         k = folds.fold_hess.shape[0]
         q = int(lams.shape[0])
         h = folds.fold_hess.shape[-1]
-        mesh = self._resolve_mesh(k)
+        mesh = self._resolve_mesh(folds)
         self._check_fold_axis(mesh, k)
         h_tr, g_tr = self._split(folds.hess, folds.grad,
                                  folds.fold_hess, folds.fold_grad)
@@ -1553,7 +1679,7 @@ class CVEngine:
         last = parts[-1]
         errors = np.concatenate([p.errors for p in parts])
         lams_eval = np.concatenate([p.lams for p in parts])
-        mesh = self._resolve_mesh(folds.fold_hess.shape[0])
+        mesh = self._resolve_mesh(folds)
         n_lam = 1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS]
         chunk = self._stage_chunk(int(jnp.shape(lams)[0]),
                                   folds.fold_hess.shape[-1],
@@ -1563,7 +1689,9 @@ class CVEngine:
             precision=self._prec.name,
             mesh=None if mesh is None else dict(mesh.shape),
             donated=bool(self.donate), lam_chunk=self.lam_chunk,
-            lam_chunk_resolved=chunk // n_lam, cache=last.cache)
+            lam_chunk_resolved=chunk // n_lam, cache=last.cache,
+            shard=self._shard_counts(mesh, folds, chunk // n_lam,
+                                     last.n_exact_chol > 0))
         meta["async"] = dict(
             pipelined=pipelined, stop_tol=stop_tol,
             stop_patience=stop_patience, stopped=last.stopped,
@@ -1670,7 +1798,7 @@ class CVEngine:
         k = folds.fold_hess.shape[0]
         q = int(lams.shape[0])
         h = folds.fold_hess.shape[-1]
-        mesh = self._resolve_mesh(k)
+        mesh = self._resolve_mesh(folds)
         self._check_fold_axis(mesh, k)
         h_tr, g_tr = self._split(folds.hess, folds.grad,
                                  folds.fold_hess, folds.fold_grad)
@@ -1764,14 +1892,14 @@ class CVEngine:
         order = np.argsort(xs_all)
         n_eval = int(xs_all.shape[0])
         n_chol = 0 if warm else strat.n_exact_chol(k, n_eval)
+        w_loc = w // (1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS])
         meta = dict(
             strategy=strat.name, backend=self._bk.name,
             precision=self._prec.name,
             mesh=None if mesh is None else dict(mesh.shape),
             donated=bool(self.donate), lam_chunk=self.lam_chunk,
-            lam_chunk_resolved=w // (1 if mesh is None else
-                                     mesh.shape[shardlib.CV_LAM_AXIS]),
-            cache=cache_info)
+            lam_chunk_resolved=w_loc, cache=cache_info,
+            shard=self._shard_counts(mesh, folds, w_loc, not warm))
         meta["search"] = dict(
             wave=w, waves=waves, lams_evaluated=n_eval, dense_q=q,
             evals_vs_grid=n_eval / q, tol_decades=tol_decades,
@@ -2020,7 +2148,7 @@ class CVEngine:
         lams = self._check_lams(lams)
         k = folds.fold_hess.shape[0]
         q = lams.shape[0]
-        mesh = self._resolve_mesh(k)
+        mesh = self._resolve_mesh(folds)
         self._check_fold_axis(mesh, k)
         if mesh is not None:
             lams_run, _ = shardlib.pad_to_multiple(
@@ -2049,6 +2177,7 @@ class CVEngine:
             with tracing.span("cv.fetch"):
                 errs = np.asarray(errs)[:, :q]
         n_lam = 1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS]
+        q_loc = int(lams_run.shape[0]) // n_lam
         return CVResult.from_errors(
             lams, errs.mean(0), n_chol,
             engine=dict(
@@ -2057,9 +2186,9 @@ class CVEngine:
                 mesh=None if mesh is None else dict(mesh.shape),
                 donated=bool(self.donate), lam_chunk=self.lam_chunk,
                 lam_chunk_resolved=self._lam_chunk_used(
-                    int(lams_run.shape[0]) // n_lam,
-                    folds.fold_hess.shape[-1], folds.fold_hess.dtype),
-                cache=cache_info))
+                    q_loc, folds.fold_hess.shape[-1], folds.fold_hess.dtype),
+                cache=cache_info,
+                shard=self._shard_counts(mesh, folds, q_loc, n_chol > 0)))
 
     # -- batched admission (multi-tenant serving) ---------------------------
 
@@ -2095,7 +2224,7 @@ class CVEngine:
         The fused stacking path engages when every problem shares the fold
         geometry (h, n_f, dtype), derives the same anchor set, the strategy
         advertises ``batchable_state`` (and ``cache_meta``), a cache is
-        attached, and no mesh is configured; otherwise the batch degrades
+        attached, and the sweep runs on one device; otherwise the batch degrades
         gracefully to per-problem :meth:`run` calls (same results, no
         stacked dispatch).
         """
@@ -2120,6 +2249,7 @@ class CVEngine:
                  for _, l in problems]
         fusable = (self.cache is not None and self.reuse is not False
                    and self.mesh is None
+                   and self._fit_mesh(problems[0][0]) is None
                    and getattr(strat, "batchable_state", False)
                    and all(m is not None for m in metas))
         if fusable:

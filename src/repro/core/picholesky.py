@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from . import packing, tracing
 from .backends import BackendLike, resolve_backend
 
-__all__ = ["PiCholesky", "fit", "anchor_factors", "evaluate",
+__all__ = ["PiCholesky", "fit", "fit_targets", "anchor_factors",
+           "pair_factors", "evaluate",
            "evaluate_packed", "vandermonde", "choose_sample_lambdas",
            "refine_solutions", "loo_interp_scores", "select_interpolant"]
 
@@ -163,25 +164,37 @@ def fit(
         # Step 2: tile-packed target matrix T (g × P) — aligned BLAS-3 layout.
         targets = (factors if factors.ndim == 2
                    else bk.pack_tril(factors, block))
-        center = (jnp.mean(sample_lams) if basis == "centered"
-                  else jnp.zeros((), sample_lams.dtype))
-        fit_dtype = bk.precision.fit_dtype(targets.dtype)
-        store_dtype = bk.precision.store_dtype(targets.dtype)
-        v = vandermonde(sample_lams, degree, center).astype(fit_dtype)
+        theta, center = fit_targets(targets, sample_lams, degree,
+                                    block=block, basis=basis, backend=bk)
+        return PiCholesky(theta=theta, center=center, h=h, block=block)
 
-        # Steps 5–6: Θ = (VᵀV)⁻¹ VᵀT — normal equations exactly as in the
-        # paper, at the fit dtype; Θ is then stored at the storage dtype.
-        # One packed tile of columns at a time: a fold-batched solve
-        # against the whole (r+1, P) right-hand side compiled for v5e to
-        # 13.2 GiB of temporaries for the 5-fold state at h=4096, against
-        # 4.8 GiB this way.
-        h_lam = v.T @ v
-        tiles = targets.astype(fit_dtype).reshape(g, -1, block * block)
-        theta = jax.lax.map(lambda t: jnp.linalg.solve(h_lam, v.T @ t),
-                            jnp.moveaxis(tiles, 1, 0))     # (n, r+1, B²)
-        theta = jnp.moveaxis(theta, 0, 1).reshape(degree + 1, -1)
-        return PiCholesky(theta=theta.astype(store_dtype),
-                          center=center.astype(fit_dtype), h=h, block=block)
+
+def fit_targets(targets: jax.Array, sample_lams: jax.Array, degree: int, *,
+                block: int, basis: str = "monomial",
+                backend: BackendLike = "reference"):
+    """Steps 5–6 of Algorithm 1 on packed targets ``(g, N)``: ``(Θ (r+1,
+    N) at the storage dtype, center)``.  Each column is fitted alone, so
+    ``N`` may be any whole number of ``block²`` tiles: all of a factor, or
+    the slab of it one device fits in a divided state stage."""
+    bk = resolve_backend(backend)
+    g = sample_lams.shape[0]
+    center = (jnp.mean(sample_lams) if basis == "centered"
+              else jnp.zeros((), sample_lams.dtype))
+    fit_dtype = bk.precision.fit_dtype(targets.dtype)
+    store_dtype = bk.precision.store_dtype(targets.dtype)
+    v = vandermonde(sample_lams, degree, center).astype(fit_dtype)
+
+    # Θ = (VᵀV)⁻¹ VᵀT — normal equations exactly as in the paper, at the
+    # fit dtype; Θ is then stored at the storage dtype.  One packed tile of
+    # columns at a time: a fold-batched solve against the whole (r+1, P)
+    # right-hand side compiled for v5e to 13.2 GiB of temporaries for the
+    # 5-fold state at h=4096, against 4.8 GiB this way.
+    h_lam = v.T @ v
+    tiles = targets.astype(fit_dtype).reshape(g, -1, block * block)
+    theta = jax.lax.map(lambda t: jnp.linalg.solve(h_lam, v.T @ t),
+                        jnp.moveaxis(tiles, 1, 0))     # (n, r+1, B²)
+    theta = jnp.moveaxis(theta, 0, 1).reshape(degree + 1, -1)
+    return theta.astype(store_dtype), center.astype(fit_dtype)
 
 
 def anchor_factors(hessian: jax.Array, sample_lams: jax.Array,
@@ -190,6 +203,17 @@ def anchor_factors(hessian: jax.Array, sample_lams: jax.Array,
     with tracing.scope(tracing.ANCHOR_CHOL):
         eye = jnp.eye(hessian.shape[-1], dtype=hessian.dtype)
         return jax.vmap(lambda lam: chol_fn(hessian + lam * eye))(sample_lams)
+
+
+def pair_factors(hessians: jax.Array, lams: jax.Array,
+                 chol_fn: Callable[[jax.Array], jax.Array]) -> jax.Array:
+    """Step 1 for a list of (Hessian, shift) pairs: ``chol(H_p + λ_p I)``,
+    (p, h, h) — the anchor factorizations one device runs in a divided
+    state stage."""
+    with tracing.scope(tracing.ANCHOR_CHOL):
+        eye = jnp.eye(hessians.shape[-1], dtype=hessians.dtype)
+        return jax.vmap(lambda a, lam: chol_fn(a + lam * eye))(hessians,
+                                                               lams)
 
 
 def loo_interp_scores(

@@ -22,6 +22,12 @@ call to an already compiled function would add nothing):
     site: ``picholesky.fit`` from ``pack_tril`` to the returned Θ (so also
     ``CVEngine._refit_from_anchors``).  Covers: packing of the anchor
     factors and the normal equations.
+``cv.anchor_exchange``
+    site: ``PiCholeskyStrategy.divided_state`` (a mesh whose λ axis holds
+    more than one device).  Covers: the all-to-all that gives each device
+    one tile slab of every (fold, anchor) pair's packed factor, the gather
+    of the Θ slabs, and the pads and slices around them.  The Θ fit
+    between them is ``cv.theta_fit``.
 ``cv.lam_stage``
     site: ``_InterpolantErrors.fold_errors`` around ``state.solve``, and
     ``CVEngine._stream_errors``.  Covers: ``interp_solve`` (both
@@ -57,6 +63,15 @@ Counters beside them:
 
 ``FactorCache.fingerprint_bytes``
     in ``FactorCache.stats``: the host bytes hashed into cache keys.
+``shard``
+    in ``extras['engine']`` of ``run``, ``run_async`` and ``search``:
+    ``devices``, the devices of the sweep's mesh (1 without one);
+    ``pairs_per_device``, the factorizations one device ran for the
+    problem (0 when the state came from the cache); ``exchange_bytes``,
+    the bytes one device received from the others in
+    ``cv.anchor_exchange``, worked out from the layout's shapes
+    (``PairLayout.exchange_bytes``, 0 where nothing is exchanged), not
+    read from the device.
 ``lam_chunk_resolved``
     in ``extras['engine']`` of every result that records ``lam_chunk``
     (``run``, ``run_async``, ``search``, ``run_batch``): the λs one call
@@ -74,7 +89,9 @@ THETA_FIT = "cv.theta_fit"
 LAM_STAGE = "cv.lam_stage"
 REFINE = "cv.refine"
 SCORE = "cv.score"
-#: the device scopes of the piCholesky pipeline, in pipeline order
+ANCHOR_EXCHANGE = "cv.anchor_exchange"
+#: the device scopes of the one-device piCholesky pipeline, in pipeline
+#: order (a divided state stage adds ANCHOR_EXCHANGE)
 SCOPES = (SPLIT, ANCHOR_CHOL, THETA_FIT, LAM_STAGE, REFINE, SCORE)
 
 

@@ -340,7 +340,7 @@ def lower_sweep(engine, folds, lams):
     import jax.numpy as jnp
 
     k = folds.fold_hess.shape[0]
-    mesh = engine._resolve_mesh(k)
+    mesh = engine._resolve_mesh(folds)
     engine._check_fold_axis(mesh, k)
     h_tr, g_tr, x_s, y_s = _abstract_problem(folds, lams)
     lams = jnp.asarray(lams)
@@ -376,15 +376,18 @@ def score_candidates(engine, folds, lams, candidates: Sequence[TunedConfig],
 # -------------------------------------------------------------------- tune
 
 
-def default_config(engine, k: int, h: int, q: int, dtype) -> TunedConfig:
-    """The engine's untuned configuration as a lattice point: strategy /
-    engine block, the resolved λ-chunk (VMEM-auto, explicit int, or the
-    whole grid when streaming is off), and the mesh the engine would
-    build (the gcd heuristic under ``mesh='auto'``)."""
+def default_config(engine, folds, q: int) -> TunedConfig:
+    """The engine's untuned configuration for ``folds`` and a grid of
+    ``q`` λ as a lattice point: strategy / engine block, the resolved
+    λ-chunk (VMEM-auto, explicit int, or the whole grid when streaming is
+    off), and the mesh the engine would build (the gcd heuristic under
+    ``mesh='auto'``; with ``mesh=None`` the default rule on the folds'
+    geometry)."""
     block = getattr(engine.strategy, "block", None) or engine.block or 128
-    chunk = engine._resolve_chunk(q, h, dtype)
+    chunk = engine._resolve_chunk(q, folds.fold_hess.shape[-1],
+                                  folds.fold_hess.dtype)
     chunk = q if chunk is None else min(chunk, q)
-    mesh = engine._resolve_mesh(k)
+    mesh = engine._resolve_mesh(folds)
     mesh_shape = (None if mesh is None else
                   (mesh.shape[shardlib.CV_FOLD_AXIS],
                    mesh.shape[shardlib.CV_LAM_AXIS]))
@@ -412,7 +415,7 @@ def tune(engine, folds, lams, *, cache: Optional[TuningCache] = None,
     dtype = folds.fold_hess.dtype
     n_devices = len(jax.devices())
 
-    default = default_config(engine, k, h, q, dtype)
+    default = default_config(engine, folds, q)
     lattice_desc = dict(
         blocks=tuple(blocks) if blocks else DEFAULT_BLOCKS,
         chunks=tuple(chunks) if chunks else "auto-ladder",

@@ -7,6 +7,7 @@ divisibility so a bad mesh fails loudly at lowering time, not deep in XLA.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any, Optional, Sequence, Tuple
 
@@ -19,7 +20,8 @@ from repro.models.params import Spec
 
 __all__ = ["spec_pspec", "param_pspecs", "param_shardings", "data_pspec",
            "CV_FOLD_AXIS", "CV_LAM_AXIS", "make_cv_mesh", "cv_axis_sizes",
-           "mesh_shape_candidates",
+           "mesh_shape_candidates", "PairLayout", "sweep_bytes",
+           "device_bytes_free",
            "pad_to_multiple", "chunk_lams", "auto_lam_chunk",
            "cv_state_specs", "cv_chunk_in_specs", "StageRing"]
 
@@ -104,6 +106,90 @@ def make_cv_mesh(k: int, devices: Optional[Sequence[jax.Device]] = None) -> Mesh
     n_fold, n_lam = cv_axis_sizes(k, len(devices))
     dev = np.asarray(devices[: n_fold * n_lam]).reshape(n_fold, n_lam)
     return Mesh(dev, (CV_FOLD_AXIS, CV_LAM_AXIS))
+
+
+@dataclasses.dataclass(frozen=True)
+class PairLayout:
+    """The divided state stage: the ``k·g`` (fold, anchor) factorizations
+    of the folds a λ row of the mesh holds, dealt out over its ``n``
+    devices.
+
+    Every device of a λ row holds the same folds, so a fold-sharded state
+    stage would repeat each of their factorizations on all ``n`` devices.
+    Here device ``j`` factorizes pairs ``j·per_device … (j+1)·per_device −
+    1`` of the fold-major pair list; the list is padded to
+    ``n·per_device`` (never the folds), a padding pair repeating the last.
+    The packed factors are exchanged in ``n`` slabs of whole tiles: device
+    ``j`` receives slab ``j`` of every pair, fits Θ on it for every fold,
+    and a gather gives every device every fold's Θ.
+    """
+
+    k: int       # folds on the λ row
+    g: int       # anchors per fold
+    n: int       # devices on the λ axis
+    h: int       # order of the factors
+    block: int   # their packing tile
+
+    @property
+    def per_device(self) -> int:
+        """Factorizations each device runs: ⌈k·g / n⌉."""
+        return -(-self.k * self.g // self.n)
+
+    @property
+    def tiles(self) -> int:
+        """Tiles of one packed factor."""
+        from repro.core import packing   # local: distributed ↔ core layering
+        nt = packing.num_tiles(self.h, self.block)
+        return nt * (nt + 1) // 2
+
+    @property
+    def slab(self) -> int:
+        """Elements of one exchanged slab: ⌈tiles / n⌉ whole tiles."""
+        return -(-self.tiles // self.n) * self.block ** 2
+
+    def pairs(self, j: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """(fold, anchor) indices of device ``j``'s pairs (traced ``j``)."""
+        p = jnp.minimum(j * self.per_device + jnp.arange(self.per_device),
+                        self.k * self.g - 1)
+        return p // self.g, p % self.g
+
+    def exchange_bytes(self, degree: int, factor_itemsize: int,
+                       theta_itemsize: int) -> int:
+        """Bytes one device receives from the others per problem, from
+        the collectives' shapes: the all-to-all of the packed factors
+        ((n−1) slabs of its ``per_device`` pairs) and the gather of Θ
+        ((n−1) slabs of ``k·(degree+1)`` rows)."""
+        rows = (self.per_device * factor_itemsize
+                + self.k * (degree + 1) * theta_itemsize)
+        return (self.n - 1) * self.slab * rows
+
+
+#: The one-device fused sweep's temporaries, in dense ``h×h`` factors per
+#: (fold, anchor) pair.  XLA's memory analysis of the sweep for a
+#: described v5e (k=5, g=4, n=4h, f32) reads 3.8 at h=4096 (5.12 GB of
+#: temporaries, 5.73 GB in all) and 2.9 at h=8192 (15.61 GB; the compile
+#: is refused, 16.79 GiB of 15.75), so the larger: an estimate too high
+#: only costs a mesh where one device would have done.
+SWEEP_FACTORS_PER_PAIR = 3.8
+
+
+def sweep_bytes(k: int, g: int, h: int, n_rows: int, itemsize: int) -> int:
+    """Device bytes the one-device fused sweep takes, from shapes: its
+    arguments (the ``k`` train Hessians and the ``n_rows × h`` design) and
+    the state stage's temporaries, :data:`SWEEP_FACTORS_PER_PAIR` dense
+    factors for each of the ``k·g`` (fold, anchor) pairs."""
+    args = k * h * h + n_rows * h
+    return int(itemsize * (args + SWEEP_FACTORS_PER_PAIR * k * g * h * h))
+
+
+def device_bytes_free(device: jax.Device) -> Optional[int]:
+    """The memory one device can still give a program: its limit less
+    what is in use (the designs already made there, say), or None where
+    it reports no limit (the CPU)."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return stats["bytes_limit"] - stats.get("bytes_in_use", 0)
 
 
 def cv_state_specs(state: Any) -> Any:

@@ -96,7 +96,7 @@ def test_tune_zero_candidate_executions():
     assert cfg.source == "tuned"
     assert np.isfinite(cfg.predicted_s) and cfg.predicted_s > 0
     # scored candidates all carry finite predictions, chosen is the argmin
-    default = autotune.default_config(eng, 4, 24, 16, jnp.float32)
+    default = autotune.default_config(eng, folds, 16)
     scored = autotune.score_candidates(
         eng, folds, lams, autotune.candidate_lattice(
             h=24, k=4, q=16, n_devices=len(jax.devices()), default=default,
@@ -155,8 +155,7 @@ def test_default_always_candidate_ties_resolve_to_default():
     element on ties)."""
     folds, lams = _problem()
     eng = CVEngine("picholesky", backend="reference")
-    default = autotune.default_config(eng, 4, 24, int(lams.shape[0]),
-                                      jnp.float32)
+    default = autotune.default_config(eng, folds, int(lams.shape[0]))
     cfg = autotune.tune(eng, folds, lams, blocks=(default.block,),
                         chunks=(default.lam_chunk,),
                         mesh_shapes=[default.mesh_shape])
