@@ -68,7 +68,7 @@ from typing import (Any, Callable, Iterator, Optional, Protocol, Union,
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as shardlib
 
@@ -117,10 +117,23 @@ def _errors_from_thetas(thetas: jax.Array, x_f: jax.Array,
         return jax.vmap(lambda t: holdout_nrmse(t, x_f, y_f))(thetas)
 
 
+def _fold_scores(thetas: jax.Array, x_folds: jax.Array,
+                 y_folds: jax.Array) -> jax.Array:
+    """(k, q) hold-out errors of each fold's solutions (k, q, h)."""
+    return jax.vmap(_errors_from_thetas)(thetas, x_folds, y_folds)
+
+
 def _split_stats(hess, grad, fold_hess, fold_grad):
     """Per-fold train Hessians and gradients: the totals less each fold's."""
     with tracing.scope(tracing.SPLIT):
         return hess[None] - fold_hess, grad[None] - fold_grad
+
+
+def _split_folds(total, parts, folds):
+    """The train statistics of the folds ``folds`` (an index array of any
+    shape): the total less each of those folds'."""
+    with tracing.scope(tracing.SPLIT):
+        return total[None] - parts[folds]
 
 
 def _packed_anchors(factors: jax.Array, block: int, bk) -> jax.Array:
@@ -170,6 +183,8 @@ class StrategyBase:
     #: True when the strategy can divide its state stage's factorizations
     #: over the devices of a mesh axis (``divided_state``) — the engine
     #: then does so over the λ axis, whose devices hold the same folds.
+    #: Such a strategy's ``prepare`` reads only the λ grid, and its state
+    #: stage reads only the Hessians of each device's own pairs' folds.
     divisible_state: bool = False
 
     def prepare(self, x_folds, y_folds, h_tr, g_tr, lams, bk):
@@ -228,9 +243,15 @@ class _InterpolantErrors:
     :func:`~repro.core.picholesky.refine_solutions` — an fp32 residual
     sweep per λ chunk, riding inside the same O(chunk · P) budget."""
 
-    def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
+    @staticmethod
+    def solutions(state, g_tr_f, lams, bk):
+        """θ(λ) of one fold at the λ chunk, (q_loc, h), unrefined: what
+        ``fold_errors`` scores where the policy does not refine."""
         with tracing.scope(tracing.LAM_STAGE):
-            thetas = state.solve(lams, g_tr_f, backend=bk)   # (q_loc, h)
+            return state.solve(lams, g_tr_f, backend=bk)
+
+    def fold_errors(self, state, f_idx, h_tr_f, g_tr_f, x_f, y_f, lams, aux, bk):
+        thetas = self.solutions(state, g_tr_f, lams, bk)      # (q_loc, h)
         if bk.precision.refine_iters:
             with tracing.scope(tracing.REFINE):
                 thetas = picholesky.refine_solutions(
@@ -289,21 +310,28 @@ class PiCholeskyStrategy(_InterpolantErrors, StrategyBase):
         with tracing.scope(tracing.THETA_FIT):
             return model, vec.astype(bk.precision.store_dtype(vec.dtype))
 
-    def divided_state(self, h_tr, aux, bk, n: int, axis: str):
-        """``fold_state`` of every fold of ``h_tr`` (k, h, h), its k·g
-        anchor factorizations divided over the ``n`` devices of mesh axis
-        ``axis`` (:class:`~repro.distributed.sharding.PairLayout`), inside
-        the engine's ``shard_map`` body: this device factorizes and packs
-        its own (fold, anchor) pairs, an all-to-all gives it one tile slab
-        of every pair, it fits Θ on that slab for every fold, and a gather
-        gives every device every fold's Θ.  Returns ``(state batched over
-        the k folds, this device's packed anchors (per_device, P) at the
-        storage dtype)``."""
-        k, h = h_tr.shape[0], h_tr.shape[-1]
-        lay = shardlib.PairLayout(k, self.g, n, h, self.block)
+    def pair_layout(self, k: int, n: int, h: int) -> shardlib.PairLayout:
+        """How :meth:`divided_state` deals ``k`` folds' pairs out over
+        ``n`` devices."""
+        return shardlib.PairLayout(k, self.g, n, h, self.block)
+
+    def divided_state(self, h_loc, k: int, aux, bk, n: int, axis: str):
+        """``fold_state`` of the ``k`` folds of a λ row, their k·g anchor
+        factorizations divided over the ``n`` devices of mesh axis
+        ``axis`` (:meth:`pair_layout`), inside the engine's ``shard_map``
+        body.  ``h_loc`` (f, h, h) holds the train Hessians of the folds
+        this device's pairs read, its row of
+        :meth:`~repro.distributed.sharding.PairLayout.device_folds`.  This
+        device factorizes and packs its own (fold, anchor) pairs, an
+        all-to-all gives it one tile slab of every pair, it fits Θ on that
+        slab for every fold, and a gather gives every device every fold's
+        Θ.  Returns ``(state batched over the k folds, this device's
+        packed anchors (per_device, P) at the storage dtype)``."""
+        h = h_loc.shape[-1]
+        lay = self.pair_layout(k, n, h)
         fold, anchor = lay.pairs(jax.lax.axis_index(axis))
         with tracing.scope(tracing.ANCHOR_CHOL):
-            hess = h_tr[fold]
+            hess = h_loc[fold - fold[0]]
         factors = picholesky.pair_factors(hess, aux[anchor],
                                           self.chol_fn or bk.cholesky)
         vec = _packed_anchors(factors, self.block, bk)   # (per_device, P)
@@ -754,7 +782,10 @@ class CVEngine:
                of a λ row hold the same folds, and a strategy that can
                divide its state stage (``divisible_state``: piCholesky)
                deals the (fold, anchor) factorizations out over them
-               (:meth:`PiCholeskyStrategy.divided_state`).
+               (:meth:`PiCholeskyStrategy.divided_state`); unless the
+               policy refines, each device is then handed only its
+               pairs' folds of the train Hessians and no hold-out row,
+               and the designs' device scores the solutions.
     donate:    donate the per-fold training Hessians into the jitted sweep
                (``None`` = on except on CPU, where XLA cannot alias).
     block:     Pallas kernel tile size override for small test problems.
@@ -902,6 +933,8 @@ class CVEngine:
         self._interp_engines: dict = {}  # (degree, basis) -> derived engine
         self._anchor_targets = None      # jitted anchor-factorize stage
         self._split = jax.jit(_split_stats)
+        self._split_folds = jax.jit(_split_folds)
+        self._score = None        # jitted scoring of given solutions
 
     # -- mesh -------------------------------------------------------------
 
@@ -940,30 +973,92 @@ class CVEngine:
             return 1
         return mesh.shape[shardlib.CV_LAM_AXIS]
 
+    def _by_pair(self, mesh: Optional[Mesh]) -> bool:
+        """Whether the state stage is handed, on each device, only the
+        train Hessians of its own pairs' folds (:meth:`_pair_hessians`):
+        where the state stage is divided and the λ stage reads no train
+        Hessian (the policy does not refine).  Otherwise every device of
+        a λ row is handed its row's folds whole."""
+        return (self._state_division(mesh) > 1
+                and not self._prec.refine_iters)
+
+    def _pair_folds(self, mesh: Mesh, k: int, h: int) -> np.ndarray:
+        """(devices, f): the folds whose train Hessians block ``r·n + j``
+        of :meth:`_pair_hessians` holds, for device (r, j) of the mesh —
+        fold row r's folds r·k/rows … that device j's pairs read
+        (``PairLayout.device_folds``)."""
+        rows = mesh.shape[shardlib.CV_FOLD_AXIS]
+        n = mesh.shape[shardlib.CV_LAM_AXIS]
+        local = self.strategy.pair_layout(k // rows, n, h).device_folds()
+        return (np.arange(rows)[:, None, None] * (k // rows)
+                + local).reshape(rows * n, -1)
+
+    def _pair_hessians(self, mesh: Mesh, folds: FoldData) -> jax.Array:
+        """The train Hessians of the divided state stage, each device
+        handed only the folds its pairs read (:meth:`_pair_folds`):
+        (devices, f, h, h) under ``P((folds, lams))``.  Each device's
+        block is made where the designs are and put on its device alone,
+        so no device receives a fold it does not read."""
+        h = folds.fold_hess.shape[-1]
+        of_block = self._pair_folds(mesh, folds.fold_hess.shape[0], h)
+        sharding = NamedSharding(mesh, P((shardlib.CV_FOLD_AXIS,
+                                          shardlib.CV_LAM_AXIS)))
+        shape = of_block.shape + (h, h)
+        blocks = [
+            jax.device_put(self._split_folds(folds.hess, folds.fold_hess,
+                                             of_block[idx[0]]), device)
+            for device, idx in
+            sharding.addressable_devices_indices_map(shape).items()]
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        blocks)
+
+    def _state_hessians(self, mesh: Optional[Mesh], folds: FoldData,
+                        h_tr: jax.Array) -> jax.Array:
+        """The train Hessians a state stage over ``mesh`` is handed: the
+        pairs' folds (:meth:`_by_pair`), else ``h_tr``."""
+        return self._pair_hessians(mesh, folds) if self._by_pair(mesh) \
+            else h_tr
+
     def _shard_counts(self, mesh: Optional[Mesh], folds: FoldData,
-                      q_loc: int, ran: bool) -> dict:
+                      q_loc: int, ran: bool, fused: bool) -> dict:
         """``extras['engine']['shard']``: the devices, the factorizations
-        one device ran for the problem and the bytes it received in the
-        anchor exchange (``ran`` False: the state came from the cache)."""
+        one device ran for the problem, the bytes it received in the
+        anchor exchange, how the state stage's inputs were placed and
+        their bytes on the devices other than the designs' (``ran``
+        False: the state came from the cache; ``fused``: the state stage
+        ran in the fused sweep, which is handed the hold-out rows too)."""
         k, h = folds.fold_hess.shape[0], folds.fold_hess.shape[-1]
         devices = 1 if mesh is None else mesh.devices.size
         k_loc = k if mesh is None else k // mesh.shape[shardlib.CV_FOLD_AXIS]
         n_div = self._state_division(mesh)
+        by_pair = self._by_pair(mesh)
+        counts = dict(devices=devices, pairs_per_device=0, exchange_bytes=0,
+                      inputs="by_pair" if by_pair else "replicated",
+                      placed_bytes=0)
         if not ran:
-            return dict(devices=devices, pairs_per_device=0, exchange_bytes=0)
-        if n_div == 1:
-            return dict(devices=devices,
-                        pairs_per_device=self.strategy.n_exact_chol(k_loc,
-                                                                    q_loc),
-                        exchange_bytes=0)
-        strat = self.strategy
-        lay = shardlib.PairLayout(k_loc, strat.g, n_div, h, strat.block)
+            return counts
         itemsize = folds.fold_hess.dtype.itemsize
-        theta_itemsize = np.dtype(
-            self._prec.store_dtype(folds.fold_hess.dtype)).itemsize
-        return dict(devices=devices, pairs_per_device=lay.per_device,
-                    exchange_bytes=lay.exchange_bytes(
-                        strat.degree, itemsize, theta_itemsize))
+        strat = self.strategy
+        if n_div == 1:
+            counts["pairs_per_device"] = strat.n_exact_chol(k_loc, q_loc)
+        else:
+            lay = strat.pair_layout(k_loc, n_div, h)
+            theta_itemsize = np.dtype(
+                self._prec.store_dtype(folds.fold_hess.dtype)).itemsize
+            counts.update(pairs_per_device=lay.per_device,
+                          exchange_bytes=lay.exchange_bytes(
+                              strat.degree, itemsize, theta_itemsize))
+        # a device's block of each input, in elements: the train Hessians
+        # (its pairs' folds, or its row's) and gradients, and in the fused
+        # sweep (unless by pair, where the designs' device scores) the
+        # hold-out rows and targets
+        n_f = folds.x_folds.shape[1]
+        hess = self._pair_folds(mesh, k, h).shape[1] if by_pair else k_loc
+        block = hess * h * h + k_loc * h
+        if fused and not by_pair:
+            block += k_loc * n_f * (h + 1)
+        counts["placed_bytes"] = (devices - 1) * block * itemsize
+        return counts
 
     @staticmethod
     def _check_fold_axis(mesh: Optional[Mesh], k: int) -> None:
@@ -1127,7 +1222,8 @@ class CVEngine:
         interpolants/factors are live at a time, so peak memory is
         O(chunk · P) however dense the grid.  Composes with the
         folds × lams ``shard_map``: chunking happens per device on the
-        local λ shard.  Shared by the fused cold sweep and the
+        local λ shard.  Shared by the fused cold sweep, the divided sweep
+        (which streams solutions, ``(k_loc, chunk, h)`` a chunk) and the
         warm-replay path, so the memory contract has one implementation.
         """
         q_loc = lams.shape[0]
@@ -1136,8 +1232,22 @@ class CVEngine:
             if chunk == q_loc:
                 return errors_at(lams)
             chunks, _ = shardlib.chunk_lams(lams, chunk)  # (n_c, chunk)
-            errs = jax.lax.map(errors_at, chunks)         # (n_c, k_loc, chunk)
-            return jnp.moveaxis(errs, 1, 0).reshape(k_loc, -1)[:, :q_loc]
+            errs = jax.lax.map(errors_at, chunks)     # (n_c, k_loc, chunk, …)
+            return jnp.moveaxis(errs, 1, 0).reshape(
+                k_loc, -1, *errs.shape[3:])[:, :q_loc]
+
+    def _divided_state(self, h, k: int, aux, n_div: int, by_pair: bool):
+        """``divided_state`` in a ``shard_map`` body over the λ axis: ``h``
+        is this device's block of :meth:`_pair_hessians` (``by_pair``), or
+        its row's ``k`` train Hessians, of which it takes its pairs'
+        folds."""
+        strat, lam_ax = self.strategy, shardlib.CV_LAM_AXIS
+        if by_pair:
+            h_loc = h[0]
+        else:
+            rows = strat.pair_layout(k, n_div, h.shape[-1]).device_folds()
+            h_loc = h[jnp.asarray(rows)[jax.lax.axis_index(lam_ax)]]
+        return strat.divided_state(h_loc, k, aux, self._bk, n_div, lam_ax)
 
     def _core(self, h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux,
               n_div: int = 1):
@@ -1145,8 +1255,8 @@ class CVEngine:
         ``n_div`` > 1 divides the state stage over the λ axis."""
         strat, bk = self.strategy, self._bk
         if n_div > 1:
-            state, _ = strat.divided_state(h_tr, aux, bk, n_div,
-                                           shardlib.CV_LAM_AXIS)
+            state, _ = self._divided_state(h_tr, h_tr.shape[0], aux, n_div,
+                                           by_pair=False)
         else:
             state = jax.vmap(
                 lambda f, h, g: strat.fold_state(f, h, g, aux, bk)
@@ -1161,8 +1271,84 @@ class CVEngine:
         return self._stream_errors(errors_at, lams, h_tr.shape[0],
                                    h_tr.shape[-1], h_tr.dtype)
 
+    def _pair_core(self, h_pairs, g_tr, lams, aux, n_div: int):
+        """(k_loc folds, q_loc λs, h) solutions on one device of the
+        divided sweep handed its pairs' folds: the divided state stage,
+        then the λ stage on its λ shard, unscored."""
+        strat, bk = self.strategy, self._bk
+        k = g_tr.shape[0]
+        state, _ = self._divided_state(h_pairs, k, aux, n_div, by_pair=True)
+
+        def thetas_at(lams_c):
+            return jax.vmap(lambda st, g: strat.solutions(st, g, lams_c, bk)
+                            )(state, g_tr)
+
+        return self._stream_errors(thetas_at, lams, k, h_pairs.shape[-1],
+                                   h_pairs.dtype)
+
+    def _pair_sweep_fn(self, mesh: Mesh):
+        """Jitted ``(pair Hessians, g_tr, lams) -> θ (k, q, h)`` over
+        ``mesh``: the divided sweep handed each device only its pairs'
+        folds (:meth:`_pair_hessians`); no hold-out row reaches it."""
+        key = ("pairs", self._mesh_key(mesh))
+        if key not in self._sweeps:
+            strat, bk = self.strategy, self._bk
+            fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
+
+            def solve(h_pairs, g_tr, lams):
+                aux = strat.prepare(None, None, None, g_tr, lams, bk)
+                return jax.shard_map(
+                    functools.partial(self._pair_core,
+                                      n_div=self._state_division(mesh)),
+                    mesh=mesh,
+                    in_specs=(P((fold_ax, lam_ax)), P(fold_ax), P(lam_ax),
+                              jax.tree.map(lambda _: P(), aux)),
+                    out_specs=P(fold_ax, lam_ax),
+                    check_vma=False,
+                )(h_pairs, g_tr, lams, aux)
+
+            self._sweeps[key] = _jit(
+                solve, donate_argnums=(0,) if self.donate else ())
+        return self._sweeps[key]
+
+    def _lower_sweep(self, mesh: Optional[Mesh], h_tr, g_tr, x_folds,
+                     y_folds, lams):
+        """The program over ``mesh`` that runs the sweep's state stage,
+        lowered for these arrays or shapes: the divided program handed
+        each device its pairs' folds (:meth:`_by_pair`; the designs'
+        device's scoring is a program of its own), else the fused
+        sweep."""
+        if not self._by_pair(mesh):
+            return self._sweep_fn(mesh).lower(h_tr, g_tr, x_folds, y_folds,
+                                              lams)
+        h = h_tr.shape[-1]
+        pairs = jax.ShapeDtypeStruct(
+            self._pair_folds(mesh, h_tr.shape[0], h).shape + (h, h),
+            h_tr.dtype, sharding=NamedSharding(mesh, P((
+                shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS))))
+        return self._pair_sweep_fn(mesh).lower(pairs, g_tr, lams)
+
+    def _score_fn(self):
+        """Jitted ``(θ (k, q, h), x_folds, y_folds) -> (k, q)`` hold-out
+        errors, on the device that holds the hold-out rows."""
+        if self._score is None:
+            self._score = _jit(_fold_scores)
+        return self._score
+
     def _build_sweep(self, mesh: Optional[Mesh]):
         strat, bk = self.strategy, self._bk
+        if self._by_pair(mesh):
+            solve, score = self._pair_sweep_fn(mesh), self._score_fn()
+
+            def scored(h_pairs, g_tr, x_folds, y_folds, lams):
+                thetas = solve(h_pairs, g_tr, lams)
+                # θ to the hold-out rows (a jit handed both a mesh array
+                # and a one-device array would put the rows on the mesh)
+                if len(x_folds.devices()) == 1:
+                    thetas = jax.device_put(thetas, *x_folds.devices())
+                return score(thetas, x_folds, y_folds)
+
+            return scored
 
         def sweep(h_tr, g_tr, x_folds, y_folds, lams):
             k = h_tr.shape[0]
@@ -1251,19 +1437,20 @@ class CVEngine:
             self._replays[key] = self._build_replay(mesh)
         return self._replays[key]
 
-    def _state_core(self, n_div: int, with_anchors: bool):
-        """Per-device state stage ``(f_idx, h_tr, g_tr, aux) -> (batched
+    def _state_core(self, n_div: int, with_anchors: bool, by_pair: bool):
+        """Per-device state stage ``(f_idx, h, g_tr, aux) -> (batched
         state, packed anchors)``: each fold's ``fold_state``, or the folds'
-        states divided over ``n_div`` devices of the λ axis.  Without
+        states divided over ``n_div`` devices of the λ axis (``h`` this
+        device's pairs' folds where ``by_pair``).  Without
         ``with_anchors`` the anchors are an empty row per fold."""
         strat, bk = self.strategy, self._bk
 
         def core(f_idx, h_tr, g_tr, aux):
             if n_div > 1:
-                state, vec = strat.divided_state(h_tr, aux, bk, n_div,
-                                                 shardlib.CV_LAM_AXIS)
+                k = g_tr.shape[0]
+                state, vec = self._divided_state(h_tr, k, aux, n_div, by_pair)
                 return state, (vec if with_anchors else
-                               jnp.zeros((h_tr.shape[0], 0), h_tr.dtype))
+                               jnp.zeros((k, 0), h_tr.dtype))
 
             def one(f, h_f, g_f):
                 if with_anchors:
@@ -1275,28 +1462,32 @@ class CVEngine:
         return core
 
     def _sharded_state(self, mesh: Optional[Mesh], with_anchors: bool):
-        """The state stage over ``mesh``: ``(f_idx, h_tr, g_tr, aux) ->
-        (state, packed anchors)``, the state fold-sharded.  Divided, each
-        device returns its own pairs' anchors, which are put back in
-        (fold, anchor) order here."""
+        """The state stage over ``mesh``: ``(f_idx, h, g_tr, aux) ->
+        (state, packed anchors)``, the state fold-sharded; ``h`` is
+        :meth:`_state_hessians`.  Divided, each device returns its own
+        pairs' anchors, which are put back in (fold, anchor) order
+        here."""
         n_div = self._state_division(mesh)
-        core = self._state_core(n_div, with_anchors)
+        by_pair = self._by_pair(mesh)
+        core = self._state_core(n_div, with_anchors, by_pair)
         if mesh is None:
             return core
         fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
-        by_pair = with_anchors and n_div > 1
+        pair_anchors = with_anchors and n_div > 1
+        h_spec = P((fold_ax, lam_ax)) if by_pair else P(fold_ax)
 
-        def statef(f_idx, h_tr, g_tr, aux):
+        def statef(f_idx, h, g_tr, aux):
             repl = jax.tree.map(lambda _: P(), aux)
             state, avec = jax.shard_map(
                 core, mesh=mesh,
-                in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), repl),
+                in_specs=(P(fold_ax), h_spec, P(fold_ax), repl),
                 out_specs=(P(fold_ax),
-                           P((fold_ax, lam_ax)) if by_pair else P(fold_ax)),
+                           P((fold_ax, lam_ax)) if pair_anchors
+                           else P(fold_ax)),
                 check_vma=False,
-            )(f_idx, h_tr, g_tr, aux)
-            if by_pair:   # (fold rows, λ row's padded pair list, P)
-                k, g = h_tr.shape[0], self.strategy.g
+            )(f_idx, h, g_tr, aux)
+            if pair_anchors:   # (fold rows, λ row's padded pair list, P)
+                k, g = g_tr.shape[0], self.strategy.g
                 rows = mesh.shape[fold_ax]
                 avec = avec.reshape(rows, -1, avec.shape[-1])[
                     :, :k // rows * g].reshape(k, g, -1)
@@ -1308,9 +1499,9 @@ class CVEngine:
         strat, bk = self.strategy, self._bk
         state_of = self._sharded_state(mesh, with_anchors)
 
-        def statef(h_tr, g_tr, x_folds, y_folds, lams):
-            aux = strat.prepare(x_folds, y_folds, h_tr, g_tr, lams, bk)
-            return state_of(jnp.arange(h_tr.shape[0]), h_tr, g_tr, aux)
+        def statef(h, g_tr, x_folds, y_folds, lams):
+            aux = strat.prepare(x_folds, y_folds, h, g_tr, lams, bk)
+            return state_of(jnp.arange(g_tr.shape[0]), h, g_tr, aux)
 
         return _jit(statef)
 
@@ -1438,7 +1629,8 @@ class CVEngine:
         if mesh is not None:
             with self._stage_scope("fold_state"):
                 state, avec = self._staged_state_fn(mesh, with_anchors)(
-                    jnp.arange(h_tr.shape[0]), h_tr, g_tr, aux)
+                    jnp.arange(h_tr.shape[0]),
+                    self._state_hessians(mesh, folds, h_tr), g_tr, aux)
             if not pipelined:
                 jax.block_until_ready((state, avec))
         else:
@@ -1691,7 +1883,7 @@ class CVEngine:
             donated=bool(self.donate), lam_chunk=self.lam_chunk,
             lam_chunk_resolved=chunk // n_lam, cache=last.cache,
             shard=self._shard_counts(mesh, folds, chunk // n_lam,
-                                     last.n_exact_chol > 0))
+                                     last.n_exact_chol > 0, fused=False))
         meta["async"] = dict(
             pipelined=pipelined, stop_tol=stop_tol,
             stop_patience=stop_patience, stopped=last.stopped,
@@ -1899,7 +2091,8 @@ class CVEngine:
             mesh=None if mesh is None else dict(mesh.shape),
             donated=bool(self.donate), lam_chunk=self.lam_chunk,
             lam_chunk_resolved=w_loc, cache=cache_info,
-            shard=self._shard_counts(mesh, folds, w_loc, not warm))
+            shard=self._shard_counts(mesh, folds, w_loc, not warm,
+                                     fused=False))
         meta["search"] = dict(
             wave=w, waves=waves, lams_evaluated=n_eval, dense_q=q,
             evals_vs_grid=n_eval / q, tol_decades=tol_decades,
@@ -2123,7 +2316,8 @@ class CVEngine:
 
         def cold_state(with_anchors):
             state, avec = self._state_fn(mesh, with_anchors)(
-                h_tr, g_tr, folds.x_folds, folds.y_folds, lams_run)
+                self._state_hessians(mesh, folds, h_tr), g_tr,
+                folds.x_folds, folds.y_folds, lams_run)
             pf = (packing.PackedFactor(vec=avec, h=h_tr.shape[-1],
                                        block=meta["params"]["block"])
                   if with_anchors else None)
@@ -2158,17 +2352,25 @@ class CVEngine:
 
         with tracing.span("cv.run", h=int(folds.fold_hess.shape[-1]), k=k,
                           q=int(q)) as span:
-            # engine-owned train-stat buffers: safe to donate into the sweep
-            h_tr, g_tr = self._split(folds.hess, folds.grad,
-                                     folds.fold_hess, folds.fold_grad)
             meta = (self.strategy.cache_meta(lams)
                     if self.cache is not None
                     and hasattr(self.strategy, "cache_meta") else None)
             if meta is not None:
+                h_tr, g_tr = self._split(folds.hess, folds.grad,
+                                         folds.fold_hess, folds.fold_grad)
                 errs, cache_info, n_chol = self._run_cached(
                     meta, mesh, h_tr, g_tr, folds, lams_run, q)
             else:
-                errs = self._sweep_fn(mesh)(h_tr, g_tr, folds.x_folds,
+                # engine-owned train-stat buffers: safe to donate into the
+                # sweep
+                if self._by_pair(mesh):
+                    h_in = self._pair_hessians(mesh, folds)
+                    g_tr = self._split_folds(folds.grad, folds.fold_grad,
+                                             np.arange(k))
+                else:
+                    h_in, g_tr = self._split(folds.hess, folds.grad,
+                                             folds.fold_hess, folds.fold_grad)
+                errs = self._sweep_fn(mesh)(h_in, g_tr, folds.x_folds,
                                             folds.y_folds, lams_run)
                 cache_info = (None if self.cache is None
                               else dict(status="bypass"))
@@ -2188,7 +2390,8 @@ class CVEngine:
                 lam_chunk_resolved=self._lam_chunk_used(
                     q_loc, folds.fold_hess.shape[-1], folds.fold_hess.dtype),
                 cache=cache_info,
-                shard=self._shard_counts(mesh, folds, q_loc, n_chol > 0)))
+                shard=self._shard_counts(mesh, folds, q_loc, n_chol > 0,
+                                         fused=meta is None)))
 
     # -- batched admission (multi-tenant serving) ---------------------------
 

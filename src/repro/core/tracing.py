@@ -12,8 +12,10 @@ Device scopes, opened inside the jitted stage functions (a scope around a
 call to an already compiled function would add nothing):
 
 ``cv.split``
-    site: the function ``CVEngine._split`` jits.  Covers: the per-fold
-    train Hessians and gradients from the fold stats.
+    site: the functions ``CVEngine._split`` and ``CVEngine._split_folds``
+    jit.  Covers: the per-fold train Hessians and gradients from the fold
+    stats (on the divided sweep, each device's block of its pairs' folds,
+    made on the designs' device).
 ``cv.anchor_chol``
     site: ``picholesky.anchor_factors`` (every anchor-factorizing path) and
     the anchor grid of ``prepare``.  Covers: the g anchor factorizations
@@ -37,7 +39,9 @@ call to an already compiled function would add nothing):
     site: around ``picholesky.refine_solutions``.  Covers: the refinement
     sweeps (``bf16_refined``).
 ``cv.score``
-    site: ``_errors_from_thetas``.  Covers: hold-out scoring.
+    site: ``_errors_from_thetas``.  Covers: hold-out scoring, inside the
+    sweep, or on the divided sweep handed its pairs' folds in a program
+    of its own on the designs' device (``CVEngine._score_fn``).
 ``cv.<strategy>``
     the rest of a non-piCholesky strategy: ``cv.exact``, ``cv.svd``,
     ``cv.low_rank``, ``cv.sketch``, ``cv.warmstart``, ``cv.pinrmse``.
@@ -71,7 +75,16 @@ Counters beside them:
     the bytes one device received from the others in
     ``cv.anchor_exchange``, worked out from the layout's shapes
     (``PairLayout.exchange_bytes``, 0 where nothing is exchanged), not
-    read from the device.
+    read from the device; ``inputs``, ``"by_pair"`` where each device of
+    a divided state stage was handed only its pairs' folds of the train
+    Hessians (``CVEngine._by_pair``), else ``"replicated"`` (each device
+    its fold row's folds whole); ``placed_bytes``, the bytes of the train
+    Hessians and gradients, and in the fused sweep of ``run`` the
+    hold-out rows and targets, that the program running the state stage
+    was handed on the devices other than the designs' (λ grids and
+    ``aux``, a few hundred bytes, left out), worked out from the shapes
+    the placement used, not read from the device (0 without a mesh, or
+    when the state came from the cache).
 ``lam_chunk_resolved``
     in ``extras['engine']`` of every result that records ``lam_chunk``
     (``run``, ``run_async``, ``search``, ``run_batch``): the λs one call

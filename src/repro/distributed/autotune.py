@@ -348,8 +348,8 @@ def lower_sweep(engine, folds, lams):
     if mesh is not None:
         q += (-q) % mesh.shape[shardlib.CV_LAM_AXIS]
     lam_s = jax.ShapeDtypeStruct((q,), lams.dtype)
-    compiled = engine._sweep_fn(mesh).lower(
-        h_tr, g_tr, x_s, y_s, lam_s).compile()
+    compiled = engine._lower_sweep(mesh, h_tr, g_tr, x_s, y_s,
+                                   lam_s).compile()
     chips = 1 if mesh is None else int(np.prod(list(mesh.shape.values())))
     return compiled, chips
 

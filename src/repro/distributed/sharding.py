@@ -121,7 +121,9 @@ class PairLayout:
     ``n·per_device`` (never the folds), a padding pair repeating the last.
     The packed factors are exchanged in ``n`` slabs of whole tiles: device
     ``j`` receives slab ``j`` of every pair, fits Θ on it for every fold,
-    and a gather gives every device every fold's Θ.
+    and a gather gives every device every fold's Θ.  A device's pairs are
+    contiguous, so they read a run of folds (:meth:`device_folds`), and
+    only those folds' training Hessians need be on it.
     """
 
     k: int       # folds on the λ row
@@ -152,6 +154,18 @@ class PairLayout:
         p = jnp.minimum(j * self.per_device + jnp.arange(self.per_device),
                         self.k * self.g - 1)
         return p // self.g, p % self.g
+
+    def device_folds(self) -> np.ndarray:
+        """(n, f) the folds each device's pairs read, in order, from the
+        first fold of its first pair: device ``j``'s pair of fold ``fold``
+        reads row ``j``'s entry ``fold − fold[0]`` (:meth:`pairs`).  ``f``
+        is the widest run; a shorter row repeats its last fold, as a
+        padding pair does."""
+        p = np.minimum(np.arange(self.n)[:, None] * self.per_device
+                       + np.arange(self.per_device), self.k * self.g - 1)
+        first, last = p[:, 0] // self.g, p[:, -1] // self.g
+        width = int((last - first).max()) + 1
+        return np.minimum(first[:, None] + np.arange(width), last[:, None])
 
     def exchange_bytes(self, degree: int, factor_itemsize: int,
                        theta_itemsize: int) -> int:

@@ -8,9 +8,11 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh
 
 from repro.core import CVEngine, FactorCache
 from repro.core.engine import PiCholeskyStrategy
@@ -42,16 +44,21 @@ def test_divided_curve_is_the_one_device_curve(k, g):
     lay = shardlib.PairLayout(k, g, 4, H, BLOCK)
     assert div.extras["engine"]["shard"] == dict(
         devices=4, pairs_per_device=math.ceil(k * g / 4),
-        exchange_bytes=lay.exchange_bytes(2, 8, 8))
+        exchange_bytes=lay.exchange_bytes(2, 8, 8), inputs="by_pair",
+        placed_bytes=3 * (lay.device_folds().shape[1] * H * H + k * H) * 8)
     assert one.extras["engine"]["shard"] == dict(
-        devices=1, pairs_per_device=k * g, exchange_bytes=0)
+        devices=1, pairs_per_device=k * g, exchange_bytes=0,
+        inputs="replicated", placed_bytes=0)
 
 
 def test_fold_axis_that_tiles_the_folds_keeps_fold_sharding():
-    res = _engine(4, mesh="auto").run(_folds(4), LAMS)
+    folds = _folds(4)
+    res = _engine(4, mesh="auto").run(folds, LAMS)
     assert res.extras["engine"]["mesh"] == {"folds": 4, "lams": 1}
+    n_f = folds.x_folds.shape[1]      # each device its own fold, whole
     assert res.extras["engine"]["shard"] == dict(
-        devices=4, pairs_per_device=4, exchange_bytes=0)
+        devices=4, pairs_per_device=4, exchange_bytes=0,
+        inputs="replicated", placed_bytes=3 * (H * H + H + n_f * H + n_f) * 8)
 
 
 @pytest.mark.parametrize("k,g,n", [(5, 4, 4), (3, 3, 4), (4, 4, 2),
@@ -69,19 +76,37 @@ def test_each_pair_is_factorized_and_no_device_runs_more_than_its_share(
     assert seen == {(f, a) for f in range(k) for a in range(g)}
     assert lay.slab * n >= lay.tiles * BLOCK ** 2
     assert lay.slab % BLOCK ** 2 == 0
+    # each device's folds: exactly those its pairs read, in order, the row
+    # padded with its last fold
+    rows = lay.device_folds()
+    assert rows.shape[0] == n
+    for j in range(n):
+        fold, _ = lay.pairs(jnp.asarray(j))
+        fold = np.asarray(fold)
+        assert set(rows[j]) == set(fold.tolist())
+        np.testing.assert_array_equal(rows[j][fold - fold[0]], fold)
+
+
+def test_five_folds_on_four_devices_read_two_folds_each():
+    rows = shardlib.PairLayout(5, 4, 4, H, BLOCK).device_folds()
+    np.testing.assert_array_equal(rows, [[0, 1], [1, 2], [2, 3], [3, 4]])
+
+
+def _pair_sweep_text(eng, folds, compiled=False):
+    mesh = eng._resolve_mesh(folds)
+    h_tr, g_tr = eng._split(folds.hess, folds.grad, folds.fold_hess,
+                            folds.fold_grad)
+    lowered = eng._lower_sweep(mesh, h_tr, g_tr, folds.x_folds,
+                               folds.y_folds, jnp.logspace(-3, 0, 12))
+    return lowered.compile().as_text() if compiled else lowered.as_text()
 
 
 def test_divided_sweep_factorizes_only_its_pairs_per_device():
     """The compiled per-device program factorizes a (⌈k·g/4⌉, h, h)
-    batch, where the undivided one factorizes all k·g pairs."""
-    folds = _folds(5)
-    eng = _engine(4, mesh="auto")
-    mesh = eng._resolve_mesh(folds)
-    h_tr, g_tr = eng._split(folds.hess, folds.grad, folds.fold_hess,
-                            folds.fold_grad)
-    text = eng._sweep_fn(mesh).lower(
-        h_tr, g_tr, folds.x_folds, folds.y_folds,
-        jnp.logspace(-3, 0, 12)).as_text()
+    batch, where the undivided one factorizes all k·g pairs; it is handed
+    each device's 2 folds of the train Hessians, not all 5."""
+    text = _pair_sweep_text(_engine(4, mesh="auto"), _folds(5))
+    assert f"tensor<4x2x{H}x{H}xf64>" in text
     assert f"tensor<5x{H}x{H}xf64>" in text
     assert f"tensor<20x{H}x{H}xf64>" not in text
     assert "all_to_all" in text and "all_gather" in text
@@ -115,7 +140,11 @@ def test_staged_sweep_divides_the_state_too():
     res = _engine(4, mesh="auto", lam_chunk=4).run_async(folds, LAMS)
     np.testing.assert_allclose(res.errors, _engine(4).run(folds, LAMS).errors,
                                rtol=1e-12, atol=1e-14)
-    assert res.extras["engine"]["shard"]["pairs_per_device"] == 5
+    shard = res.extras["engine"]["shard"]
+    assert shard["pairs_per_device"] == 5
+    # its state stage is handed each device's 2 folds, as the fused sweep
+    assert shard["inputs"] == "by_pair"
+    assert shard["placed_bytes"] == 3 * (2 * H * H + 5 * H) * 8
 
 
 V5E_LIMIT = 16_909_336_064          # bytes_limit of a v5e chip
@@ -165,14 +194,8 @@ def test_default_mesh_follows_the_device_limit(monkeypatch):
 
 
 def test_anchor_exchange_scope_reaches_the_collectives():
-    folds = _folds(5)
-    eng = _engine(4, mesh="auto")
-    mesh = eng._resolve_mesh(folds)
-    h_tr, g_tr = eng._split(folds.hess, folds.grad, folds.fold_hess,
-                            folds.fold_grad)
-    text = eng._sweep_fn(mesh).lower(
-        h_tr, g_tr, folds.x_folds, folds.y_folds,
-        jnp.logspace(-3, 0, 12)).compile().as_text()
+    text = _pair_sweep_text(_engine(4, mesh="auto"), _folds(5),
+                            compiled=True)
     ops = [line for line in text.splitlines()
            if " all-to-all(" in line or " all-gather(" in line]
     assert ops and all("cv.anchor_exchange" in line for line in ops)
@@ -196,3 +219,68 @@ def test_batch_and_search_run_on_the_default_mesh(monkeypatch):
     found = _engine(4).search(folds, LAMS)
     assert found.extras["engine"]["shard"]["pairs_per_device"] == 5
     assert found.extras["engine"]["mesh"] == {"folds": 1, "lams": 4}
+
+
+def _mesh(rows, n):
+    return Mesh(np.asarray(jax.devices()[:rows * n]).reshape(rows, n),
+                (shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS))
+
+
+@pytest.mark.parametrize("k,rows,n", [(5, 1, 4), (4, 2, 2)])
+def test_each_device_is_handed_its_pairs_folds_and_no_holdout_row(
+        monkeypatch, k, rows, n):
+    folds = _folds(k)
+    mesh = _mesh(rows, n)
+    eng = _engine(4, mesh=mesh)
+    handed = {}
+
+    def spy(name, fn):
+        def call(*args):
+            handed[name] = args
+            return fn(*args)
+        return call
+
+    solve, score = eng._pair_sweep_fn(mesh), eng._score_fn()
+    monkeypatch.setattr(eng, "_pair_sweep_fn", lambda m: spy("sweep", solve))
+    monkeypatch.setattr(eng, "_score_fn", lambda: spy("score", score))
+    div = eng.run(folds, LAMS)
+    one = _engine(4).run(folds, LAMS)
+    np.testing.assert_allclose(div.errors, one.errors, rtol=1e-12,
+                               atol=1e-14)
+    assert div.best_lam == one.best_lam
+
+    # the four-device program: each device's block of the train Hessians
+    # is its pairs' folds of its fold row, and no hold-out row is handed
+    h_pairs, g_tr, _ = handed["sweep"]
+    lay = shardlib.PairLayout(k // rows, 4, n, H, BLOCK)
+    local = lay.device_folds()
+    hess, fold_hess = np.asarray(folds.hess), np.asarray(folds.fold_hess)
+    for shard in h_pairs.addressable_shards:
+        r, j = map(int, np.argwhere(mesh.devices == shard.device)[0])
+        want = hess - fold_hess[r * (k // rows) + local[j]]
+        np.testing.assert_array_equal(np.asarray(shard.data)[0], want)
+    assert len(h_pairs.addressable_shards) == 4
+    # scoring: every array it is handed lives on the designs' device
+    home = folds.x_folds.devices()
+    assert all(a.devices() == home for a in handed["score"])
+
+    shard = div.extras["engine"]["shard"]
+    assert shard["inputs"] == "by_pair"
+    assert shard["placed_bytes"] == 3 * (local.shape[1] * H * H
+                                         + k // rows * H) * 8
+    assert shard["pairs_per_device"] == lay.per_device
+
+
+def test_refining_policy_keeps_every_fold_on_every_device():
+    """Under ``bf16_refined`` the λ stage reads each fold's train Hessian
+    on every λ shard, so the sweep keeps them whole on every device."""
+    folds = _folds(5)
+    div = _engine(4, mesh="auto", precision="bf16_refined").run(folds, LAMS)
+    one = _engine(4, precision="bf16_refined").run(folds, LAMS)
+    shard = div.extras["engine"]["shard"]
+    assert shard["inputs"] == "replicated"
+    n_f = folds.x_folds.shape[1]
+    assert shard["placed_bytes"] == 3 * 5 * (H * H + H + n_f * H + n_f) * 8
+    np.testing.assert_allclose(div.errors, one.errors, rtol=1e-12,
+                               atol=1e-14)
+    assert div.best_lam == one.best_lam

@@ -129,11 +129,13 @@ HBM = 15.75 * 2**30          # what XLA lets a v5e program use
 
 
 def _sweep_bytes(topo, monkeypatch, divided: bool) -> tuple:
-    """(per-device argument + temporary bytes, compiled text) of the fused
+    """(per-device argument bytes, temporary bytes, compiled text) of the fused
     sweep of the paper's grid (k=5, q=31 padded to 32, g=4, degree 2,
     block 128, f32, n=4h) on the (folds 1 × lams 4) mesh the engine
-    builds for 5 folds on 4 chips; undivided, as before the state stage
-    was divided: every chip runs all 20 factorizations."""
+    builds for 5 folds on 4 chips.  Divided, each chip is handed its
+    pairs' 2 folds of the train Hessians and no hold-out row; undivided,
+    as before the state stage was divided, every chip runs all 20
+    factorizations on every fold and scores them."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     k, h = 5, SWEEP_H
     mesh = shardlib.make_cv_mesh(k, topo.devices)
@@ -148,21 +150,23 @@ def _sweep_bytes(topo, monkeypatch, divided: bool) -> tuple:
     def spec(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=repl)
 
-    compiled = eng._sweep_fn(mesh).lower(
-        spec(k, h, h), spec(k, h), spec(k, n_f, h), spec(k, n_f),
+    compiled = eng._lower_sweep(
+        mesh, spec(k, h, h), spec(k, h), spec(k, n_f, h), spec(k, n_f),
         spec(32)).compile()
     mem = compiled.memory_analysis()
-    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes,
+    return (mem.argument_size_in_bytes, mem.temp_size_in_bytes,
             compiled.as_text())
 
 
 def test_divided_sweep_compiles_for_four_chips(topo, as_the_chip,
                                                monkeypatch):
-    """At h=8192 it reads 7.21 GB a chip (2.42 of arguments, 4.79 of
-    temporaries); the bound leaves room on device 0 for the harness's two
-    designs."""
-    per_device, text = _sweep_bytes(topo, monkeypatch, divided=True)
-    assert per_device * SCALE <= 9e9, per_device
+    """At h=8192 it reads 5.34 GB a chip (0.54 of arguments, its pairs'
+    2 folds of the train Hessians; 4.80 of temporaries); the bound leaves
+    room on device 0 for the harness's two designs.  Handed every fold
+    and the hold-out rows, the arguments read 2.42 GB."""
+    args, temp, text = _sweep_bytes(topo, monkeypatch, divided=True)
+    assert (args + temp) * SCALE <= 9e9, (args, temp)
+    assert args * SCALE <= 0.6e9, args
     assert "all-to-all" in text and "all-gather" in text
     assert "tpu_custom_call" in text
 
@@ -171,10 +175,34 @@ def test_undivided_sweep_does_not_fit_a_chip_at_h8192(topo, as_the_chip,
                                                       monkeypatch):
     """Every chip repeating the whole state stage holds what one chip
     would: at h=8192, 16.79 GiB of 15.75 (refused at compile)."""
-    per_device, text = _sweep_bytes(topo, monkeypatch, divided=False)
+    args, temp, text = _sweep_bytes(topo, monkeypatch, divided=False)
+    per_device = args + temp
     assert per_device * SCALE > HBM, per_device
     assert "all-to-all" not in text
     # the default mesh rule's estimate reads the same verdicts
     one_device = shardlib.sweep_bytes(5, 4, SWEEP_H, 4 * SWEEP_H, 4)
     assert one_device <= HBM < shardlib.sweep_bytes(5, 4, 8192, 4 * 8192, 4)
     assert 0.75 <= one_device / per_device <= 1.25
+
+
+def test_scoring_compiles_for_one_chip(topo, as_the_chip, monkeypatch):
+    """The divided sweep's solutions are scored on the designs' chip, at
+    full matmul precision: at h=8192 its arguments are the 1.07 GB of
+    hold-out rows and 5.2 MB of θ, with no temporaries."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    k, h = 5, SWEEP_H
+    n_f = 4 * h // k
+    one = SingleDeviceSharding(topo.devices[0])
+    eng = CVEngine(PiCholeskyStrategy(g=4, degree=2, block=128),
+                   backend="pallas", precision="fp32")
+    compiled = eng._score_fn().lower(
+        _spec(one, (k, 32, h)), _spec(one, (k, n_f, h)),
+        _spec(one, (k, n_f))).compile()
+    mem = compiled.memory_analysis()
+    least = 4 * k * (32 * h + n_f * h + n_f)   # before the chip's tiling
+    assert least <= mem.argument_size_in_bytes <= 1.01 * least
+    assert mem.temp_size_in_bytes == 0
+    dots = [line for line in compiled.as_text().splitlines()
+            if "cv.score" in line and "operand_precision" in line]
+    assert dots and all("operand_precision={highest,highest}" in line
+                        for line in dots)
