@@ -136,6 +136,12 @@ def _split_folds(total, parts, folds):
         return total[None] - parts[folds]
 
 
+def _repl(tree) -> Any:
+    """``shard_map`` specs placing every leaf of ``tree`` whole on every
+    device."""
+    return jax.tree.map(lambda _: P(), tree)
+
+
 def _packed_anchors(factors: jax.Array, block: int, bk) -> jax.Array:
     """The anchor factors tile-packed, (g, P): the Θ fit's targets."""
     with tracing.scope(tracing.THETA_FIT):
@@ -924,17 +930,10 @@ class CVEngine:
         self._tuned_engines: dict = {}    # TunedConfig.key() -> derived engine
         if self.donate is None:
             self.donate = jax.default_backend() != "cpu"
-        self._sweeps: dict = {}   # mesh-key -> jitted fused sweep fn
-        self._states: dict = {}   # (mesh-key, with_anchors) -> jitted state fn
-        self._replays: dict = {}  # mesh-key -> jitted replay fn
-        self._chunks: dict = {}   # mesh-key -> jitted per-chunk errors fn
-        self._fold_states: dict = {}   # with_anchors -> jitted 1-fold state fn
-        self._prepare = None      # jitted replicated prepare stage
+        self._programs: dict = {}   # (stage, mesh key, flags) -> jitted
         self._interp_engines: dict = {}  # (degree, basis) -> derived engine
-        self._anchor_targets = None      # jitted anchor-factorize stage
         self._split = jax.jit(_split_stats)
         self._split_folds = jax.jit(_split_folds)
-        self._score = None        # jitted scoring of given solutions
 
     # -- mesh -------------------------------------------------------------
 
@@ -1138,10 +1137,12 @@ class CVEngine:
         chunk = self._resolve_chunk(q_loc, h, dtype)
         return q_loc if chunk is None else min(chunk, q_loc)
 
-    def _stage_chunk(self, q: int, h: int, dtype, mesh) -> int:
+    def _stage_chunk(self, folds: FoldData, lams, mesh) -> int:
         """λs per dispatch of the staged sweep's chunk stage (whole grid
         when streaming is off), padded to the λ mesh axis."""
-        chunk = self._resolve_chunk(q, h, dtype)
+        q = int(lams.shape[0])
+        chunk = self._resolve_chunk(q, folds.fold_hess.shape[-1],
+                                    folds.fold_hess.dtype)
         if chunk is None or chunk > q:
             chunk = q
         if mesh is not None:
@@ -1192,13 +1193,18 @@ class CVEngine:
                 dev = np.asarray(
                     jax.devices()[: n_fold * n_lam]).reshape(n_fold, n_lam)
                 mesh = Mesh(dev, (shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS))
-        derived = CVEngine(
-            strategy=strat, backend=bk, mesh=mesh, donate=self.donate,
-            block=cfg.block, lam_chunk=int(cfg.lam_chunk), cache=self.cache,
-            reuse=self.reuse, cache_anchors=self.cache_anchors,
-            tune=False, tune_cache=self.tune_cache)
+        derived = self._derive(strategy=strat, backend=bk, mesh=mesh,
+                               block=cfg.block, lam_chunk=int(cfg.lam_chunk))
         self._tuned_engines[key] = derived
         return derived
+
+    def _derive(self, **fields) -> "CVEngine":
+        """An engine on this engine's resolved backend with ``fields``
+        changed and every other field carried over (the factor cache and
+        the tuning cache shared); ``tune=False``, since a derived engine
+        runs one configuration and never tunes again."""
+        return dataclasses.replace(
+            self, **{"backend": self._bk, "tune": False, **fields})
 
     def _tuned_engine(self, folds: FoldData, lams):
         """(derived engine, chosen config) for this problem geometry —
@@ -1215,19 +1221,51 @@ class CVEngine:
         return self._apply_tuned(cfg), cfg
 
     # -- sweep construction ----------------------------------------------
+    #
+    # Every program is a composition of two per-device bodies, the state
+    # stage (_state_body) and the λ stage (_lam_body, or _lam_solutions
+    # where the designs' device scores), put onto the mesh by _on_mesh and
+    # kept in one memo (_program).  The fused sweep runs both in one
+    # program; with a cache or staged, the state program (_state_fn) runs
+    # apart and the λ program (_replay_fn) replays any grid or chunk from
+    # its state.
 
-    def _stream_errors(self, errors_at, lams, k_loc, h, dtype):
+    @staticmethod
+    def _mesh_key(mesh: Optional[Mesh]):
+        return None if mesh is None else (tuple(mesh.shape.items()),
+                                          tuple(map(id, mesh.devices.flat)))
+
+    def _program(self, key: tuple, build: Callable) -> Callable:
+        """The jitted program of ``key`` (its stage, mesh key and flags),
+        built by ``build()`` on first use."""
+        if key not in self._programs:
+            self._programs[key] = build()
+        return self._programs[key]
+
+    @staticmethod
+    def _on_mesh(body: Callable, mesh: Optional[Mesh], in_specs,
+                 out_specs) -> Callable:
+        """``body`` itself without a mesh, else its ``shard_map`` over
+        ``mesh``."""
+        if mesh is None:
+            return body
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
+
+    def _stream_errors(self, errors_at, lams, g_tr):
         """Stream ``errors_at`` over the local λ shard in ``lam_chunk``-sized
         chunks under a sequential ``lax.map`` — only one chunk's
         interpolants/factors are live at a time, so peak memory is
         O(chunk · P) however dense the grid.  Composes with the
         folds × lams ``shard_map``: chunking happens per device on the
-        local λ shard.  Shared by the fused cold sweep, the divided sweep
-        (which streams solutions, ``(k_loc, chunk, h)`` a chunk) and the
-        warm-replay path, so the memory contract has one implementation.
+        local λ shard, whose (k_loc, h) train gradients ``g_tr`` give the
+        fold count, h and dtype.  Every λ stage streams here, the divided
+        sweep's solutions (``(k_loc, chunk, h)`` a chunk) too, so the
+        memory contract has one implementation.
         """
+        k_loc, h = g_tr.shape
         q_loc = lams.shape[0]
-        chunk = self._lam_chunk_used(q_loc, h, dtype)
+        chunk = self._lam_chunk_used(q_loc, h, g_tr.dtype)
         with tracing.scope(tracing.LAM_STAGE):
             if chunk == q_loc:
                 return errors_at(lams)
@@ -1236,31 +1274,46 @@ class CVEngine:
             return jnp.moveaxis(errs, 1, 0).reshape(
                 k_loc, -1, *errs.shape[3:])[:, :q_loc]
 
-    def _divided_state(self, h, k: int, aux, n_div: int, by_pair: bool):
-        """``divided_state`` in a ``shard_map`` body over the λ axis: ``h``
-        is this device's block of :meth:`_pair_hessians` (``by_pair``), or
-        its row's ``k`` train Hessians, of which it takes its pairs'
-        folds."""
-        strat, lam_ax = self.strategy, shardlib.CV_LAM_AXIS
-        if by_pair:
-            h_loc = h[0]
-        else:
-            rows = strat.pair_layout(k, n_div, h.shape[-1]).device_folds()
-            h_loc = h[jnp.asarray(rows)[jax.lax.axis_index(lam_ax)]]
-        return strat.divided_state(h_loc, k, aux, self._bk, n_div, lam_ax)
-
-    def _core(self, h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux,
-              n_div: int = 1):
-        """(k_loc folds) × (q_loc λs) error grid — runs per device shard;
-        ``n_div`` > 1 divides the state stage over the λ axis."""
+    def _state_body(self, n_div: int, with_anchors: bool, by_pair: bool):
+        """The per-device state stage ``(f_idx, h, g_tr, aux) -> (batched
+        state, packed anchors)``: each fold's ``fold_state`` (or
+        ``fold_state_and_anchors``) under ``vmap``, or where ``n_div`` > 1
+        the folds' ``divided_state`` over the ``n_div`` devices of the λ
+        axis — ``h`` then this device's block of :meth:`_pair_hessians`
+        (``by_pair``), or its row's train Hessians, of which it takes its
+        pairs' folds.  Without ``with_anchors`` the anchors are an empty
+        row per fold."""
         strat, bk = self.strategy, self._bk
-        if n_div > 1:
-            state, _ = self._divided_state(h_tr, h_tr.shape[0], aux, n_div,
-                                           by_pair=False)
-        else:
-            state = jax.vmap(
-                lambda f, h, g: strat.fold_state(f, h, g, aux, bk)
-            )(f_idx, h_tr, g_tr)
+        lam_ax = shardlib.CV_LAM_AXIS
+
+        def body(f_idx, h, g_tr, aux):
+            if n_div > 1:
+                k = g_tr.shape[0]
+                if by_pair:
+                    h_loc = h[0]
+                else:
+                    rows = strat.pair_layout(k, n_div,
+                                             h.shape[-1]).device_folds()
+                    h_loc = h[jnp.asarray(rows)[jax.lax.axis_index(lam_ax)]]
+                state, vec = strat.divided_state(h_loc, k, aux, bk, n_div,
+                                                 lam_ax)
+                return state, (vec if with_anchors
+                               else jnp.zeros((k, 0), h.dtype))
+
+            def one(f, h_f, g_f):
+                if with_anchors:
+                    return strat.fold_state_and_anchors(f, h_f, g_f, aux, bk)
+                return (strat.fold_state(f, h_f, g_f, aux, bk),
+                        jnp.zeros((0,), h_f.dtype))
+            return jax.vmap(one)(f_idx, h, g_tr)
+
+        return body
+
+    def _lam_body(self, state, f_idx, h_tr, g_tr, x_folds, y_folds, lams,
+                  aux):
+        """The per-device λ stage: each fold's ``fold_errors`` on the
+        device's λ shard, (k_loc, q_loc) hold-out errors."""
+        strat, bk = self.strategy, self._bk
 
         def errors_at(lams_c):
             return jax.vmap(
@@ -1268,48 +1321,94 @@ class CVEngine:
                     st, f, h, g, x, y, lams_c, aux, bk)
             )(state, f_idx, h_tr, g_tr, x_folds, y_folds)
 
-        return self._stream_errors(errors_at, lams, h_tr.shape[0],
-                                   h_tr.shape[-1], h_tr.dtype)
+        return self._stream_errors(errors_at, lams, g_tr)
 
-    def _pair_core(self, h_pairs, g_tr, lams, aux, n_div: int):
-        """(k_loc folds, q_loc λs, h) solutions on one device of the
-        divided sweep handed its pairs' folds: the divided state stage,
-        then the λ stage on its λ shard, unscored."""
+    def _lam_solutions(self, state, g_tr, lams):
+        """The λ stage unscored: each fold's θ(λ) on the device's λ shard,
+        (k_loc, q_loc, h)."""
         strat, bk = self.strategy, self._bk
-        k = g_tr.shape[0]
-        state, _ = self._divided_state(h_pairs, k, aux, n_div, by_pair=True)
 
         def thetas_at(lams_c):
             return jax.vmap(lambda st, g: strat.solutions(st, g, lams_c, bk)
                             )(state, g_tr)
 
-        return self._stream_errors(thetas_at, lams, k, h_pairs.shape[-1],
-                                   h_pairs.dtype)
+        return self._stream_errors(thetas_at, lams, g_tr)
+
+    def _sweep_fn(self, mesh: Optional[Mesh]):
+        """Jitted fused sweep ``(h, g_tr, x_folds, y_folds, lams) -> (k, q)``
+        errors over ``mesh``: the state and the λ stage in one program.
+        Where each device is handed its pairs' folds (:meth:`_by_pair`;
+        ``h`` is then :meth:`_pair_hessians`), the by-pair program
+        (:meth:`_pair_sweep_fn`) and the designs' device's scoring
+        (:meth:`_score_fn`)."""
+        def build():
+            if self._by_pair(mesh):
+                solve, score = self._pair_sweep_fn(mesh), self._score_fn()
+
+                def scored(h_pairs, g_tr, x_folds, y_folds, lams):
+                    thetas = solve(h_pairs, g_tr, lams)
+                    # θ to the hold-out rows (a jit handed both a mesh
+                    # array and a one-device array would put the rows on
+                    # the mesh)
+                    if len(x_folds.devices()) == 1:
+                        thetas = jax.device_put(thetas, *x_folds.devices())
+                    return score(thetas, x_folds, y_folds)
+
+                return scored
+
+            strat, bk = self.strategy, self._bk
+            state_of = self._state_body(self._state_division(mesh), False,
+                                        False)
+            fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
+
+            def body(h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux):
+                state, _ = state_of(f_idx, h_tr, g_tr, aux)
+                return self._lam_body(state, f_idx, h_tr, g_tr, x_folds,
+                                      y_folds, lams, aux)
+
+            def sweep(h_tr, g_tr, x_folds, y_folds, lams):
+                f_idx = jnp.arange(h_tr.shape[0])
+                aux = strat.prepare(x_folds, y_folds, h_tr, g_tr, lams, bk)
+                return self._on_mesh(
+                    body, mesh, (P(fold_ax),) * 5 + (P(lam_ax), _repl(aux)),
+                    P(fold_ax, lam_ax),
+                )(h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux)
+
+            return _jit(sweep, donate_argnums=(0, 1) if self.donate else ())
+
+        return self._program(("sweep", self._mesh_key(mesh)), build)
 
     def _pair_sweep_fn(self, mesh: Mesh):
         """Jitted ``(pair Hessians, g_tr, lams) -> θ (k, q, h)`` over
-        ``mesh``: the divided sweep handed each device only its pairs'
-        folds (:meth:`_pair_hessians`); no hold-out row reaches it."""
-        key = ("pairs", self._mesh_key(mesh))
-        if key not in self._sweeps:
+        ``mesh``: the divided state stage handed each device only its
+        pairs' folds (:meth:`_pair_hessians`), then the λ stage unscored;
+        no hold-out row reaches it."""
+        def build():
             strat, bk = self.strategy, self._bk
+            state_of = self._state_body(self._state_division(mesh), False,
+                                        True)
             fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
+
+            def body(h_pairs, g_tr, lams, aux):
+                state, _ = state_of(None, h_pairs, g_tr, aux)
+                return self._lam_solutions(state, g_tr, lams)
 
             def solve(h_pairs, g_tr, lams):
                 aux = strat.prepare(None, None, None, g_tr, lams, bk)
-                return jax.shard_map(
-                    functools.partial(self._pair_core,
-                                      n_div=self._state_division(mesh)),
-                    mesh=mesh,
-                    in_specs=(P((fold_ax, lam_ax)), P(fold_ax), P(lam_ax),
-                              jax.tree.map(lambda _: P(), aux)),
-                    out_specs=P(fold_ax, lam_ax),
-                    check_vma=False,
+                return self._on_mesh(
+                    body, mesh, (P((fold_ax, lam_ax)), P(fold_ax), P(lam_ax),
+                                 _repl(aux)),
+                    P(fold_ax, lam_ax),
                 )(h_pairs, g_tr, lams, aux)
 
-            self._sweeps[key] = _jit(
-                solve, donate_argnums=(0,) if self.donate else ())
-        return self._sweeps[key]
+            return _jit(solve, donate_argnums=(0,) if self.donate else ())
+
+        return self._program(("pairs", self._mesh_key(mesh)), build)
+
+    def _score_fn(self):
+        """Jitted ``(θ (k, q, h), x_folds, y_folds) -> (k, q)`` hold-out
+        errors, on the device that holds the hold-out rows."""
+        return self._program(("score",), lambda: _jit(_fold_scores))
 
     def _lower_sweep(self, mesh: Optional[Mesh], h_tr, g_tr, x_folds,
                      y_folds, lams):
@@ -1328,188 +1427,68 @@ class CVEngine:
                 shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS))))
         return self._pair_sweep_fn(mesh).lower(pairs, g_tr, lams)
 
-    def _score_fn(self):
-        """Jitted ``(θ (k, q, h), x_folds, y_folds) -> (k, q)`` hold-out
-        errors, on the device that holds the hold-out rows."""
-        if self._score is None:
-            self._score = _jit(_fold_scores)
-        return self._score
-
-    def _build_sweep(self, mesh: Optional[Mesh]):
-        strat, bk = self.strategy, self._bk
-        if self._by_pair(mesh):
-            solve, score = self._pair_sweep_fn(mesh), self._score_fn()
-
-            def scored(h_pairs, g_tr, x_folds, y_folds, lams):
-                thetas = solve(h_pairs, g_tr, lams)
-                # θ to the hold-out rows (a jit handed both a mesh array
-                # and a one-device array would put the rows on the mesh)
-                if len(x_folds.devices()) == 1:
-                    thetas = jax.device_put(thetas, *x_folds.devices())
-                return score(thetas, x_folds, y_folds)
-
-            return scored
-
-        def sweep(h_tr, g_tr, x_folds, y_folds, lams):
-            k = h_tr.shape[0]
-            f_idx = jnp.arange(k)
-            aux = strat.prepare(x_folds, y_folds, h_tr, g_tr, lams, bk)
-            if mesh is None:
-                return self._core(h_tr, g_tr, x_folds, y_folds, f_idx,
-                                  lams, aux)
+    def _state_fn(self, mesh: Optional[Mesh], with_anchors: bool,
+                  donate: bool = False):
+        """Jitted state stage ``(f_idx, h, g_tr, aux) -> (state, packed
+        anchors)`` over ``mesh``, the state fold-sharded; ``h`` is
+        :meth:`_state_hessians`.  Divided, each device returns its own
+        pairs' anchors, which are put back in (fold, anchor) order here.
+        ``donate`` donates ``h`` (the staged sweep's one-fold slices)."""
+        def build():
+            n_div, by_pair = self._state_division(mesh), self._by_pair(mesh)
+            body = self._state_body(n_div, with_anchors, by_pair)
             fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
-            repl = jax.tree.map(lambda _: P(), aux)
-            sharded = jax.shard_map(
-                functools.partial(self._core,
-                                  n_div=self._state_division(mesh)),
-                mesh=mesh,
-                in_specs=(P(fold_ax), P(fold_ax), P(fold_ax), P(fold_ax),
-                          P(fold_ax), P(lam_ax), repl),
-                out_specs=P(fold_ax, lam_ax),
-                check_vma=False,
-            )
-            return sharded(h_tr, g_tr, x_folds, y_folds, f_idx, lams, aux)
+            pair_anchors = with_anchors and n_div > 1
 
-        donate = (0, 1) if self.donate else ()
-        return _jit(sweep, donate_argnums=donate)
+            def state_stage(f_idx, h, g_tr, aux):
+                state, avec = self._on_mesh(
+                    body, mesh,
+                    (P(fold_ax), P((fold_ax, lam_ax)) if by_pair
+                     else P(fold_ax), P(fold_ax), _repl(aux)),
+                    (P(fold_ax), P((fold_ax, lam_ax)) if pair_anchors
+                     else P(fold_ax)),
+                )(f_idx, h, g_tr, aux)
+                if pair_anchors:   # (fold rows, λ row's padded pairs, P)
+                    k, g = g_tr.shape[0], self.strategy.g
+                    rows = mesh.shape[fold_ax]
+                    avec = avec.reshape(rows, -1, avec.shape[-1])[
+                        :, :k // rows * g].reshape(k, g, -1)
+                return state, avec
 
-    @staticmethod
-    def _mesh_key(mesh: Optional[Mesh]):
-        return None if mesh is None else (tuple(mesh.shape.items()),
-                                          tuple(map(id, mesh.devices.flat)))
+            return _jit(state_stage, donate_argnums=(1,) if donate else ())
 
-    def _sweep_fn(self, mesh: Optional[Mesh]):
-        key = self._mesh_key(mesh)
-        if key not in self._sweeps:
-            self._sweeps[key] = self._build_sweep(mesh)
-        return self._sweeps[key]
-
-    # -- warm-replay path (factor cache) ----------------------------------
-    #
-    # With a cache, the sweep splits at the PR-1 seam into two jitted
-    # stages: the λ-independent ``fold_state`` stage (skipped entirely on a
-    # hit) and the replay stage, which streams any λ grid through the
-    # fused interp_solve chunked pipeline from a given state.  Neither
-    # donates the train Hessians — the state fn's output must outlive the
-    # call (it goes into the cache) and the replay reads h_tr/g_tr again.
-
-    def _replay_core(self, state, f_idx, h_tr, g_tr, x_folds, y_folds, lams):
-        """Per-shard replay: fold_errors from a cached per-fold state.
-
-        Runs with ``aux=()`` — ``prepare`` is never called, so a strategy
-        is only cacheable if its ``fold_errors`` ignores ``aux`` (the
-        ``cache_meta`` contract).
-        """
-        strat, bk = self.strategy, self._bk
-
-        def errors_at(lams_c):
-            return jax.vmap(
-                lambda st, f, h, g, x, y: strat.fold_errors(
-                    st, f, h, g, x, y, lams_c, (), bk)
-            )(state, f_idx, h_tr, g_tr, x_folds, y_folds)
-
-        return self._stream_errors(errors_at, lams, h_tr.shape[0],
-                                   h_tr.shape[-1], h_tr.dtype)
-
-    def _build_replay(self, mesh: Optional[Mesh]):
-        def replay(state, h_tr, g_tr, x_folds, y_folds, lams):
-            k = h_tr.shape[0]
-            f_idx = jnp.arange(k)
-            if mesh is None:
-                return self._replay_core(state, f_idx, h_tr, g_tr,
-                                         x_folds, y_folds, lams)
-            fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
-            sharded = jax.shard_map(
-                self._replay_core, mesh=mesh,
-                in_specs=(shardlib.cv_state_specs(state), P(fold_ax),
-                          P(fold_ax), P(fold_ax), P(fold_ax), P(fold_ax),
-                          P(lam_ax)),
-                out_specs=P(fold_ax, lam_ax),
-                check_vma=False,
-            )
-            return sharded(state, f_idx, h_tr, g_tr, x_folds, y_folds, lams)
-
-        return _jit(replay)
+        return self._program(
+            ("state", self._mesh_key(mesh), with_anchors, donate), build)
 
     def _replay_fn(self, mesh: Optional[Mesh]):
-        key = self._mesh_key(mesh)
-        if key not in self._replays:
-            self._replays[key] = self._build_replay(mesh)
-        return self._replays[key]
+        """Jitted λ stage from a batched state, ``(state, h_tr, g_tr,
+        x_folds, y_folds, lams, aux=()) -> (k, q)`` errors over ``mesh``:
+        the cached path's replay of the whole grid (``aux=()``: a
+        cacheable strategy's ``fold_errors`` never reads it, the
+        ``cache_meta`` contract) and the staged sweep's chunk stage."""
+        def build():
+            fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
 
-    def _state_core(self, n_div: int, with_anchors: bool, by_pair: bool):
-        """Per-device state stage ``(f_idx, h, g_tr, aux) -> (batched
-        state, packed anchors)``: each fold's ``fold_state``, or the folds'
-        states divided over ``n_div`` devices of the λ axis (``h`` this
-        device's pairs' folds where ``by_pair``).  Without
-        ``with_anchors`` the anchors are an empty row per fold."""
+            def replay(state, h_tr, g_tr, x_folds, y_folds, lams, aux=()):
+                return self._on_mesh(
+                    self._lam_body, mesh,
+                    (shardlib.cv_state_specs(state),) + (P(fold_ax),) * 5
+                    + (P(lam_ax), _repl(aux)),
+                    P(fold_ax, lam_ax),
+                )(state, jnp.arange(g_tr.shape[0]), h_tr, g_tr, x_folds,
+                  y_folds, lams, aux)
+
+            return _jit(replay)
+
+        return self._program(("replay", self._mesh_key(mesh)), build)
+
+    def _prepare_fn(self):
+        """Jitted ``prepare`` of the state stages that run apart from the
+        λ stage."""
         strat, bk = self.strategy, self._bk
-
-        def core(f_idx, h_tr, g_tr, aux):
-            if n_div > 1:
-                k = g_tr.shape[0]
-                state, vec = self._divided_state(h_tr, k, aux, n_div, by_pair)
-                return state, (vec if with_anchors else
-                               jnp.zeros((k, 0), h_tr.dtype))
-
-            def one(f, h_f, g_f):
-                if with_anchors:
-                    return strat.fold_state_and_anchors(f, h_f, g_f, aux, bk)
-                return strat.fold_state(f, h_f, g_f, aux, bk), \
-                    jnp.zeros((0,), h_f.dtype)
-            return jax.vmap(one)(f_idx, h_tr, g_tr)
-
-        return core
-
-    def _sharded_state(self, mesh: Optional[Mesh], with_anchors: bool):
-        """The state stage over ``mesh``: ``(f_idx, h, g_tr, aux) ->
-        (state, packed anchors)``, the state fold-sharded; ``h`` is
-        :meth:`_state_hessians`.  Divided, each device returns its own
-        pairs' anchors, which are put back in (fold, anchor) order
-        here."""
-        n_div = self._state_division(mesh)
-        by_pair = self._by_pair(mesh)
-        core = self._state_core(n_div, with_anchors, by_pair)
-        if mesh is None:
-            return core
-        fold_ax, lam_ax = shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS
-        pair_anchors = with_anchors and n_div > 1
-        h_spec = P((fold_ax, lam_ax)) if by_pair else P(fold_ax)
-
-        def statef(f_idx, h, g_tr, aux):
-            repl = jax.tree.map(lambda _: P(), aux)
-            state, avec = jax.shard_map(
-                core, mesh=mesh,
-                in_specs=(P(fold_ax), h_spec, P(fold_ax), repl),
-                out_specs=(P(fold_ax),
-                           P((fold_ax, lam_ax)) if pair_anchors
-                           else P(fold_ax)),
-                check_vma=False,
-            )(f_idx, h, g_tr, aux)
-            if pair_anchors:   # (fold rows, λ row's padded pair list, P)
-                k, g = g_tr.shape[0], self.strategy.g
-                rows = mesh.shape[fold_ax]
-                avec = avec.reshape(rows, -1, avec.shape[-1])[
-                    :, :k // rows * g].reshape(k, g, -1)
-            return state, avec
-
-        return statef
-
-    def _build_state(self, mesh: Optional[Mesh], with_anchors: bool):
-        strat, bk = self.strategy, self._bk
-        state_of = self._sharded_state(mesh, with_anchors)
-
-        def statef(h, g_tr, x_folds, y_folds, lams):
-            aux = strat.prepare(x_folds, y_folds, h, g_tr, lams, bk)
-            return state_of(jnp.arange(g_tr.shape[0]), h, g_tr, aux)
-
-        return _jit(statef)
-
-    def _state_fn(self, mesh: Optional[Mesh], with_anchors: bool):
-        key = (self._mesh_key(mesh), with_anchors)
-        if key not in self._states:
-            self._states[key] = self._build_state(mesh, with_anchors)
-        return self._states[key]
+        return self._program(("prepare",), lambda: _jit(
+            lambda h_tr, g_tr, x, y, lams: strat.prepare(
+                x, y, h_tr, g_tr, lams, bk)))
 
     def _refit_from_anchors(self, pf: packing.PackedFactor, meta: dict):
         """Θ from cached packed anchor factors — a batched GEMM least-
@@ -1525,14 +1504,16 @@ class CVEngine:
 
         return _jit(jax.vmap(one))(jnp.asarray(pf.vec))
 
-    # -- pipelined staged sweep -------------------------------------------
+    # -- the state of the cached and staged sweeps ------------------------
     #
-    # The fold_state / fold_errors seam, driven from the host: per-fold
-    # state stages dispatch without blocking (bounded by a depth-2
-    # StageRing so at most two donated Hessian slices are in flight), the
-    # λ grid streams through one jitted chunk stage, and each completed
-    # chunk surfaces as a partial hold-out curve the early-stop search can
-    # act on.  `pipelined=False` runs the *same* jitted stage functions
+    # run() with a cache, sweep_async and search acquire their fitted state
+    # one way (_acquire_state): fingerprint, then a hit, an anchor refit or
+    # a cold state stage that populates the cache; then they stream λ
+    # through the replay.  The staged sweep on one device dispatches its
+    # cold state stage per fold (bounded by a depth-2 StageRing, so at most
+    # two donated Hessian slices are in flight) and streams λ chunk by
+    # chunk, each completed chunk a partial hold-out curve the early-stop
+    # search can act on.  `pipelined=False` runs the *same* jitted programs
     # with a block after every dispatch — the serial reference the parity
     # tests compare bit-for-bit against.
 
@@ -1546,158 +1527,113 @@ class CVEngine:
         with tracing.span(f"cv.{label}"), counting:
             yield
 
-    def _prepare_fn(self):
-        if self._prepare is None:
-            strat, bk = self.strategy, self._bk
-            self._prepare = _jit(
-                lambda h_tr, g_tr, x, y, lams: strat.prepare(
-                    x, y, h_tr, g_tr, lams, bk))
-        return self._prepare
-
-    def _fold_state_fn(self, with_anchors: bool):
-        """Jitted single-fold ``fold_state`` — the pipelined sweep's unit of
-        dispatch.  The fold's Hessian slice (an engine-owned copy) is
-        donated when the strategy actually consumes it."""
-        if with_anchors not in self._fold_states:
-            strat, bk = self.strategy, self._bk
-
-            def one(f, h_f, g_f, aux):
-                if with_anchors:
-                    return strat.fold_state_and_anchors(f, h_f, g_f, aux, bk)
-                return (strat.fold_state(f, h_f, g_f, aux, bk),
-                        jnp.zeros((0,), h_f.dtype))
-
-            donate = ((1,) if self.donate
-                      and getattr(strat, "state_uses_hessian", False) else ())
-            self._fold_states[with_anchors] = _jit(one,
-                                                   donate_argnums=donate)
-        return self._fold_states[with_anchors]
-
-    def _build_chunk_errors(self, mesh: Optional[Mesh]):
-        strat, bk = self.strategy, self._bk
-
-        def core(state, f_idx, h_tr, g_tr, x_folds, y_folds, lams_c, aux):
-            return jax.vmap(
-                lambda st, f, h, g, x, y: strat.fold_errors(
-                    st, f, h, g, x, y, lams_c, aux, bk)
-            )(state, f_idx, h_tr, g_tr, x_folds, y_folds)
-
-        def chunk_errors(state, f_idx, h_tr, g_tr, x_folds, y_folds,
-                         lams_c, aux):
-            if mesh is None:
-                return core(state, f_idx, h_tr, g_tr, x_folds, y_folds,
-                            lams_c, aux)
-            sharded = jax.shard_map(
-                core, mesh=mesh,
-                in_specs=shardlib.cv_chunk_in_specs(state, aux),
-                out_specs=P(shardlib.CV_FOLD_AXIS, shardlib.CV_LAM_AXIS),
-                check_vma=False,
-            )
-            return sharded(state, f_idx, h_tr, g_tr, x_folds, y_folds,
-                           lams_c, aux)
-
-        return _jit(chunk_errors)
-
-    def _chunk_errors_fn(self, mesh: Optional[Mesh]):
-        key = self._mesh_key(mesh)
-        if key not in self._chunks:
-            self._chunks[key] = self._build_chunk_errors(mesh)
-        return self._chunks[key]
-
-    def _pipelined_state(self, mesh, h_tr, g_tr, folds: FoldData, lams,
-                         with_anchors: bool, pipelined: bool):
-        """Cold ``fold_state`` stage of the staged sweep.
-
-        Unsharded: per-fold jitted dispatches through a depth-2
-        :class:`~repro.distributed.sharding.StageRing` — fold f+1's anchor
-        factorizations sit in the device queue (with their donated Hessian
-        slices) while fold f's output is still being computed, and the ring
-        bounds in-flight donated buffers to two.  With a mesh, the stage is
-        one batched call over the mesh (:meth:`_sharded_state`): the folds
-        factorize in parallel across the fold axis, divided over the λ
-        axis, instead of in dispatch order (no donation — the chunk stage
-        reads ``h_tr`` again).
-
-        Returns ``(batched state, packed anchors | None, aux)``.
-        """
+    def _cold_state(self, mesh, folds: FoldData, h_tr, g_tr, lams,
+                    with_anchors: bool, pipelined: Optional[bool]):
+        """The state stage run afresh: ``prepare``, then the state program
+        over ``mesh`` in one dispatch.  The staged sweep (``pipelined``
+        not None) on one device dispatches it per fold instead, on
+        one-fold slices of the train Hessians (engine-owned copies,
+        donated where the strategy reads them), through a depth-2
+        :class:`~repro.distributed.sharding.StageRing`: fold f+1's
+        factorizations sit in the device queue while fold f's run.
+        ``pipelined=False`` blocks after every dispatch.  Returns
+        ``(batched state, packed anchors | None, aux)``."""
         strat = self.strategy
+        serial = pipelined is False
         with self._stage_scope("prepare"):
             aux = self._prepare_fn()(h_tr, g_tr, folds.x_folds,
                                      folds.y_folds, lams)
-        if not pipelined:
+        if serial:
             jax.block_until_ready(aux)
-        if mesh is not None:
-            with self._stage_scope("fold_state"):
-                state, avec = self._staged_state_fn(mesh, with_anchors)(
-                    jnp.arange(h_tr.shape[0]),
-                    self._state_hessians(mesh, folds, h_tr), g_tr, aux)
-            if not pipelined:
-                jax.block_until_ready((state, avec))
-        else:
-            fn = self._fold_state_fn(with_anchors)
-            ring = shardlib.StageRing(depth=2)
-            outs = []
-            with self._stage_scope("fold_state"):
-                for f in range(h_tr.shape[0]):
-                    staged = fn(jnp.asarray(f), h_tr[f], g_tr[f], aux)
+        k = g_tr.shape[0]
+        with self._stage_scope("fold_state"):
+            if mesh is not None or pipelined is None:
+                out = self._state_fn(mesh, with_anchors)(
+                    jnp.arange(k), self._state_hessians(mesh, folds, h_tr),
+                    g_tr, aux)
+                if serial:
+                    jax.block_until_ready(out)
+            else:
+                fn = self._state_fn(None, with_anchors, donate=bool(
+                    self.donate and getattr(strat, "state_uses_hessian",
+                                            False)))
+                ring = shardlib.StageRing(depth=2)
+                outs = []
+                for f in range(k):
+                    staged = fn(jnp.arange(f, f + 1), h_tr[f:f + 1],
+                                g_tr[f:f + 1], aux)
                     outs.append(ring.admit(staged))
-                    if not pipelined:
+                    if serial:
                         jax.block_until_ready(staged)
-            state = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                 *[s for s, _ in outs])
-            avec = jnp.stack([a for _, a in outs])
+                out = jax.tree.map(lambda *xs: jnp.concatenate(xs), *outs)
+        state, avec = out
         pf = (packing.PackedFactor(vec=avec, h=h_tr.shape[-1],
                                    block=strat.block)
               if with_anchors else None)
         return state, pf, aux
 
-    def _staged_state_fn(self, mesh: Mesh, with_anchors: bool):
-        """Fold-sharded batched state stage taking a precomputed ``aux``
-        (unlike :meth:`_state_fn`, which runs ``prepare`` inside its jit —
-        the staged sweep computes ``aux`` once and shares it with the chunk
-        stage, so ``prepare``'s factorizations are never traced twice)."""
-        key = ("staged", self._mesh_key(mesh), with_anchors)
-        if key not in self._states:
-            self._states[key] = _jit(self._sharded_state(mesh, with_anchors))
-        return self._states[key]
+    def _cache_meta(self, lams) -> Optional[dict]:
+        """The strategy's ``cache_meta`` where a cache is attached, else
+        None (the sweep does not go through the cache)."""
+        if self.cache is None or not hasattr(self.strategy, "cache_meta"):
+            return None
+        return self.strategy.cache_meta(lams)
 
-    def _staged_state_for(self, mesh, h_tr, g_tr, folds: FoldData, lams,
-                          pipelined: bool):
-        """State stage of the staged sweep, cache dispatch included —
-        shared by :meth:`sweep_async` and :meth:`search` so the two λ
-        streams acquire their fitted state identically (fingerprint →
-        hit | anchor refit | cold populate) and can never drift.
+    def _cache_key(self, h_tr, meta: dict) -> cachelib.CacheKey:
+        """The cache key of a sweep's λ-independent inputs, fingerprinted
+        (and its bytes counted) by the attached cache."""
+        return self.cache.fingerprint(
+            h_tr, meta["anchors"], block=meta["params"]["block"],
+            backend=self._bk.name, params=meta["params"],
+            precision=self._prec.descriptor(),
+            sketch=meta.get("sketch", "exact"))
 
-        Returns ``(batched state, aux, warm, cache_info)``.
-        """
-        strat, bk = self.strategy, self._bk
-        meta = (strat.cache_meta(lams)
-                if self.cache is not None and hasattr(strat, "cache_meta")
-                else None)
-        aux: Any = ()
-        warm = False
-        if meta is not None:
-            key = self._cache_key(h_tr, meta)
-
-            def cold_state(with_anchors):
-                state, pf, _ = self._pipelined_state(
-                    mesh, h_tr, g_tr, folds, lams, with_anchors, pipelined)
-                return state, pf
-
-            entry, status = self._acquire_cached_state(meta, key, cold_state)
-            state = entry.state
-            warm = status != "miss"
-            cache_info = dict(status=status, digest=entry.key.digest()[:12],
-                              policy=self.reuse, **self.cache.stats)
-            # replay contract: fold_errors of a cacheable strategy never
-            # reads aux, so the chunk stage streams with aux=() on both the
-            # warm and the just-populated cold path
+    def _acquire_state(self, meta: Optional[dict], mesh, folds: FoldData,
+                       h_tr, g_tr, lams,
+                       pipelined: Optional[bool] = None):
+        """The sweep's fitted state, acquired one way by :meth:`run` with
+        a cache, :meth:`sweep_async` and :meth:`search`.  With ``meta``
+        (:meth:`_cache_meta`): the fingerprint, then a hit, an anchor
+        refit, or the cold state stage (:meth:`_cold_state`), which
+        populates the cache; without it, the cold state stage alone.
+        Returns ``(batched state, aux, status, cache info)``: ``aux`` is
+        ``()`` for a cached state, ``status`` ``hit`` / ``refit`` /
+        ``miss``, ``bypass`` (a cache, but no ``meta``) or None (no
+        cache)."""
+        if meta is None:
+            state, _, aux = self._cold_state(mesh, folds, h_tr, g_tr, lams,
+                                             False, pipelined)
+            if self.cache is None:
+                return state, aux, None, None
+            return state, aux, "bypass", dict(status="bypass")
+        strat, cache = self.strategy, self.cache
+        key = self._cache_key(h_tr, meta)
+        if self.reuse:
+            entry = cache.lookup(key, self.reuse)
         else:
-            state, _, aux = self._pipelined_state(
-                mesh, h_tr, g_tr, folds, lams, False, pipelined)
-            cache_info = (None if self.cache is None
-                          else dict(status="bypass"))
-        return state, aux, warm, cache_info
+            entry = None
+            cache.misses += 1     # write-only runs are misses by definition
+        status = "hit"
+        if entry is None:
+            with_anchors = (self.cache_anchors
+                            and hasattr(strat, "fold_state_and_anchors"))
+            pf = (cache.get_anchors(key)
+                  if self.reuse and with_anchors else None)
+            if pf is not None:
+                # same anchor factors, different polynomial: refit Θ from
+                # the cached packed targets — still zero factorizations
+                state, status = self._refit_from_anchors(pf, meta), "refit"
+            else:
+                state, pf, _ = self._cold_state(mesh, folds, h_tr, g_tr,
+                                                lams, with_anchors, pipelined)
+                status = "miss"
+            entry = cache.put(key, state, pf)
+        # digest of the entry actually SERVED (≠ the requested key's under
+        # a covering hit), so results are attributable to their Θ; the
+        # replay streams with aux=() (the cache_meta contract)
+        info = dict(status=status, digest=entry.key.digest()[:12],
+                    policy=self.reuse, **cache.stats)
+        return entry.state, (), status, info
 
     def sweep_async(self, folds: FoldData, lams: jax.Array, *,
                     stop_tol: Optional[float] = None, stop_patience: int = 2,
@@ -1738,11 +1674,7 @@ class CVEngine:
         complete, replayable entry (the fit is λ-grid independent — only
         the curve evaluation is truncated).
         """
-        if stop_tol is not None and stop_tol < 0:
-            raise ValueError(f"stop_tol must be >= 0 or None, got {stop_tol}")
-        if stop_patience < 1:
-            raise ValueError(
-                f"stop_patience must be >= 1, got {stop_patience}")
+        self._check_stop(stop_tol, stop_patience)
         if self.tune:
             derived, _ = self._tuned_engine(folds, lams)
             yield from derived.sweep_async(
@@ -1750,11 +1682,29 @@ class CVEngine:
                 pipelined=pipelined)
             return
         lams = self._check_lams(lams)
+        mesh = self._resolve_mesh(folds)
+        yield from self._stream(folds, lams, mesh,
+                                self._stage_chunk(folds, lams, mesh),
+                                stop_tol, stop_patience, pipelined)
+
+    @staticmethod
+    def _check_stop(stop_tol: Optional[float], stop_patience: int) -> None:
+        """Refuse early-stop arguments the staged sweep cannot act on."""
+        if stop_tol is not None and stop_tol < 0:
+            raise ValueError(f"stop_tol must be >= 0 or None, got {stop_tol}")
+        if stop_patience < 1:
+            raise ValueError(
+                f"stop_patience must be >= 1, got {stop_patience}")
+
+    def _stream(self, folds: FoldData, lams, mesh: Optional[Mesh],
+                chunk: int, stop_tol: Optional[float], stop_patience: int,
+                pipelined: bool) -> Iterator[SweepChunk]:
+        """The staged sweep over ``mesh`` in chunks of ``chunk`` λs, both
+        resolved once by the caller (:meth:`sweep_async`,
+        :meth:`run_async`): the state stage, then the λ-chunk stream."""
         lams_np = np.asarray(lams)
         k = folds.fold_hess.shape[0]
         q = int(lams.shape[0])
-        h = folds.fold_hess.shape[-1]
-        mesh = self._resolve_mesh(folds)
         self._check_fold_axis(mesh, k)
         h_tr, g_tr = self._split(folds.hess, folds.grad,
                                  folds.fold_hess, folds.fold_grad)
@@ -1762,22 +1712,21 @@ class CVEngine:
 
         # fixed-size chunk schedule (last chunk edge-padded) so one jitted
         # chunk stage serves the whole stream
-        chunk = self._stage_chunk(q, h, h_tr.dtype, mesh)
         chunks, _ = shardlib.chunk_lams(lams, chunk)
         n_c = chunks.shape[0]
 
         # ---- state stage (cache dispatch identical to run()) ------------
-        state, aux, warm, cache_info = self._staged_state_for(
-            mesh, h_tr, g_tr, folds, lams, pipelined)
+        state, aux, status, cache_info = self._acquire_state(
+            self._cache_meta(lams), mesh, folds, h_tr, g_tr, lams, pipelined)
+        warm = status in ("hit", "refit")
 
         # ---- λ-chunk stream ---------------------------------------------
-        f_idx = jnp.arange(k)
-        chunk_fn = self._chunk_errors_fn(mesh)
+        replay = self._replay_fn(mesh)
 
         def dispatch(c):
             with self._stage_scope("fold_errors"):
-                return chunk_fn(state, f_idx, h_tr, g_tr, folds.x_folds,
-                                folds.y_folds, chunks[c], aux)
+                return replay(state, h_tr, g_tr, folds.x_folds,
+                              folds.y_folds, chunks[c], aux)
 
         # full pipelined sweeps keep one chunk of dispatch lookahead; the
         # early-stop search dispatches chunk-by-chunk (the decision is the
@@ -1858,6 +1807,7 @@ class CVEngine:
         and whether it stopped); without it this is the staged equivalent
         of :meth:`run`.
         """
+        self._check_stop(stop_tol, stop_patience)
         if self.tune:
             derived, cfg = self._tuned_engine(folds, lams)
             res = derived.run_async(folds, lams, stop_tol=stop_tol,
@@ -1865,23 +1815,18 @@ class CVEngine:
                                     pipelined=pipelined)
             res.extras["engine"]["tune"] = cfg.to_json()
             return res
-        parts = list(self.sweep_async(folds, lams, stop_tol=stop_tol,
-                                      stop_patience=stop_patience,
-                                      pipelined=pipelined))
+        lams = self._check_lams(lams)
+        # the mesh and chunk the stream runs on are the ones reported
+        mesh = self._resolve_mesh(folds)
+        chunk = self._stage_chunk(folds, lams, mesh)
+        parts = list(self._stream(folds, lams, mesh, chunk, stop_tol,
+                                  stop_patience, pipelined))
         last = parts[-1]
         errors = np.concatenate([p.errors for p in parts])
         lams_eval = np.concatenate([p.lams for p in parts])
-        mesh = self._resolve_mesh(folds)
         n_lam = 1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS]
-        chunk = self._stage_chunk(int(jnp.shape(lams)[0]),
-                                  folds.fold_hess.shape[-1],
-                                  folds.fold_hess.dtype, mesh)
-        meta = dict(
-            strategy=self.strategy.name, backend=self._bk.name,
-            precision=self._prec.name,
-            mesh=None if mesh is None else dict(mesh.shape),
-            donated=bool(self.donate), lam_chunk=self.lam_chunk,
-            lam_chunk_resolved=chunk // n_lam, cache=last.cache,
+        meta = self._result_meta(
+            mesh, chunk // n_lam, last.cache,
             shard=self._shard_counts(mesh, folds, chunk // n_lam,
                                      last.n_exact_chol > 0, fused=False))
         meta["async"] = dict(
@@ -2011,19 +1956,18 @@ class CVEngine:
 
         # state stage once, over the full λ range — identical cache
         # dispatch to sweep_async / run (hit → zero factorizations here)
-        state, aux, warm, cache_info = self._staged_state_for(
-            mesh, h_tr, g_tr, folds, lams, pipelined)
-
-        f_idx = jnp.arange(k)
-        chunk_fn = self._chunk_errors_fn(mesh)
+        state, aux, status, cache_info = self._acquire_state(
+            self._cache_meta(lams), mesh, folds, h_tr, g_tr, lams, pipelined)
+        warm = status in ("hit", "refit")
+        replay = self._replay_fn(mesh)
         dtype = lams.dtype
 
         def eval_wave(xs):
             """Mean hold-out error at 10**xs — one fixed-shape dispatch."""
             lam_w = np.asarray(10.0 ** xs, dtype=dtype)
             with self._stage_scope("fold_errors"):
-                e = chunk_fn(state, f_idx, h_tr, g_tr, folds.x_folds,
-                             folds.y_folds, jnp.asarray(lam_w), aux)
+                e = replay(state, h_tr, g_tr, folds.x_folds, folds.y_folds,
+                           jnp.asarray(lam_w), aux)
             with tracing.span("cv.fetch"):
                 return lam_w, np.asarray(e).mean(0)
 
@@ -2085,12 +2029,8 @@ class CVEngine:
         n_eval = int(xs_all.shape[0])
         n_chol = 0 if warm else strat.n_exact_chol(k, n_eval)
         w_loc = w // (1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS])
-        meta = dict(
-            strategy=strat.name, backend=self._bk.name,
-            precision=self._prec.name,
-            mesh=None if mesh is None else dict(mesh.shape),
-            donated=bool(self.donate), lam_chunk=self.lam_chunk,
-            lam_chunk_resolved=w_loc, cache=cache_info,
+        meta = self._result_meta(
+            mesh, w_loc, cache_info,
             shard=self._shard_counts(mesh, folds, w_loc, not warm,
                                      fused=False))
         meta["search"] = dict(
@@ -2119,14 +2059,9 @@ class CVEngine:
         if key == (strat.degree, strat.basis):
             return self
         if key not in self._interp_engines:
-            self._interp_engines[key] = CVEngine(
+            self._interp_engines[key] = self._derive(
                 strategy=dataclasses.replace(strat, degree=key[0],
-                                             basis=key[1]),
-                backend=self._bk, mesh=self.mesh, donate=self.donate,
-                block=self.block, lam_chunk=self.lam_chunk,
-                cache=self.cache, reuse=self.reuse,
-                cache_anchors=self.cache_anchors,
-                tune=False, tune_cache=self.tune_cache)
+                                             basis=key[1]))
         return self._interp_engines[key]
 
     def _anchor_targets_fn(self):
@@ -2135,19 +2070,17 @@ class CVEngine:
         The anchor Hessian goes through the strategy's ``anchor_hessian``
         hook, so sketched strategies select against the sketched targets
         the sweep will actually fit."""
-        if self._anchor_targets is None:
-            strat, bk = self.strategy, self._bk
+        strat, bk = self.strategy, self._bk
 
-            def targets(h_tr, anchors, x_folds):
-                def per_fold(f, h_f):
-                    h_eff = strat.anchor_hessian(f, h_f, x_folds, bk)
-                    factors = picholesky.anchor_factors(h_eff, anchors,
-                                                        bk.cholesky)
-                    return _packed_anchors(factors, strat.block, bk)
-                return jax.vmap(per_fold)(jnp.arange(h_tr.shape[0]), h_tr)
+        def targets(h_tr, anchors, x_folds):
+            def per_fold(f, h_f):
+                h_eff = strat.anchor_hessian(f, h_f, x_folds, bk)
+                factors = picholesky.anchor_factors(h_eff, anchors,
+                                                    bk.cholesky)
+                return _packed_anchors(factors, strat.block, bk)
+            return jax.vmap(per_fold)(jnp.arange(h_tr.shape[0]), h_tr)
 
-            self._anchor_targets = _jit(targets)
-        return self._anchor_targets
+        return self._program(("anchor_targets",), lambda: _jit(targets))
 
     def select_interpolant(self, folds: FoldData, lams: jax.Array, *,
                            degrees=None,
@@ -2260,78 +2193,11 @@ class CVEngine:
         lams = jnp.asarray(lams)
         h_tr, g_tr = self._split(folds.hess, folds.grad, folds.fold_hess,
                                  folds.fold_grad)
-        state, _ = self._state_fn(None, False)(
-            h_tr, g_tr, folds.x_folds, folds.y_folds, lams)
+        state, _, _ = self._cold_state(None, folds, h_tr, g_tr, lams, False,
+                                       None)
         lowered = self._replay_fn(None).lower(
             state, h_tr, g_tr, folds.x_folds, folds.y_folds, lams)
         return int(lowered.compile().memory_analysis().temp_size_in_bytes)
-
-    def _cache_key(self, h_tr, meta: dict) -> cachelib.CacheKey:
-        """The cache key of a sweep's λ-independent inputs, fingerprinted
-        (and its bytes counted) by the attached cache."""
-        return self.cache.fingerprint(
-            h_tr, meta["anchors"], block=meta["params"]["block"],
-            backend=self._bk.name, params=meta["params"],
-            precision=self._prec.descriptor(),
-            sketch=meta.get("sketch", "exact"))
-
-    def _acquire_cached_state(self, meta: dict, key, cold_state_fn):
-        """Cache dispatch shared by :meth:`run` and :meth:`sweep_async`:
-        fingerprint → (hit | anchor refit | cold populate).
-
-        ``cold_state_fn(with_anchors)`` computes the batched cold state,
-        returning ``(state, packed_anchors | None)``.  Returns
-        ``(entry, status)``.
-        """
-        strat, cache = self.strategy, self.cache
-        if self.reuse:
-            entry = cache.lookup(key, self.reuse)
-        else:
-            entry = None
-            cache.misses += 1     # write-only runs are misses by definition
-        status = "hit"
-        if entry is None:
-            with_anchors = (self.cache_anchors
-                            and hasattr(strat, "fold_state_and_anchors"))
-            cached_pf = (cache.get_anchors(key)
-                         if self.reuse and with_anchors else None)
-            if cached_pf is not None:
-                # same anchor factors, different polynomial: refit Θ from
-                # the cached packed targets — still zero factorizations
-                state = self._refit_from_anchors(cached_pf, meta)
-                entry = cache.put(key, state, cached_pf)
-                status = "refit"
-            else:
-                state, pf = cold_state_fn(with_anchors)
-                entry = cache.put(key, state, pf)
-                status = "miss"
-        return entry, status
-
-    def _run_cached(self, meta: dict, mesh, h_tr, g_tr, folds: FoldData,
-                    lams_run: jax.Array, q: int):
-        """Warm-replay dispatch: fingerprint → (hit | anchor refit | cold
-        populate) → replay.  Returns (error grid, cache_info, n_chol)."""
-        key = self._cache_key(h_tr, meta)
-        k = h_tr.shape[0]
-
-        def cold_state(with_anchors):
-            state, avec = self._state_fn(mesh, with_anchors)(
-                self._state_hessians(mesh, folds, h_tr), g_tr,
-                folds.x_folds, folds.y_folds, lams_run)
-            pf = (packing.PackedFactor(vec=avec, h=h_tr.shape[-1],
-                                       block=meta["params"]["block"])
-                  if with_anchors else None)
-            return state, pf
-
-        entry, status = self._acquire_cached_state(meta, key, cold_state)
-        n_chol = (self.strategy.n_exact_chol(k, q) if status == "miss" else 0)
-        errs = self._replay_fn(mesh)(entry.state, h_tr, g_tr, folds.x_folds,
-                                     folds.y_folds, lams_run)
-        # digest of the entry actually SERVED (≠ the requested key's under
-        # a covering hit), so results are attributable to their Θ
-        info = dict(status=status, digest=entry.key.digest()[:12],
-                    policy=self.reuse, **self.cache.stats)
-        return errs, info, n_chol
 
     def run(self, folds: FoldData, lams: jax.Array) -> CVResult:
         if self.tune:
@@ -2352,14 +2218,17 @@ class CVEngine:
 
         with tracing.span("cv.run", h=int(folds.fold_hess.shape[-1]), k=k,
                           q=int(q)) as span:
-            meta = (self.strategy.cache_meta(lams)
-                    if self.cache is not None
-                    and hasattr(self.strategy, "cache_meta") else None)
+            meta = self._cache_meta(lams)
             if meta is not None:
                 h_tr, g_tr = self._split(folds.hess, folds.grad,
                                          folds.fold_hess, folds.fold_grad)
-                errs, cache_info, n_chol = self._run_cached(
-                    meta, mesh, h_tr, g_tr, folds, lams_run, q)
+                state, _, status, cache_info = self._acquire_state(
+                    meta, mesh, folds, h_tr, g_tr, lams_run)
+                errs = self._replay_fn(mesh)(state, h_tr, g_tr,
+                                             folds.x_folds, folds.y_folds,
+                                             lams_run)
+                n_chol = (self.strategy.n_exact_chol(k, q)
+                          if status == "miss" else 0)
             else:
                 # engine-owned train-stat buffers: safe to donate into the
                 # sweep
@@ -2381,17 +2250,23 @@ class CVEngine:
         n_lam = 1 if mesh is None else mesh.shape[shardlib.CV_LAM_AXIS]
         q_loc = int(lams_run.shape[0]) // n_lam
         return CVResult.from_errors(
-            lams, errs.mean(0), n_chol,
-            engine=dict(
-                strategy=self.strategy.name, backend=self._bk.name,
-                precision=self._prec.name,
-                mesh=None if mesh is None else dict(mesh.shape),
-                donated=bool(self.donate), lam_chunk=self.lam_chunk,
-                lam_chunk_resolved=self._lam_chunk_used(
-                    q_loc, folds.fold_hess.shape[-1], folds.fold_hess.dtype),
-                cache=cache_info,
+            lams, errs.mean(0), n_chol, engine=self._result_meta(
+                mesh, self._lam_chunk_used(q_loc, folds.fold_hess.shape[-1],
+                                           folds.fold_hess.dtype),
+                cache_info,
                 shard=self._shard_counts(mesh, folds, q_loc, n_chol > 0,
                                          fused=meta is None)))
+
+    def _result_meta(self, mesh: Optional[Mesh], chunk: int,
+                     cache: Optional[dict], **more) -> dict:
+        """A result's ``extras['engine']``: the engine's configuration,
+        the mesh the sweep ran on, the λs each λ-stage call took
+        (``chunk``), the cache's info and ``more``."""
+        return dict(strategy=self.strategy.name, backend=self._bk.name,
+                    precision=self._prec.name,
+                    mesh=None if mesh is None else dict(mesh.shape),
+                    donated=bool(self.donate), lam_chunk=self.lam_chunk,
+                    lam_chunk_resolved=chunk, cache=cache, **more)
 
     # -- batched admission (multi-tenant serving) ---------------------------
 
@@ -2448,8 +2323,7 @@ class CVEngine:
                 r.extras["engine"]["tune"] = cfg.to_json()
             return results
         strat = self.strategy
-        metas = [strat.cache_meta(l) if hasattr(strat, "cache_meta") else None
-                 for _, l in problems]
+        metas = [self._cache_meta(l) for _, l in problems]
         fusable = (self.cache is not None and self.reuse is not False
                    and self.mesh is None
                    and self._fit_mesh(problems[0][0]) is None
@@ -2515,10 +2389,12 @@ class CVEngine:
                 [problems[i][0].x_folds for i in cold_idx])
             y_stack = jnp.concatenate(
                 [problems[i][0].y_folds for i in cold_idx])
+            with self._stage_scope("prepare"):
+                aux = self._prepare_fn()(h_stack, g_stack, x_stack, y_stack,
+                                         problems[cold_idx[0]][1])
             with self._stage_scope("fold_state"):
                 state, avec = self._state_fn(None, with_anchors)(
-                    h_stack, g_stack, x_stack, y_stack,
-                    problems[cold_idx[0]][1])
+                    jnp.arange(h_stack.shape[0]), h_stack, g_stack, aux)
             off = 0
             for i in cold_idx:
                 k_i = splits[i][0].shape[0]
@@ -2566,14 +2442,8 @@ class CVEngine:
                         digest=entries[i].key.digest()[:12],
                         policy=self.reuse, tenant=tenants[i], **cache.stats)
             results.append(CVResult.from_errors(
-                lams_i, errs.mean(0), n_chol,
-                engine=dict(strategy=strat.name, backend=self._bk.name,
-                            precision=self._prec.name, mesh=None,
-                            donated=bool(self.donate),
-                            lam_chunk=self.lam_chunk,
-                            lam_chunk_resolved=self._lam_chunk_used(
-                                q_i, h_tr.shape[-1], h_tr.dtype),
-                            cache=info,
-                            batch=dict(size=n, index=i,
-                                       cold=len(cold_idx)))))
+                lams_i, errs.mean(0), n_chol, engine=self._result_meta(
+                    None, self._lam_chunk_used(q_i, h_tr.shape[-1],
+                                               h_tr.dtype), info,
+                    batch=dict(size=n, index=i, cold=len(cold_idx)))))
         return results

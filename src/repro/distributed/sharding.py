@@ -23,7 +23,7 @@ __all__ = ["spec_pspec", "param_pspecs", "param_shardings", "data_pspec",
            "mesh_shape_candidates", "PairLayout", "sweep_bytes",
            "device_bytes_free",
            "pad_to_multiple", "chunk_lams", "auto_lam_chunk",
-           "cv_state_specs", "cv_chunk_in_specs", "StageRing"]
+           "cv_state_specs", "StageRing"]
 
 
 def spec_pspec(spec: Spec, ctx) -> P:
@@ -216,23 +216,6 @@ def cv_state_specs(state: Any) -> Any:
     fitted from — cache shards follow the folds × lams mesh.
     """
     return jax.tree.map(lambda _: P(CV_FOLD_AXIS), state)
-
-
-def cv_chunk_in_specs(state: Any, aux: Any) -> tuple:
-    """Per-stage ``in_specs`` for the pipelined sweep's λ-chunk stage.
-
-    The staged (async) sweep evaluates one λ chunk per dispatch:
-    ``chunk_errors(state, f_idx, h_tr, g_tr, x_folds, y_folds, lams_c, aux)``.
-    Everything per-fold — the cached/stacked state pytree and the fold
-    statistics — shards over :data:`CV_FOLD_AXIS` (leading axis), the λ
-    chunk over :data:`CV_LAM_AXIS`, and the replicated ``aux`` from
-    ``prepare`` rides along unsharded.  One definition shared by the
-    warm-replay chunk stage and the cold pipelined stage, so the two paths
-    cannot drift onto different meshes.
-    """
-    fold = P(CV_FOLD_AXIS)
-    return (cv_state_specs(state), fold, fold, fold, fold, fold,
-            P(CV_LAM_AXIS), jax.tree.map(lambda _: P(), aux))
 
 
 class StageRing:

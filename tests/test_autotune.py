@@ -1,6 +1,8 @@
 """Roofline-guided autotuner: lattice legality, zero-execution scoring,
 tuned-vs-untuned parity, tuning-cache hits and cross-process persistence,
 and the serving layer's tune-once-per-geometry contract."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -224,6 +226,34 @@ def test_explicit_tuned_config_pins_configuration():
     derived = eng._apply_tuned(cfg)
     assert derived.strategy.block == 32 and derived.lam_chunk == 4
     assert derived.tune is False                 # recursion guard
+
+
+@pytest.mark.parametrize("derivation", ["tuned", "interpolant"])
+def test_derived_engine_carries_every_other_field(derivation):
+    """A derived engine (the tuned configuration's, or another
+    interpolant's) changes what its derivation sets and carries every
+    other field of the engine it derives from."""
+    from repro.core.factor_cache import FactorCache
+    from repro.core.sketch import SketchPlan
+    cfg = autotune.TunedConfig(block=32, lam_chunk=4, mesh_shape=None)
+    eng = CVEngine("picholesky", backend="reference", donate=False,
+                   block=16, lam_chunk=5, cache=FactorCache(),
+                   reuse="covering", cache_anchors=True, precision="fp32",
+                   tune=cfg, tune_cache=autotune.TuningCache(),
+                   tune_lattice=dict(blocks=(32,)),
+                   sketch=SketchPlan(m=64))
+    if derivation == "tuned":
+        derived = eng._apply_tuned(cfg)
+        changed = {"strategy", "backend", "mesh", "block", "lam_chunk"}
+    else:
+        derived = eng.with_interpolant(1, "centered")
+        changed = {"strategy", "backend"}
+    assert derived.tune is False                 # recursion guard
+    assert derived._prec == eng._prec
+    for field in dataclasses.fields(CVEngine):
+        if field.name not in changed | {"tune"}:
+            assert getattr(derived, field.name) == getattr(eng, field.name), \
+                field.name
 
 
 # ---------------------------------------------------------- backend retile

@@ -193,6 +193,26 @@ def test_default_mesh_follows_the_device_limit(monkeypatch):
     assert res.extras["engine"]["shard"]["pairs_per_device"] == 5
 
 
+def test_run_async_reports_the_mesh_its_sweep_ran_on(monkeypatch):
+    """The device's free bytes change once the sweep has run: the result
+    names the mesh the sweep was planned and run on, not a second
+    reading's."""
+    folds = _folds(5)
+    reads = []
+
+    def free(device):
+        reads.append(device)
+        return 1 if len(reads) == 1 else None   # later: no limit, one device
+    monkeypatch.setattr(shardlib, "device_bytes_free", free)
+    res = _engine(4, lam_chunk=4).run_async(folds, LAMS)
+    meta = res.extras["engine"]
+    assert meta["mesh"] == {"folds": 1, "lams": 4}
+    assert meta["shard"]["devices"] == 4
+    assert meta["shard"]["pairs_per_device"] == 5
+    assert meta["lam_chunk_resolved"] == 1      # a chunk of 4 over 4 devices
+    assert len(reads) == 1
+
+
 def test_anchor_exchange_scope_reaches_the_collectives():
     text = _pair_sweep_text(_engine(4, mesh="auto"), _folds(5),
                             compiled=True)

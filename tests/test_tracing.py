@@ -26,7 +26,8 @@ _SCOPE = re.compile(r"cv\.[a-z_]+")
 
 def _compiled_texts(path: str, precision: str, h: int = 256) -> list:
     """Compiled HLO of every program one sweep runs: the split, then the
-    fused sweep, or the cached path's state and replay programs."""
+    fused sweep, or the cached path's prepare, state and replay
+    programs."""
     folds = regression_folds(h=h, n=4 * h, k=4, dtype=jnp.float32)
     lams = jnp.logspace(-3, 0, 9, dtype=jnp.float32)
     eng = CVEngine("picholesky", backend="reference", precision=precision)
@@ -37,9 +38,13 @@ def _compiled_texts(path: str, precision: str, h: int = 256) -> list:
     if path == "fused":
         texts.append(eng._sweep_fn(None).lower(*args).compile().as_text())
     else:
+        prepare = eng._prepare_fn()
+        texts.append(prepare.lower(*args).compile().as_text())
         state_fn = eng._state_fn(None, True)
-        texts.append(state_fn.lower(*args).compile().as_text())
-        state, _ = jax.eval_shape(state_fn, *args)
+        state_args = (jnp.arange(h_tr.shape[0]), h_tr, g_tr,
+                      jax.eval_shape(prepare, *args))
+        texts.append(state_fn.lower(*state_args).compile().as_text())
+        state, _ = jax.eval_shape(state_fn, *state_args)
         texts.append(eng._replay_fn(None).lower(state, *args)
                      .compile().as_text())
     return texts
